@@ -70,7 +70,7 @@ def _add_model_args(p: argparse.ArgumentParser):
         "--fused_encoder",
         action="store_true",
         help="fused Pallas encoder + corr-build kernels for test-mode "
-        "forwards (ops/encoder_pallas.py). TPU-only in practice: off-TPU "
+        "forwards (ops/encoder_pallas.py). TPU-only in practice: on the CPU "
         "the kernels run in the Pallas interpreter (pathologically slow at "
         "full resolution); training forwards are unaffected either way",
     )
@@ -80,7 +80,7 @@ def _add_model_args(p: argparse.ArgumentParser):
         help="scalar-prefetch windowed correlation lookup for test-mode "
         "forwards ('pallas' corr only; bit-identical — rough coordinate "
         "fields fall back to the dense kernel). Training forwards are "
-        "unaffected; off-TPU runs in the Pallas interpreter",
+        "unaffected; on the CPU it runs in the Pallas interpreter",
     )
     p.add_argument(
         "--fused_gru_tail",
@@ -237,7 +237,7 @@ def _train_parser() -> argparse.ArgumentParser:
                    help="host-side non-finite detection cadence in steps (one "
                    "bulk device fetch per window); default resolves per "
                    "backend at startup: 1 on CPU, 25 on TPU (each fetch "
-                   "pays a host RTT there)")
+                   "is a device-to-host sync there)")
     p.add_argument("--coord_interval", type=int, default=None,
                    help="multi-host coordination cadence in steps (pod-wide "
                    "all-reduce of stop/skip/rollback/budget flags); default "
@@ -304,11 +304,12 @@ def _train_parser() -> argparse.ArgumentParser:
                    "on watchdog fire, non-finite rollback, and every fit "
                    "exit (0 disables recording; counters still report)")
     p.add_argument("--compilation_cache_dir", default=None, metavar="DIR",
-                   help="persistent JAX compilation cache for the training "
-                   "step: compiled programs are written under DIR and reused "
-                   "across restarts/preemptions, so --auto_resume relaunches "
-                   "skip the multi-minute XLA compile (the serving analogue "
-                   "is `serve --aot_cache_dir`)")
+                   help="persistent JAX compilation cache directory: compiled "
+                   "programs are written under DIR and reused across "
+                   "restarts/preemptions, so --auto_resume relaunches skip "
+                   "the multi-minute XLA compile. Default: .jax_cache/ in the "
+                   "checkout. JAX_COMPILATION_CACHE_DIR, when set, wins over "
+                   "both (utils/compile_cache.py)")
     _add_model_args(p)
     return p
 
@@ -454,28 +455,11 @@ def _run_train(args, config: TrainConfig) -> int:
         from raft_stereo_tpu.data.loader import DataLoader
         from raft_stereo_tpu.parallel.distributed import host_shard_args, init_multihost
         from raft_stereo_tpu.train.trainer import Trainer
+        from raft_stereo_tpu.utils.compile_cache import setup_compile_cache
         from raft_stereo_tpu.utils.metrics import MetricsLogger
 
+        setup_compile_cache(config.compilation_cache_dir)
         init_multihost()  # no-op single-host; connects the pod otherwise
-        if config.compilation_cache_dir:
-            # Best-effort: a missing/old jax build must degrade to cold
-            # compiles, never block training.
-            try:
-                import jax
-
-                os.makedirs(config.compilation_cache_dir, exist_ok=True)
-                jax.config.update(
-                    "jax_compilation_cache_dir", config.compilation_cache_dir
-                )
-                # Default threshold skips sub-second compiles; for restart
-                # latency we want everything persisted.
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.0
-                )
-            except Exception as exc:  # noqa: BLE001 - cache is an optimization
-                logging.getLogger(__name__).warning(
-                    "compilation cache unavailable (%r); compiling cold", exc
-                )
         if getattr(args, "explain_sharding", False):
             # Dry run: initialize the state tree and dump every leaf ->
             # PartitionSpec decision, without touching datasets or ckpts.
@@ -570,6 +554,9 @@ def cmd_evaluate(argv: List[str]) -> int:
 
     import jax
 
+    from raft_stereo_tpu.utils.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     config = _model_config(args)
     from raft_stereo_tpu.evaluate import VALIDATORS, Evaluator
 
@@ -826,7 +813,9 @@ def cmd_serve(argv: List[str]) -> int:
 
     from raft_stereo_tpu.config import ServeConfig, VideoConfig
     from raft_stereo_tpu.serving.service import StereoService, serve_http
+    from raft_stereo_tpu.utils.compile_cache import setup_compile_cache
 
+    setup_compile_cache()
     try:
         buckets = tuple(
             tuple(int(d) for d in b.lower().split("x")) for b in args.buckets
@@ -901,8 +890,8 @@ def cmd_serve(argv: List[str]) -> int:
         if args.require_cache_hit:
             if not boot.get("cache_enabled"):
                 print("--require_cache_hit: AOT cache is disabled "
-                      "(missing --aot_cache_dir or serialize_executable "
-                      "unavailable)", file=sys.stderr)
+                      "(missing or unwritable --aot_cache_dir)",
+                      file=sys.stderr)
                 return 3
             if int(boot.get("cache_misses", 0)) > 0:
                 print(f"--require_cache_hit: {boot['cache_misses']} warmup "

@@ -371,6 +371,13 @@ class DataLoader:
                 # libtpu) initialized — forked children can inherit held
                 # locks and deadlock. The dataset ships to workers via
                 # initargs, so no fork-time memory inheritance is needed.
+                # The parent holds the chip, and a chip belongs to one
+                # process: a worker must never initialize a jax backend.
+                # It does not — no file under raft_stereo_tpu/data/ imports
+                # jax, and what a worker runs (datasets, frame_io, augment,
+                # native_io) is numpy and the native core; the jax *module*
+                # arrives only through raft_stereo_tpu.utils, unused there.
+                # Keep it so.
                 self._pool = ProcessPoolExecutor(
                     max_workers=self.num_workers,
                     mp_context=multiprocessing.get_context("forkserver"),

@@ -6,15 +6,19 @@ reference's C++-backed DataLoader worker pool (reference
 core/stereo_datasets.py:541-542). pybind11 is not in this image, so the
 binding is a plain C ABI consumed through ctypes.
 
-The library is built lazily with `make -C native` on first use and cached;
-every entry point degrades gracefully (returns None / raises ImportError)
-when the toolchain or libpng is unavailable, and the pure-Python readers in
-frame_io.py remain the fallback. Set RAFT_STEREO_TPU_NATIVE_IO=0 to disable.
+The library is built lazily with `make -C native` on first use and cached
+(`libraft_io.so` is a build product, not a tracked file: a fresh checkout
+builds it from io_core.cc); every entry point degrades gracefully (returns
+None / raises ImportError) when make, the toolchain or libpng is
+unavailable, and the pure-Python readers in frame_io.py remain the fallback.
+The first use logs which of the two it got. Set RAFT_STEREO_TPU_NATIVE_IO=0
+to disable.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import os.path as osp
 import subprocess
@@ -23,6 +27,8 @@ from typing import Iterator, Optional, Sequence, Tuple
 import uuid
 
 import numpy as np
+
+logger = logging.getLogger(__name__)
 
 KIND_PFM = 0
 KIND_PNG = 1
@@ -93,7 +99,8 @@ def _load() -> Optional[ctypes.CDLL]:
             return tmp
 
         try:
-            if not osp.exists(so):
+            built = not osp.exists(so)
+            if built:
                 # Mirror the stale-rebuild path below: a failed os.replace
                 # (EXDEV, permissions, disk full) must not leave the
                 # uuid-named tmp orphaned in the source tree — a recycled
@@ -142,9 +149,16 @@ def _load() -> Optional[ctypes.CDLL]:
                             os.unlink(tmp)
                         except OSError:
                             pass
-        except (OSError, subprocess.SubprocessError):
+        except (OSError, subprocess.SubprocessError) as exc:
+            # make absent (FileNotFoundError), compile or dlopen failure.
+            logger.info(
+                "native IO unavailable (%r): using the Python readers", exc
+            )
             _lib_failed = True
             return None
+        logger.info(
+            "native IO: %s %s", "built" if built else "loaded existing", so
+        )
         for name in ("rsio_read_pfm", "rsio_read_png"):
             getattr(lib, name).argtypes = [ctypes.c_char_p, ctypes.POINTER(_RsioImage)]
             getattr(lib, name).restype = ctypes.c_int

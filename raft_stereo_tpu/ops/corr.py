@@ -48,9 +48,18 @@ Array = jax.Array
 # amplifies chaotically with iteration count (measured: 0.012 px at 2 iters
 # vs 6.1 px at 16 on the same weights) — the 2-iter delta is the bounded,
 # lever-isolated quantity a budget can govern; re-anchor at 32 iters when a
-# trained checkpoint lands (ROADMAP item 4). scripts/check_bench_json.py
+# trained checkpoint lands (ROADMAP R7). scripts/check_bench_json.py
 # holds a LITERAL mirror of this value (the validator must stay stdlib-only);
 # a tier-1 test pins the two together so they can never drift.
+#
+# One draw of untrained weights is noise around this number, so the tier-1
+# test holds the MEDIAN of five draws to it (CPU, 128x192: 0.010-0.088 px
+# per draw under jax 0.9.0, median 0.030; the spread, not the arithmetic, is
+# what moved with the toolchain — jax_threefry_partitionable's default
+# flipped in jax 0.5, so PRNGKey(0) draws other weights than it did).
+# Measured on the chip (v5e, chip_smoke.py --seed 0, PR 22): 0.063 px on one
+# draw at 384x512 with the Pallas lookup — inside that spread, over the
+# budget, and one more reason the budget wants R7's trained checkpoint.
 BF16_CORR_EPE_BUDGET_PX = 0.05
 
 

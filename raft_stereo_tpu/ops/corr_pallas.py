@@ -34,8 +34,10 @@ by construction, unlike the reference's racy unsynchronized `+=`
 (sampler_kernel.cu:102), and ~2.3x faster end-to-end in training than
 XLA's scatter lowering of the equivalent vjp.
 
-On non-TPU backends (the CPU test mesh) the kernel runs in interpreter mode,
-so parity tests cover identical code paths.
+On the CPU backend (the test mesh) the kernels run in interpreter mode, so
+parity tests cover identical kernel bodies; on a TPU backend they are
+compiled, and any other backend is an error (ops/pallas_mode.py). What only
+the chip's compiler can refuse is held by tests/test_chip_compile.py.
 """
 
 from __future__ import annotations
@@ -48,7 +50,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
 from raft_stereo_tpu.ops.corr import corr_pyramid, corr_volume
+from raft_stereo_tpu.ops.pallas_mode import pallas_interpret
+from raft_stereo_tpu.parallel.mesh import DATA_AXIS, SPATIAL_AXIS
 
 Array = jax.Array
 
@@ -85,6 +91,43 @@ def _query_layout(coords: Array):
         ((0, 0), (0, w1_pad - w1), (0, 0)),
     )
     return rows, w1_blk, w1_pad, coords_flat
+
+
+def _rows_over_data_axis(call, *operands):
+    """Run a row-gridded kernel call — every operand and result laid out
+    (rows = B*H, ...) — on the mesh a multi-device step is traced under.
+
+    XLA cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned", raised by the chip's compiler for the
+    data-parallel train step), so the call is `shard_map`ped: each device
+    runs the kernel on its own rows. Rows are batch-major, so splitting them
+    over the data axis is exactly the batch sharding the operands already
+    have — no resharding. `call` must size its grid from the shapes it is
+    handed (they are the per-device shapes inside the map).
+
+    The mesh is jax's own context mesh (`jax.set_mesh`, entered by
+    ShardingEngine.wrap around every multi-device step), which is part of
+    the trace cache key. With none set — every single-device path — this is
+    a plain call."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return call(*operands)
+    if mesh.shape.get(SPATIAL_AXIS, 1) > 1:
+        raise NotImplementedError(
+            "the Pallas correlation kernels are wired for the data mesh axis "
+            f"only, and this mesh is {dict(mesh.shape)}; use "
+            "corr_implementation='reg' with a spatial preset on several devices"
+        )
+    rows = operands[0].shape[0]
+    if rows % mesh.shape[DATA_AXIS]:
+        raise ValueError(
+            f"{rows} correlation rows (batch x height) do not divide over the "
+            f"{mesh.shape[DATA_AXIS]} devices of the data axis"
+        )
+    spec = P(DATA_AXIS)
+    return jax.shard_map(
+        call, in_specs=(spec,) * len(operands), out_specs=spec, check_vma=False
+    )(*operands)
 
 
 def _lookup_kernel(coords_ref, *rest, radius: int, w2_padded: Tuple[int, ...]):
@@ -226,29 +269,32 @@ def _scatter_pallas_padded(
         ((0, 0), (0, w1_pad - w1), (0, 0)),
     )
 
-    grid = (rows, w1_pad // w1_blk)
     in_specs = [
         pl.BlockSpec((1, w1_blk, 1), lambda r, w: (r, w, 0), memory_space=pltpu.VMEM),
         pl.BlockSpec(
             (1, w1_blk, num_levels * k), lambda r, w: (r, w, 0), memory_space=pltpu.VMEM
         ),
     ]
-    out_specs = []
-    out_shapes = []
-    for w2p, dtype in zip(w2_padded, padded_dtypes):
-        out_specs.append(
-            pl.BlockSpec((1, w1_blk, w2p), lambda r, w: (r, w, 0), memory_space=pltpu.VMEM)
-        )
-        out_shapes.append(jax.ShapeDtypeStruct((rows, w1_pad, w2p), dtype))
+    out_specs = [
+        pl.BlockSpec((1, w1_blk, w2p), lambda r, w: (r, w, 0), memory_space=pltpu.VMEM)
+        for w2p in w2_padded
+    ]
 
-    return pl.pallas_call(
-        functools.partial(_scatter_kernel, radius=radius, w2_padded=tuple(w2_padded)),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shapes,
-        interpret=jax.default_backend() != "tpu",
-    )(coords_flat, grad_flat)
+    def call(coords_flat, grad_flat):
+        rows = coords_flat.shape[0]
+        return pl.pallas_call(
+            functools.partial(_scatter_kernel, radius=radius, w2_padded=tuple(w2_padded)),
+            grid=(rows, w1_pad // w1_blk),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=[
+                jax.ShapeDtypeStruct((rows, w1_pad, w2p), dtype)
+                for w2p, dtype in zip(w2_padded, padded_dtypes)
+            ],
+            interpret=pallas_interpret(),
+        )(coords_flat, grad_flat)
+
+    return _rows_over_data_axis(call, coords_flat, grad_flat)
 
 
 def pad_pyramid(pyramid: Sequence[Array], coords_shape: Tuple[int, int, int]):
@@ -298,7 +344,6 @@ def _lookup_pallas_padded(padded, coords: Array, radius: int, out_dtype=jnp.floa
             f"{_LANES}; build the state with pad_pyramid"
         )
 
-    grid = (rows, w1_pad // w1_blk)
     in_specs = [
         pl.BlockSpec((1, w1_blk, 1), lambda r, w: (r, w, 0), memory_space=pltpu.VMEM)
     ]
@@ -309,21 +354,24 @@ def _lookup_pallas_padded(padded, coords: Array, radius: int, out_dtype=jnp.floa
             )
         )
 
-    out = pl.pallas_call(
-        functools.partial(
-            _lookup_kernel, radius=radius, w2_padded=tuple(w2_padded)
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, w1_blk, num_levels * k),
-            lambda r, w: (r, w, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct((rows, w1_pad, num_levels * k), out_dtype),
-        interpret=jax.default_backend() != "tpu",
-    )(coords_flat, *padded)
+    def call(coords_flat, *padded):
+        rows = coords_flat.shape[0]
+        return pl.pallas_call(
+            functools.partial(
+                _lookup_kernel, radius=radius, w2_padded=tuple(w2_padded)
+            ),
+            grid=(rows, w1_pad // w1_blk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (1, w1_blk, num_levels * k),
+                lambda r, w: (r, w, 0),
+                memory_space=pltpu.VMEM,
+            ),
+            out_shape=jax.ShapeDtypeStruct((rows, w1_pad, num_levels * k), out_dtype),
+            interpret=pallas_interpret(),
+        )(coords_flat, *padded)
 
+    out = _rows_over_data_axis(call, coords_flat, *padded)
     return out[:, :w1, :].reshape(b, h, w1, num_levels * k)
 
 
@@ -555,7 +603,7 @@ def _lookup_pallas_prefetch_windowed(
         functools.partial(_pf_lookup_kernel, radius=radius, win_tiles=win_tiles),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, w1_pad, num_levels * k), out_dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(starts, coords_flat, *vols)
     return out[:, :w1, :].reshape(b, h, w1, num_levels * k)
 
@@ -642,9 +690,12 @@ def _pyramid_kernel(f1_ref, f2_ref, *out_refs, widths: Tuple[int, ...], dim: int
         # TRUE output column, so padded columns stay exactly zero (the
         # lookup kernel's zero-tap contract).
         mask = ((r >> 1) == c) & (r < 2 * (wprev // 2))
-        pool = jnp.where(
-            mask, jnp.asarray(0.5, lvl.dtype), jnp.asarray(0, lvl.dtype)
-        )
+        # Select in float32, then narrow: the mask comes from int32 iotas, and
+        # Mosaic has no relayout of an i1 vector from the 32-bit tiling to
+        # the packed 16-bit one a bf16 select needs ("Invalid relayout ...
+        # (8,128) -> (16,128)" at Middlebury-F width). 0.5 and 0 are exact in
+        # every float dtype, so the pool matrix is bit-identical.
+        pool = jnp.where(mask, 0.5, 0.0).astype(lvl.dtype)
         nxt = jax.lax.dot_general(
             lvl, pool, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         ).astype(out_refs[i].dtype)
@@ -709,7 +760,7 @@ def fused_pyramid_state(
         ],
         out_specs=out_specs,
         out_shape=out_shapes,
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(f1, f2)
     return tuple(out)
 

@@ -40,18 +40,20 @@ is a pure reshape, leaving it rides the existing stride-2 layer2 entry
 kernels (`ResidualBlockFromS2D`), exactly like the training-mode s2d path.
 
 Activation: `RAFTStereoConfig.fused_encoder` (test-mode forwards only — the
-kernels define no VJP; the training path is untouched). Off-TPU the kernels
-run in the Pallas interpreter, which the tier-1 `-m kernels` parity tests
-rely on; full-resolution interpret execution is pathologically slow, so the
-CLI/bench only enable the flag on TPU.
+kernels define no VJP; the training path is untouched). On the CPU backend
+the kernels run in the Pallas interpreter (ops/pallas_mode.py), which the
+tier-1 `-m kernels` parity tests rely on; full-resolution interpret
+execution is pathologically slow.
 
-Verdict: PENDING first end-to-end TPU A/B. bench.py measures the fused and
-XLA encoder paths head-to-head every round (fwd_total_fused_s vs
-fwd_total_xla_s; the headline uses whichever wins and records the choice in
-`fused_encoder_used`), and scripts/exp_fused_encoder.py reproduces the A/B
-standalone. If the measured end-to-end delta is negative, retire this path
-gates_pallas-style: record the numbers here, keep the kernels + flag for
-toolchain re-runs, and flip the bench default off.
+Verdict: PENDING first end-to-end TPU A/B (ROADMAP D1; scripts/
+exp_fused_encoder.py runs it standalone; bench.py measures the default,
+un-fused configuration only). The kernels have only ever run interpreted;
+what is known from the chip's compiler (PR 22, tests/test_chip_compile.py):
+`fused_conv_s2d` and `fused_pyramid_state` compile for v5e at Middlebury-F
+width, the latter with bf16 storage only since its pooling mask is selected
+in float32 (the bf16 select was refused: "Invalid relayout ... (8,128) ->
+(16,128)"). If the measured end-to-end delta is negative, delete this path
+with its flag, tests and script.
 """
 
 from __future__ import annotations
@@ -63,6 +65,8 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
+
+from raft_stereo_tpu.ops.pallas_mode import pallas_interpret
 
 Array = jax.Array
 
@@ -253,7 +257,7 @@ def fused_conv_s2d(
             ),
             pl.BlockSpec((1, c2), lambda bb, h: (0, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 2, c2), lambda bb, h: (bb, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=out_specs,
         out_shape=out_shapes,
@@ -264,10 +268,10 @@ def fused_conv_s2d(
         # Both grid dims are stateful (the DMA ring scratch persists across
         # h; the stats block accumulates across h and re-initializes per b)
         # — neither may be parallelized.
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(w_dense, bias_tiled.reshape(1, c2), aff, x)
     return y, (stats if emit_stats else None)
 
@@ -315,7 +319,7 @@ def fused_join_s2d(
         ],
         out_specs=pl.BlockSpec((1, 1, w2, c2), row, memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(skip.shape, skip.dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(skip, y, aff_skip, aff_y)
 
 

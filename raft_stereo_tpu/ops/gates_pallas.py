@@ -42,6 +42,8 @@ import jax
 from jax.experimental import pallas as pl
 import jax.numpy as jnp
 
+from raft_stereo_tpu.ops.pallas_mode import pallas_interpret
+
 Array = jax.Array
 
 _BLOCK_ROWS = 1024
@@ -80,7 +82,7 @@ def _run_elementwise(kernel, args):
         in_specs=[spec] * len(flat),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n, c), args[0].dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(*flat)
     return out.reshape(shape)
 

@@ -24,8 +24,9 @@ Activation: `RAFTStereoConfig.fused_gru_tail` — a product config flag (unlike
 the env-only gates_pallas experiment) because it is wired as a bench lever
 and CLI knob. TEST-MODE forwards only (the kernels define no VJP; the
 exact-gradient-equality test in tests/test_fast_path.py proves the training
-graph untouched). Off-TPU the kernels run in the Pallas interpreter, so the
-CPU tier-1 parity tests (`-m kernels`) cover identical kernel bodies.
+graph untouched). On the CPU backend the kernels run in the Pallas
+interpreter, so the tier-1 parity tests (`-m kernels`) cover identical
+kernel bodies.
 
 Math is fp32 in-register regardless of operand dtype; stores round once to
 the operand dtype — under mixed precision that matches the XLA path, which
@@ -39,6 +40,8 @@ from __future__ import annotations
 import jax
 from jax.experimental import pallas as pl
 import jax.numpy as jnp
+
+from raft_stereo_tpu.ops.pallas_mode import pallas_interpret
 
 Array = jax.Array
 
@@ -83,7 +86,7 @@ def fused_gru_tail(zx: Array, cz: Array, qx: Array, cq: Array, h: Array) -> Arra
         in_specs=[spec] * len(flat),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n, c), h.dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(*flat)
     return out.reshape(shape)
 
@@ -111,6 +114,6 @@ def fused_motion_tail(pre: Array, flow: Array) -> Array:
         ],
         out_specs=pl.BlockSpec((_BLOCK_ROWS, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, c), pre.dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(pre_f, flow_f)
     return out.reshape(*shape[:-1], c)

@@ -75,13 +75,10 @@ def _enable_cpu_collectives() -> None:
     tests (tests/test_distributed.py) run the REAL SPMD code paths —
     device_put of replicated state, the pod-agreement all-reduce, the
     collective checkpoint save — on a laptop-grade CPU sandbox. No-op on
-    non-CPU platforms and on jax builds without the knob."""
+    non-CPU platforms."""
     if os.environ.get("JAX_PLATFORMS", "").split(",")[0] not in ("", "cpu"):
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # unknown option on this jax version: TPU-only setup
-        logger.warning("could not enable gloo CPU collectives", exc_info=True)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def process_topology() -> tuple:

@@ -359,21 +359,32 @@ def constrain_spatial_tree(tree, enabled: bool):
     return jax.tree.map(lambda t: constrain_spatial(t, True), tree)
 
 
+@contextmanager
+def _trace_scope(mesh: Mesh) -> Iterator[None]:
+    """Both scopes a traced graph may need the mesh from: `activation_mesh`
+    for the spatial constraints, and jax's own `jax.set_mesh` for the Pallas
+    kernels, which `shard_map` themselves over it (ops/corr_pallas.py). jax
+    keys its trace cache on the latter, so a graph traced outside the scope
+    is never reused inside it."""
+    with activation_mesh(mesh), jax.set_mesh(mesh):
+        yield
+
+
 class _ScopedFn:
-    """Callable wrapper that enters the activation-mesh scope around every
-    call (and `.lower`), so tracing — whenever jit decides to do it — sees
-    the mesh. Negligible per-call cost: one global set/reset."""
+    """Callable wrapper that enters `_trace_scope` around every call (and
+    `.lower`), so tracing — whenever jit decides to do it — sees the mesh.
+    Negligible per-call cost."""
 
     def __init__(self, fn, mesh: Mesh):
         self._fn = fn
         self._mesh = mesh
 
     def __call__(self, *args, **kwargs):
-        with activation_mesh(self._mesh):
+        with _trace_scope(self._mesh):
             return self._fn(*args, **kwargs)
 
     def lower(self, *args, **kwargs):
-        with activation_mesh(self._mesh):
+        with _trace_scope(self._mesh):
             return self._fn.lower(*args, **kwargs)
 
     def __getattr__(self, name):
@@ -540,10 +551,12 @@ class ShardingEngine:
         return self.preset.constrain_activations and self.mesh.shape[SPATIAL_AXIS] > 1
 
     def wrap(self, fn):
-        """Wrap a jitted callable so tracing happens inside the activation
-        mesh scope. Identity for presets without activation constraints —
-        the dp path keeps the raw jit object (and its exact legacy graphs)."""
-        if not self.constrain_activations:
+        """Wrap a jitted callable so tracing happens inside the mesh scope
+        (`_ScopedFn`): the spatial presets' constraints bind to it, and on
+        any multi-device mesh the Pallas kernels shard_map themselves over
+        it. Identity on a one-device mesh — the single-chip path keeps the
+        raw jit object."""
+        if self.mesh.size == 1:
             return fn
         return _ScopedFn(fn, self.mesh)
 

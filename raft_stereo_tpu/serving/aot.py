@@ -41,12 +41,17 @@ of the two), which check_bench_json's `validate_boot` asserts.
 from __future__ import annotations
 
 import hashlib
+import io
 import json
 import logging
 import os
 import pickle
 import threading
 from typing import Dict, Optional, Tuple
+
+import jax
+from jax._src import compiler as _jax_compiler
+from jax.experimental import serialize_executable as _jax_serialize
 
 logger = logging.getLogger(__name__)
 
@@ -61,13 +66,54 @@ logger = logging.getLogger(__name__)
 _FORMAT_VERSION = 2
 
 
+class _RetargetingUnpickler(_jax_serialize._JaxPjrtUnpickler):
+    """jax's executable unpickler, handing the runtime the device assignment
+    too. `deserialize_executable` takes the devices an executable is LOADED
+    onto from its CompileOptions argument (that is how jax's own persistent
+    cache loads an entry onto the devices of the current request);
+    `execution_devices` alone only sets what Python believes. jax's
+    `deserialize_and_load` passes no options, and on a TPU every executable
+    then lands on device 0 — a replica on another chip fails its first call
+    ("Buffer passed to Execute() ... is on device TPU_1, but replica is
+    assigned to device TPU_0"; seen on four chips, invisible on the CPU)."""
+
+    def __init__(self, file, devices):
+        super().__init__(file, devices[0].client, devices)
+        self._compile_options = _jax_compiler.get_compile_options(
+            num_replicas=1,
+            num_partitions=len(devices),
+            device_assignment=[[d.id for d in devices]],
+            use_spmd_partitioning=len(devices) > 1,
+        )
+
+    def persistent_load(self, pid):
+        if pid[0] == "exec":
+            return self.backend.deserialize_executable(
+                pid[1],
+                executable_devices=self.execution_devices,
+                compile_options=self._compile_options,
+            )
+        return super().persistent_load(pid)
+
+
+def _deserialize_and_load(payload, in_tree, out_tree, devices):
+    """`jax.experimental.serialize_executable.deserialize_and_load` onto
+    `devices`, through `_RetargetingUnpickler`."""
+    unloaded, args_info_flat, no_kwargs = _RetargetingUnpickler(
+        io.BytesIO(payload), list(devices)
+    ).load()
+    return jax.stages.Compiled(
+        unloaded.load(), [], in_tree.unflatten(args_info_flat), out_tree,
+        no_kwargs=no_kwargs,
+    )
+
+
 def config_fingerprint(config) -> str:
     """Hex digest naming the (toolchain, topology, serving-config) world an
     executable was compiled in. Any difference — jaxlib upgrade, different
     device kind, edited bucket table, changed model width — changes the
     digest, so incompatible artifacts are unreachable rather than detected.
     """
-    import jax
     import jaxlib
 
     devices = jax.local_devices()
@@ -146,9 +192,14 @@ class ExecutableCache:
         )
 
     # -- lookup ------------------------------------------------------------
-    def load(self, key: str):
-        """Deserialize-and-load the entry, or None on miss/corruption.
-        Never raises: every failure mode evicts and reports a miss."""
+    def load(self, key: str, execution_devices):
+        """Deserialize-and-load the entry onto `execution_devices` — the
+        device(s) the executable was compiled for, which the engine knows —
+        or None on miss/corruption. Left to jax's defaults it would load
+        onto ALL local devices (a one-device executable then fails at its
+        first call, "expected N shards") and, on a TPU, always onto device 0
+        (`_RetargetingUnpickler`). Never raises: every failure mode evicts
+        and reports a miss."""
         path = self._path(key)
         if not os.path.exists(path):
             with self._lock:
@@ -164,10 +215,11 @@ class ExecutableCache:
                     f"embedded fingerprint {entry.get('fingerprint')!r} != "
                     f"{self.fingerprint!r} (version/topology mismatch)"
                 )
-            from jax.experimental.serialize_executable import deserialize_and_load
-
-            fn = deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"]
+            fn = _deserialize_and_load(
+                entry["payload"],
+                entry["in_tree"],
+                entry["out_tree"],
+                execution_devices,
             )
         except Exception as exc:  # noqa: BLE001 — any corruption = evict
             self._evict(key, repr(exc))
@@ -195,9 +247,7 @@ class ExecutableCache:
         engine warmed without hlo_audit); it rides in the entry so later
         cache-hit boots can audit this executable."""
         try:
-            from jax.experimental.serialize_executable import serialize
-
-            payload, in_tree, out_tree = serialize(compiled)
+            payload, in_tree, out_tree = _jax_serialize.serialize(compiled)
             entry = {
                 "format": _FORMAT_VERSION,
                 "fingerprint": self.fingerprint,
@@ -243,20 +293,10 @@ class ExecutableCache:
 
 
 def maybe_cache(cache_dir: Optional[str], config) -> Optional["ExecutableCache"]:
-    """ExecutableCache when a dir is configured AND this jax build can
-    serialize executables; None otherwise (engines keep the plain jit
-    path). Gating on import keeps boot working on builds without the
-    experimental API — per the no-new-deps rule, absence degrades to the
-    legacy trace-at-boot behavior, never to a crash."""
+    """ExecutableCache when a dir is configured and usable; None otherwise
+    (engines keep the plain jit path) — an unwritable cache directory
+    degrades to trace-at-boot, never to a crash."""
     if not cache_dir:
-        return None
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-    except ImportError:
-        logger.warning(
-            "aot cache: jax.experimental.serialize_executable unavailable "
-            "in this jax build — serving boots without the executable cache"
-        )
         return None
     try:
         return ExecutableCache(cache_dir, config)

@@ -180,6 +180,16 @@ class AnytimeEngine:
         are per-device while the uncommitted single engine shares one."""
         return "host" if self.device is None else f"d{self.device.id}"
 
+    def _execution_devices(self):
+        """The device(s) this engine's executables are compiled for, and a
+        cached executable must be loaded onto: the spatial mesh, the
+        replica's chip, or the default device uncommitted inputs land on."""
+        if self.sharding is not None:
+            return list(self.sharding.mesh.devices.flat)
+        if self.device is not None:
+            return [self.device]
+        return jax.local_devices()[:1]
+
     def _audit_entry_name(self, stage, hw, batch, warm_start) -> str:
         preset = "spatial" if self.sharding is not None else "dp"
         suffix = "+warm" if warm_start else ""
@@ -241,7 +251,7 @@ class AnytimeEngine:
         key = entry_key(
             stage, hw, batch, warm_start=warm_start, device_tag=self._device_tag()
         )
-        fn = self.aot_cache.load(key)
+        fn = self.aot_cache.load(key, self._execution_devices())
         if fn is None:
             fn = jit_fn.lower(*args).compile()
             snap = self._audit_snapshot(stage, hw, batch, warm_start, fn) if audit else None

@@ -96,9 +96,8 @@ class StereoService:
     def __init__(self, config: ServeConfig, variables=None):
         self.config = config
         # Persistent AOT executable cache (serving/aot.py): None when no
-        # --aot_cache_dir was given or this jax build can't serialize
-        # executables; either engine path below receives it and boots
-        # deserialize-first.
+        # --aot_cache_dir was given (or it is unwritable); either engine
+        # path below receives it and boots deserialize-first.
         from raft_stereo_tpu.serving.aot import maybe_cache
 
         self.aot_cache = maybe_cache(getattr(config, "aot_cache_dir", None), config)

@@ -482,7 +482,11 @@ def main(argv=None) -> int:
 
     from raft_stereo_tpu.config import ServeConfig, VideoConfig
     from raft_stereo_tpu.serving.service import StereoService
+    from raft_stereo_tpu.utils.compile_cache import setup_compile_cache
 
+    # The XLA compile cache is the fixed one; only the AOT executable caches
+    # of the cold/warm boot curves below live in temporary directories.
+    setup_compile_cache()
     video_cfg = None
     if args.stream_frames > 0:
         warm_iters = (

@@ -56,11 +56,13 @@ resume must preserve exactly.
 import os
 import sys
 
-# One CPU device, pinned before jax initializes (same workaround as the
-# other subprocess workers). No persistent compilation cache: on this jax
-# build (0.4.37/CPU) a cache HIT in a process that later performs an orbax
-# restore corrupts the native heap — and the in-process leg reuse above
-# already amortizes the compile where it matters.
+# One CPU device, pinned before jax initializes (same as the other
+# subprocess workers). No persistent compilation cache: these legs are
+# SIGKILLed at arbitrary points, and a kill must not be able to land inside
+# a cache write that the resume leg then reads — the in-process leg reuse
+# above already amortizes the compile where it matters. (The heap
+# corruption once seen on jax 0.4.37 with a cache hit + orbax restore does
+# not reproduce on 0.9.0: six such legs in a row exit 0.)
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 os.environ["XLA_FLAGS"] = (

@@ -116,9 +116,9 @@ def test_cache_round_trip_and_corruption_eviction(tmp_path):
     compiled = jax.jit(lambda v: v * 2.0 + 1.0).lower(x).compile()
     expect = np.asarray(jax.device_get(compiled(x)))
 
-    assert cache.load("unit") is None  # cold miss
+    assert cache.load("unit", jax.devices()[:1]) is None  # cold miss
     assert cache.store("unit", compiled)
-    fn = cache.load("unit")
+    fn = cache.load("unit", jax.devices()[:1])
     assert fn is not None
     np.testing.assert_array_equal(np.asarray(jax.device_get(fn(x))), expect)
     stats = cache.stats()
@@ -130,7 +130,7 @@ def test_cache_round_trip_and_corruption_eviction(tmp_path):
     # Garbage bytes: unpicklable entry.
     with open(cache._path("unit"), "wb") as fh:
         fh.write(b"not a pickle")
-    assert cache.load("unit") is None
+    assert cache.load("unit", jax.devices()[:1]) is None
     assert cache.files() == 0  # evicted from disk
     # Wrong embedded fingerprint: a different toolchain/config world's
     # artifact copied into this directory must be rejected, not loaded.
@@ -142,7 +142,7 @@ def test_cache_round_trip_and_corruption_eviction(tmp_path):
     entry["fingerprint"] = "0" * 16
     with open(cache._path("unit"), "wb") as fh:
         pickle.dump(entry, fh)
-    assert cache.load("unit") is None
+    assert cache.load("unit", jax.devices()[:1]) is None
     stats = cache.stats()
     assert stats["evictions"] == 2
     assert stats["cache_hits"] + stats["cache_misses"] == stats["entries"]

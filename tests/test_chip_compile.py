@@ -1,0 +1,135 @@
+"""Compile-only guard: the main path's Pallas kernels, at real widths, are
+accepted by the TPU compiler for a described (not attached) v5e.
+
+Interpret mode — what every other kernel test runs — cannot see what Mosaic
+refuses: a relayout it has no rule for, a slice off the tiling, too much
+VMEM. These compiles can, in about two seconds each and with no chip
+(`on-chip-measurement` guide, section 2). Nothing runs, so they say nothing
+about results or times; parity lives in the interpret-mode tests and the
+on-chip check in `chip_smoke.py`.
+
+One file and one process on purpose: describing the topology takes
+`/tmp/libtpu_lockfile`, and two processes doing it at once abort.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from raft_stereo_tpu.ops import corr_pallas, encoder_pallas, gru_tail_pallas
+
+# Middlebury-F after ÷32 padding is 1984x2880; the disparity field, the
+# feature maps and the correlation rows live at 1/4 of it.
+MF_H4, MF_W4 = 496, 720
+# The SceneFlow recipe step: batch 4, 320x720 crops.
+TR_B, TR_H4, TR_W4 = 4, 80, 180
+LEVELS, RADIUS, FDIM = 4, 4, 256
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """SingleDeviceSharding on one chip of a described v5e:2x2 host."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 - no libtpu / lockfile held elsewhere
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {exc!r}")
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without the chip; keep these out of it.
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _tpu_backend(monkeypatch):
+    """The kernels ask `jax.default_backend()` whether to interpret; the
+    process is on the CPU, so the test answers for the described chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _padded_pyramid(rows, w1, w2, dtype, sharding):
+    _, w1_pad = corr_pallas._w1_blocks(w1)
+    out = []
+    for level in range(LEVELS):
+        w2p = corr_pallas._round_up(w2 >> level, 128)
+        out.append(jax.ShapeDtypeStruct((rows, w1_pad, w2p), dtype, sharding=sharding))
+    return tuple(out)
+
+
+def _assert_kernel_compiled(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "kernel was not lowered through Mosaic"
+    return text
+
+
+@pytest.mark.parametrize("corr_dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("prefetch", [False, True], ids=["dense", "prefetch"])
+def test_lookup_compiles_at_middlebury_f(v5e, corr_dtype, prefetch):
+    padded = _padded_pyramid(MF_H4, MF_W4, MF_W4, corr_dtype, v5e)
+    coords = jax.ShapeDtypeStruct((1, MF_H4, MF_W4), jnp.float32, sharding=v5e)
+    lookup = (
+        corr_pallas.prefetch_corr_lookup_padded
+        if prefetch
+        else corr_pallas._lookup_pallas_padded
+    )
+    _assert_kernel_compiled(
+        lambda p, c: lookup(p, c, RADIUS, jnp.bfloat16), padded, coords
+    )
+
+
+@pytest.mark.parametrize("corr_dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_train_lookup_and_scatter_compile_at_recipe_shape(v5e, corr_dtype):
+    """Forward lookup + its scatter backward (the custom VJP), batch 4 at
+    320x720 crops: the two kernels every training step runs."""
+    padded = _padded_pyramid(TR_B * TR_H4, TR_W4, TR_W4, corr_dtype, v5e)
+    coords = jax.ShapeDtypeStruct((TR_B, TR_H4, TR_W4), jnp.float32, sharding=v5e)
+
+    def loss(p, c):
+        taps = corr_pallas.pallas_corr_lookup_padded(p, c, RADIUS, jnp.bfloat16)
+        return jnp.sum(taps.astype(jnp.float32))
+
+    text = _assert_kernel_compiled(jax.value_and_grad(loss), padded, coords)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2  # lookup, scatter
+
+
+def test_fused_gru_tail_and_motion_tail_compile_at_middlebury_f(v5e):
+    gate = jax.ShapeDtypeStruct((1, MF_H4, MF_W4, 128), jnp.bfloat16, sharding=v5e)
+    _assert_kernel_compiled(gru_tail_pallas.fused_gru_tail, gate, gate, gate, gate, gate)
+    pre = jax.ShapeDtypeStruct((1, MF_H4, MF_W4, 126), jnp.bfloat16, sharding=v5e)
+    flow = jax.ShapeDtypeStruct((1, MF_H4, MF_W4, 1), jnp.bfloat16, sharding=v5e)
+    _assert_kernel_compiled(gru_tail_pallas.fused_motion_tail, pre, flow)
+
+
+def test_fused_conv_s2d_compiles_at_middlebury_f(v5e):
+    """The encoder's layer1 conv in the W-space-to-depth domain: the stem
+    runs at full resolution, so the operand is (1, 1984, 1440, 128)."""
+    x = jax.ShapeDtypeStruct((1, 4 * MF_H4, 2 * MF_W4, 128), jnp.bfloat16, sharding=v5e)
+    w = jax.ShapeDtypeStruct((3, 3, 128, 128), jnp.bfloat16, sharding=v5e)
+    bias = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=v5e)
+    aff = jax.ShapeDtypeStruct((1, 2, 128), jnp.float32, sharding=v5e)
+    _assert_kernel_compiled(
+        lambda x, w, b, a: encoder_pallas.fused_conv_s2d(
+            x, w, b, a, affine_form="in", emit_stats=True
+        ),
+        x, w, bias, aff,
+    )
+
+
+@pytest.mark.parametrize("corr_dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_fused_pyramid_state_compiles_at_middlebury_f(v5e, corr_dtype):
+    fmap = jax.ShapeDtypeStruct((1, MF_H4, MF_W4, FDIM), jnp.float32, sharding=v5e)
+    _assert_kernel_compiled(
+        lambda a, b: corr_pallas.fused_pyramid_state(a, b, LEVELS, corr_dtype=corr_dtype),
+        fmap, fmap,
+    )

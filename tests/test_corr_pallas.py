@@ -145,3 +145,58 @@ def test_padded_lookup_rejects_unpadded_state(rng):
     bad = (pyr[0].reshape(B * H, 200, 200),)  # lane dim 200: not a 128 multiple
     with pytest.raises(ValueError):
         pallas_corr_lookup_padded(bad, coords, RADIUS)
+
+
+# --- several devices: the kernels shard_map themselves over the data axis ---
+
+
+def _value_and_grad(state, coords):
+    def loss(state):
+        taps = pallas_corr_lookup_padded(state, coords, RADIUS)
+        return jnp.sum(jnp.square(taps)), taps
+
+    (_, taps), grad = jax.value_and_grad(loss, has_aux=True)(state)
+    return taps, grad
+
+
+def test_lookup_and_scatter_on_a_data_mesh_match_one_device(rng):
+    """Under a data mesh's trace scope the lookup and its scatter backward
+    run per device on that device's rows (a Mosaic kernel cannot be
+    partitioned by XLA), with the same bits as the unsharded call and the
+    batch sharding carried through."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from raft_stereo_tpu.parallel.mesh import DATA_AXIS, make_mesh
+    from raft_stereo_tpu.parallel.sharding import ShardingEngine
+
+    f1, f2, coords = make_inputs(rng)
+    state = pallas_corr_state(f1, f2, LEVELS)
+    want_taps, want_grad = jax.jit(_value_and_grad)(state, coords)
+
+    mesh = make_mesh((2, 1), devices=jax.devices()[:2])
+    rows = NamedSharding(mesh, P(DATA_AXIS))
+    sharded = ShardingEngine(mesh, "dp").wrap(jax.jit(_value_and_grad))
+    taps, grad = sharded(jax.device_put(state, rows), jax.device_put(coords, rows))
+    np.testing.assert_array_equal(np.asarray(taps), np.asarray(want_taps))
+    for got, want in zip(grad, want_grad):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert got.sharding.is_equivalent_to(rows, got.ndim)
+    # The one-device trace above is not reused: jax keys traces on the mesh.
+    with jax.set_mesh(mesh):
+        assert "shard_map" in str(jax.make_jaxpr(_value_and_grad)(state, coords))
+
+
+def test_lookup_on_a_spatial_mesh_is_refused(rng):
+    """The kernels are wired for the data axis only; an H-sharded mesh is an
+    error at trace time, not a silently different program."""
+    import pytest
+
+    from raft_stereo_tpu.parallel.mesh import make_mesh
+    from raft_stereo_tpu.parallel.sharding import ShardingEngine
+
+    f1, f2, coords = make_inputs(rng)
+    state = pallas_corr_state(f1, f2, LEVELS)
+    engine = ShardingEngine(make_mesh((1, 2), devices=jax.devices()[:2]), "spatial")
+    lookup = engine.wrap(jax.jit(lambda s, c: pallas_corr_lookup_padded(s, c, RADIUS)))
+    with pytest.raises(NotImplementedError, match="data mesh axis only"):
+        lookup(state, coords)

@@ -323,7 +323,17 @@ def test_bf16_epe_delta_within_budget(rng):
     inside the declared budget — same 2-iteration fp32-compute regime as
     bench.py's corr_precision block (at random init the GRU is not
     contractive, so more iterations measure chaos, not precision; see
-    ops/corr.py BF16_CORR_EPE_BUDGET_PX)."""
+    ops/corr.py BF16_CORR_EPE_BUDGET_PX).
+
+    The weights are untrained, so one draw of them is noise, and the budget
+    governs the median of five. What that replaced: a single PRNGKey(0)
+    draw, which measured 0.010 px under the jax this was written on and
+    0.088 px under 0.9.0 — not from arithmetic (with
+    `jax_threefry_partitionable=False` 0.9.0 reproduces the 0.010 exactly)
+    but because that flag's default flipped in jax 0.5, so the same key now
+    draws other weights. Keys 0-4 measure 0.088/0.010/0.047/0.021/0.030
+    under the new stream and keys 0-3 0.010/0.016/0.037/0.038 under the old:
+    the same spread, and the budget holds for the middle of either."""
     from raft_stereo_tpu.config import RAFTStereoConfig
     from raft_stereo_tpu.data.datasets import make_synthetic_sequence
     from raft_stereo_tpu.models import RAFTStereo
@@ -335,19 +345,28 @@ def test_bf16_epe_delta_within_budget(rng):
     gt = jnp.asarray(frame["flow"])
     valid = jnp.asarray(frame["valid"])
     cfg = RAFTStereoConfig(corr_implementation="reg", mixed_precision=False)
-    variables = RAFTStereo(cfg).init(jax.random.PRNGKey(0), i1, i2, iters=1)
+    init = jax.jit(lambda key: RAFTStereo(cfg).init(key, i1, i2, iters=1))
 
-    def epe(dt):
+    def make_epe(dt):
         m = RAFTStereo(dataclasses.replace(cfg, corr_dtype=dt))
-        _, up = jax.jit(
-            lambda v, a, b: m.apply(v, a, b, iters=2, test_mode=True)
-        )(variables, i1, i2)
-        err = jnp.abs(up[0, :, :, 0] - gt[..., 0])
-        return float(jnp.sum(err * valid) / jnp.sum(valid))
 
-    delta = abs(epe("bfloat16") - epe("float32"))
+        @jax.jit
+        def epe(v):
+            _, up = m.apply(v, i1, i2, iters=2, test_mode=True)
+            err = jnp.abs(up[0, :, :, 0] - gt[..., 0])
+            return jnp.sum(err * valid) / jnp.sum(valid)
+
+        return epe
+
+    epe_fp32, epe_bf16 = make_epe("float32"), make_epe("bfloat16")
+    deltas = []
+    for seed in range(5):
+        variables = init(jax.random.PRNGKey(seed))
+        deltas.append(abs(float(epe_bf16(variables)) - float(epe_fp32(variables))))
+    delta = float(np.median(deltas))
     assert delta <= BF16_CORR_EPE_BUDGET_PX, (
-        f"bf16 corr EPE delta {delta:.4f} px exceeds the declared budget "
+        f"median bf16 corr EPE delta {delta:.4f} px over draws "
+        f"{[round(d, 4) for d in deltas]} exceeds the declared budget "
         f"{BF16_CORR_EPE_BUDGET_PX} px"
     )
 

@@ -161,8 +161,6 @@ def test_transient_io_classification():
     assert not retry.is_transient_io(FileNotFoundError("gone"))
     assert not retry.is_transient_io(PermissionError("denied"))
     assert not retry.is_transient_io(ValueError("bad shape"))
-    # bench.py's tunnel markers still classify through the marker helper
-    assert retry.is_transient_marker(RuntimeError("response body closed early"))
 
 
 def test_nonfinite_guard_policies():

@@ -390,10 +390,12 @@ def test_constraints_require_mesh_scope():
 
     with pytest.raises(RuntimeError, match="no activation mesh"):
         jax.jit(lambda x: constrain_spatial(x, True))(jnp.zeros((2, 8, 4)))
-    # dp engines hand back the raw callable: no scope wrapper, no overhead.
-    engine = ShardingEngine(make_mesh((8, 1)), "dp")
+    # A one-device engine hands back the raw callable: no scope wrapper, no
+    # overhead. On several devices every preset scopes — the Pallas kernels
+    # find the mesh they must shard_map over there (dp included).
     fn = jax.jit(lambda x: x)
-    assert engine.wrap(fn) is fn
+    assert ShardingEngine(make_mesh((1, 1)), "dp").wrap(fn) is fn
+    assert ShardingEngine(make_mesh((8, 1)), "dp").wrap(fn) is not fn
 
 
 # ---------------------------------------------------------------------------
