@@ -295,7 +295,7 @@ class TrainConfig:
     worker_type: str = "thread"
     # Logging/profiling: metrics (TensorBoard + JSONL) land in log_dir;
     # profile_steps > 0 captures a jax.profiler device trace for that many
-    # steps after warmup into <log_dir>/profile (utils/profiling.py).
+    # steps after warmup into <log_dir>/profile (obs.profile).
     log_dir: str = "runs"
     profile_steps: int = 0
 

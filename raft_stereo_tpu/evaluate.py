@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import time
 from typing import Dict, Tuple
 
 import jax
@@ -29,6 +28,8 @@ import numpy as np
 
 from raft_stereo_tpu.config import RAFTStereoConfig
 from raft_stereo_tpu.models import RAFTStereo
+from raft_stereo_tpu.obs import scopes
+from raft_stereo_tpu.obs.trace import span
 from raft_stereo_tpu.utils.padding import InputPadder
 
 logger = logging.getLogger(__name__)
@@ -69,31 +70,61 @@ class Evaluator:
         # long eval set never does (train/trainer.py fit).
         self.heartbeat = None
 
+        # The jitted forward closes over the model and the iteration count,
+        # not over `self`: obs.scopes keeps it (to print its compiled module
+        # on demand), and must not keep this object's variables alive.
+        model, n_iters = self.model, iters
+
         @jax.jit
         def fwd(variables, image1, image2):
-            _, up = self.model.apply(variables, image1, image2, iters=self.iters, test_mode=True)
+            _, up = model.apply(variables, image1, image2, iters=n_iters, test_mode=True)
             return up
 
         self._fwd = fwd
+        self._registered_shapes = set()
+
+    def _register_program(self, image1, image2) -> None:
+        """Tell obs.scopes how to print the optimized module of this padded
+        shape: the jitted forward and abstract arguments (shapes, dtypes,
+        the shardings of committed leaves — no buffer), so that the lowering
+        is the one that ran and its compile a cache hit. Nothing is lowered
+        until a reader asks."""
+        self._registered_shapes.add(image1.shape)
+        fwd, args = self._fwd, scopes.abstract((self.variables, image1, image2))
+        scopes.register(
+            f"evaluate/forward/{image1.shape[1]}x{image1.shape[2]}",
+            lambda: fwd.lower(*args).compile().as_text(),
+        )
 
     def __call__(self, image1: np.ndarray, image2: np.ndarray) -> Tuple[np.ndarray, float]:
         """image1/2: (H, W, C) float arrays in [0, 255]. Returns
-        ((H, W) disparity-flow, forward seconds)."""
-        i1 = jnp.asarray(image1, jnp.float32)[None]
-        i2 = jnp.asarray(image2, jnp.float32)[None]
-        padder = InputPadder(i1.shape, divis_by=32, bucket=self.pad_bucket)
-        i1, i2 = padder.pad(i1, i2)
-        start = time.perf_counter()
-        up = self._fwd(self.variables, i1, i2)
-        up = jax.block_until_ready(up)
-        elapsed = time.perf_counter() - start
-        if self.heartbeat is not None:
-            self.heartbeat()
-        # Explicit fetch (not np.asarray): the unpad slice is host math on
-        # the full map anyway, and device_get is legal under the trainer's
-        # strict-mode transfer guard (utils/jit_hygiene.py) — validation
-        # runs inside a whitelisted window, but stays guard-clean on its own.
-        return jax.device_get(padder.unpad(up))[0, :, :, 0], elapsed
+        ((H, W) disparity-flow, forward seconds).
+
+        Spans (obs/trace.py): `evaluate/call` around the whole call, with
+        `evaluate/stage` (conversion plus the dispatch of pad and
+        host-to-device), `evaluate/forward` (dispatch to block_until_ready;
+        its seconds are the second value returned) and `evaluate/fetch`
+        (unpad dispatch and the device-to-host copy of the map)."""
+        with span("evaluate/call"):
+            with span("evaluate/stage"):
+                i1 = jnp.asarray(image1, jnp.float32)[None]
+                i2 = jnp.asarray(image2, jnp.float32)[None]
+                padder = InputPadder(i1.shape, divis_by=32, bucket=self.pad_bucket)
+                i1, i2 = padder.pad(i1, i2)
+            if i1.shape not in self._registered_shapes:
+                self._register_program(i1, i2)
+            with span("evaluate/forward") as forward:
+                up = self._fwd(self.variables, i1, i2)
+                up = jax.block_until_ready(up)
+            if self.heartbeat is not None:
+                self.heartbeat()
+            # Explicit fetch (not np.asarray): the unpad slice is host math on
+            # the full map anyway, and device_get is legal under the trainer's
+            # strict-mode transfer guard (utils/jit_hygiene.py) — validation
+            # runs inside a whitelisted window, but stays guard-clean on its own.
+            with span("evaluate/fetch"):
+                out = jax.device_get(padder.unpad(up))[0, :, :, 0]
+        return out, forward.seconds
 
 
 def _epe_1d(flow_pred: np.ndarray, flow_gt: np.ndarray) -> np.ndarray:

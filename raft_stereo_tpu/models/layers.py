@@ -229,7 +229,7 @@ def im2col_conv(kernel: Array, bias: Array, x: Array) -> Array:
     spatial, which the conv lowering handles with unit-stride row access.
     Measured on v5e at the full-res stem: 6.5 ms vs 17.1 direct — and vs
     25.5 for full KxK im2col + 1x1 conv, whose (B, H, W, K*K*C_in) patch
-    tensor pays an 18 ms layout copy (scripts/trace_ops.py).
+    tensor pays an 18 ms layout copy (device trace, round 2).
 
     Patch channel t = kx*C_in + c_in matches reshaping the (K, K, C_in,
     C_out) kernel to (K, 1, K*C_in, C_out), so the math is the conv's

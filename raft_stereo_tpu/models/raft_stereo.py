@@ -65,8 +65,9 @@ def _corr_state(cfg: RAFTStereoConfig, fmap1: Array, fmap2: Array, fused: bool =
     state build for the single-kernel volume+pyramid+pad fusion
     (ops/corr_pallas.fused_pyramid_state) — same output pytree, so the
     iteration loop's lookup is untouched."""
-    f1 = fmap1.astype(jnp.float32)
-    f2 = fmap2.astype(jnp.float32)
+    with jax.named_scope("corr_build"):
+        f1 = fmap1.astype(jnp.float32)
+        f2 = fmap2.astype(jnp.float32)
     if cfg.corr_implementation == "reg":
         vol = corr_volume(f1, f2, out_dtype=jnp.dtype(cfg.corr_dtype))
         return tuple(corr_pyramid(vol, cfg.corr_levels))

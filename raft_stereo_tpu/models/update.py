@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from raft_stereo_tpu.models.layers import Conv, ConvParams, im2col_conv
+from raft_stereo_tpu.obs.scopes import scoped
 from raft_stereo_tpu.utils.geometry import avg_pool2x, resize_bilinear_align_corners
 
 Array = jax.Array
@@ -208,8 +209,14 @@ class BasicMotionEncoder(nn.Module):
         return jnp.concatenate([out, flow, zero], axis=-1)
 
 
+# The cross-scale exchange is plain functions, so flax gives it no scope;
+# `interp_pool` is the component obs/scopes.py reads.
+@scoped("interp_pool")
 def _interp_to(x: Array, like: Array) -> Array:
     return resize_bilinear_align_corners(x, like.shape[1], like.shape[2])
+
+
+_pool2x = scoped("interp_pool")(avg_pool2x)
 
 
 class BasicMultiUpdateBlock(nn.Module):
@@ -256,12 +263,12 @@ class BasicMultiUpdateBlock(nn.Module):
         gru32 = ConvGRU(self.hidden_dims[0], pallas_gates=pg, fused_tail=ft, name="gru32") if n == 3 else None
 
         if iter32 and n == 3:
-            net[2] = gru32(net[2], *context[2], avg_pool2x(net[1]))
+            net[2] = gru32(net[2], *context[2], _pool2x(net[1]))
         if iter16 and n >= 2:
             if n > 2:
-                net[1] = gru16(net[1], *context[1], avg_pool2x(net[0]), _interp_to(net[2], net[1]))
+                net[1] = gru16(net[1], *context[1], _pool2x(net[0]), _interp_to(net[2], net[1]))
             else:
-                net[1] = gru16(net[1], *context[1], avg_pool2x(net[0]))
+                net[1] = gru16(net[1], *context[1], _pool2x(net[0]))
         if iter08:
             motion = BasicMotionEncoder(
                 self.corr_channels, fused_tail=ft, name="encoder"

@@ -1,11 +1,20 @@
-"""Unified observability layer (PR 14): tracing, metrics exposition, memory.
+"""Unified observability layer: spans, component scopes, metrics, memory.
 
-Three pillars, all host-side and dependency-free:
+Four pillars, all host-side:
 
-- `trace`: a lock-cheap `Tracer` producing spans with trace IDs into a
-  bounded ring-buffer `FlightRecorder`, dumped as `flight_recorder.json`
-  by the watchdog, breaker transitions, non-finite events, and crash/exit
-  paths — the "what was the system doing in the seconds before" record.
+- `trace`: ONE span entry point, `span(name)` — a
+  `jax.profiler.TraceAnnotation("rs/<name>")` on the device trace's clock
+  plus a record in one process-wide bounded log (`process_spans()`) — and
+  the `Tracer` built on it: spans with trace IDs into a per-owner
+  ring-buffer `FlightRecorder`, dumped as `flight_recorder.json` by the
+  watchdog, breaker transitions, non-finite events, and crash/exit paths —
+  the "what was the system doing in the seconds before" record.
+  `profile(logdir)` captures a device trace around a block.
+- `scopes`: which model component (encoder, corr_build, lookup, gru08, ...)
+  and phase (forward, backward, recompute) each instruction of a compiled
+  program belongs to, from the `op_name` its optimized HLO text carries;
+  `Evaluator` and `Trainer` register their programs there, a trace reader
+  joins device time to it by instruction name.
 - `prom`: a Prometheus text-exposition (0.0.4) registry — counters,
   gauges, histograms with explicit buckets — behind `GET
   /metrics?format=prom` in serving and a stdlib HTTP sidecar
@@ -15,8 +24,9 @@ Three pillars, all host-side and dependency-free:
 
 The hot-path contract that makes this TPU-native rather than bolted-on:
 nothing here dispatches device work, transfers, or syncs. Spans timestamp
-host events only; device time comes from the wall clock around the
-already-present `block_until_ready` boundaries in the serving chunk loop.
+host events only; scopes are trace-time metadata, printed and parsed only
+when a reader asks; device time comes from a profiler trace, or from the
+wall clock around the already-present `block_until_ready` boundaries.
 """
 
 from raft_stereo_tpu.obs.memory import (
@@ -37,6 +47,9 @@ from raft_stereo_tpu.obs.trace import (
     Tracer,
     load_flight_recorder,
     observability_block,
+    process_spans,
+    profile,
+    span,
 )
 
 __all__ = [
@@ -50,7 +63,10 @@ __all__ = [
     "load_flight_recorder",
     "memory_block",
     "observability_block",
+    "process_spans",
+    "profile",
     "sample_device_memory",
     "serve_registry",
     "set_memory_gauges",
+    "span",
 ]

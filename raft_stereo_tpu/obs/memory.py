@@ -22,9 +22,11 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional
 
 # Keys lifted from a device's memory_stats() dict when present. PJRT
-# backends vary (TPU reports more); these three are the common core the
-# bench/healthz block standardizes on.
-_STAT_KEYS = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+# backends vary; the first three are the common core the bench/healthz block
+# standardizes on. `peak_bytes_reserved` is the TPU runtime's: a program's
+# temporaries show only there, not in `peak_bytes_in_use` (a Middlebury-F
+# forward: 0.3 GB in use against 5.05 GB reserved, chip run, PR 22).
+_STAT_KEYS = ("bytes_in_use", "peak_bytes_in_use", "peak_bytes_reserved", "bytes_limit")
 
 
 def sample_device_memory() -> List[Dict[str, Any]]:
@@ -84,6 +86,7 @@ def memory_block(devices: Optional[List[Dict[str, Any]]] = None) -> Dict[str, An
         "device_count": len(devices),
         "bytes_in_use": sum(int(d.get("bytes_in_use", 0)) for d in devices),
         "peak_bytes_in_use": sum(int(d.get("peak_bytes_in_use", 0)) for d in devices),
+        "peak_bytes_reserved": sum(int(d.get("peak_bytes_reserved", 0)) for d in devices),
         "bytes_limit": sum(int(d.get("bytes_limit", 0)) for d in devices),
     }
     block.update(_live_buffers())
@@ -103,6 +106,10 @@ def set_memory_gauges(registry, prefix: str = "raft") -> Dict[str, Any]:
         f"{prefix}_device_memory_peak_bytes_in_use",
         "Sum of per-device allocator peak_bytes_in_use",
     ).set(block["peak_bytes_in_use"])
+    registry.gauge(
+        f"{prefix}_device_memory_peak_bytes_reserved",
+        "Sum of per-device peak_bytes_reserved (program temporaries included; 0 where the backend has none)",
+    ).set(block["peak_bytes_reserved"])
     registry.gauge(
         f"{prefix}_device_memory_bytes_limit",
         "Sum of per-device allocator bytes_limit",

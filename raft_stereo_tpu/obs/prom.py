@@ -171,6 +171,26 @@ class Histogram(_Metric):
             series = self._series.get(_label_key(labels))
         return series[2] if series else 0
 
+    def quantile(self, q: float, **labels: str) -> Optional[float]:
+        """The q-quantile as Prometheus' `histogram_quantile` estimates it:
+        linear within the bucket that holds it (the last finite bound where
+        that is the +Inf bucket). None with no observation."""
+        with self._lock:
+            series = self._series.get(_label_key(labels))
+        if not series or series[2] == 0:
+            return None
+        counts, _, n = series
+        rank, cumulative, lower = q * n, 0, 0.0
+        for bound, c in zip(self.buckets, counts):
+            if c and cumulative + c >= rank:
+                if bound == math.inf:
+                    return lower
+                return lower + (bound - lower) * (rank - cumulative) / c
+            cumulative += c
+            if bound != math.inf:
+                lower = bound
+        return lower
+
     def sample_lines(self) -> List[str]:
         with self._lock:
             items = sorted(

@@ -25,6 +25,10 @@ TPU-native re-design of the reference's correlation stack
 
 Everything is NHWC / (B, H, W, D); per-row independence of the 1D problem is
 what makes spatial (H) sharding communication-free here.
+
+Scopes (trace-time metadata only, read by obs/scopes.py): the volume, the
+pyramid and the pooled feature levels are `corr_build`, every lookup is
+`corr_lookup`.
 """
 
 from __future__ import annotations
@@ -35,6 +39,7 @@ import jax
 from jax import lax
 import jax.numpy as jnp
 
+from raft_stereo_tpu.obs.scopes import scoped
 from raft_stereo_tpu.utils.geometry import linear_sample_1d
 
 Array = jax.Array
@@ -63,6 +68,7 @@ Array = jax.Array
 BF16_CORR_EPE_BUDGET_PX = 0.05
 
 
+@scoped("corr_build")
 def corr_volume(fmap1: Array, fmap2: Array, out_dtype=jnp.float32) -> Array:
     """All-pairs 1D correlation volume.
 
@@ -115,6 +121,7 @@ def _avg_pool_last(x: Array) -> Array:
     return out.astype(x.dtype)
 
 
+@scoped("corr_build")
 def corr_pyramid(volume: Array, num_levels: int) -> List[Array]:
     """Pyramid over the W2 axis: level i has W2 // 2**i samples.
 
@@ -127,6 +134,7 @@ def corr_pyramid(volume: Array, num_levels: int) -> List[Array]:
     return pyramid
 
 
+@scoped("corr_lookup")
 def corr_lookup(pyramid: Sequence[Array], coords: Array, radius: int) -> Array:
     """Sample a (2r+1)-tap window around `coords` at every pyramid level.
 
@@ -143,6 +151,7 @@ def corr_lookup(pyramid: Sequence[Array], coords: Array, radius: int) -> Array:
     return jnp.concatenate(out, axis=-1)
 
 
+@scoped("corr_build")
 def pool_fmap_levels(fmap2: Array, num_levels: int) -> List[Array]:
     """Pooled right-image features for the on-the-fly ("alt") strategy.
 
@@ -158,6 +167,7 @@ def pool_fmap_levels(fmap2: Array, num_levels: int) -> List[Array]:
     return levels
 
 
+@scoped("corr_lookup")
 def corr_lookup_alt(
     fmap1: Array, fmap2_levels: Sequence[Array], coords: Array, radius: int
 ) -> Array:

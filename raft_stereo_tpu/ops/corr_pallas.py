@@ -52,6 +52,7 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
+from raft_stereo_tpu.obs.scopes import scoped
 from raft_stereo_tpu.ops.corr import corr_pyramid, corr_volume
 from raft_stereo_tpu.ops.pallas_mode import pallas_interpret
 from raft_stereo_tpu.parallel.mesh import DATA_AXIS, SPATIAL_AXIS
@@ -250,6 +251,7 @@ def _scatter_kernel(
             )
 
 
+@scoped("corr_lookup")
 def _scatter_pallas_padded(
     padded_shapes: Sequence[Tuple[int, ...]],
     padded_dtypes: Sequence,
@@ -292,11 +294,13 @@ def _scatter_pallas_padded(
                 for w2p, dtype in zip(w2_padded, padded_dtypes)
             ],
             interpret=pallas_interpret(),
+            name="corr_scatter",
         )(coords_flat, grad_flat)
 
     return _rows_over_data_axis(call, coords_flat, grad_flat)
 
 
+@scoped("corr_build")
 def pad_pyramid(pyramid: Sequence[Array], coords_shape: Tuple[int, int, int]):
     """Flatten + zero-pad each (B, H, W1, W2_i) level to the kernel's
     (rows, w1_pad, w2p_i) layout. Zero lane padding reproduces grid_sample
@@ -317,6 +321,7 @@ def pad_pyramid(pyramid: Sequence[Array], coords_shape: Tuple[int, int, int]):
     return tuple(padded)
 
 
+@scoped("corr_lookup")
 def _lookup_pallas_padded(padded, coords: Array, radius: int, out_dtype=jnp.float32) -> Array:
     """Raw fused lookup (no vjp) over a pre-padded pyramid (see pad_pyramid).
     coords: (B, H, W1) level-0 x positions → (B, H, W1, L*(2r+1)) in
@@ -369,6 +374,7 @@ def _lookup_pallas_padded(padded, coords: Array, radius: int, out_dtype=jnp.floa
             ),
             out_shape=jax.ShapeDtypeStruct((rows, w1_pad, num_levels * k), out_dtype),
             interpret=pallas_interpret(),
+            name="corr_lookup",
         )(coords_flat, *padded)
 
     out = _rows_over_data_axis(call, coords_flat, *padded)
@@ -604,10 +610,12 @@ def _lookup_pallas_prefetch_windowed(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, w1_pad, num_levels * k), out_dtype),
         interpret=pallas_interpret(),
+        name="corr_lookup_prefetch",
     )(starts, coords_flat, *vols)
     return out[:, :w1, :].reshape(b, h, w1, num_levels * k)
 
 
+@scoped("corr_lookup")
 def prefetch_corr_lookup_padded(
     padded, coords: Array, radius: int, out_dtype=jnp.float32
 ) -> Array:
@@ -703,6 +711,7 @@ def _pyramid_kernel(f1_ref, f2_ref, *out_refs, widths: Tuple[int, ...], dim: int
         lvl = nxt
 
 
+@scoped("corr_build")
 def fused_pyramid_state(
     fmap1: Array, fmap2: Array, num_levels: int, corr_dtype=jnp.float32
 ):
@@ -761,6 +770,7 @@ def fused_pyramid_state(
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=pallas_interpret(),
+        name="corr_pyramid",
     )(f1, f2)
     return tuple(out)
 

@@ -272,6 +272,7 @@ def fused_conv_s2d(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=pallas_interpret(),
+        name="encoder_conv_s2d",
     )(w_dense, bias_tiled.reshape(1, c2), aff, x)
     return y, (stats if emit_stats else None)
 
@@ -320,6 +321,7 @@ def fused_join_s2d(
         out_specs=pl.BlockSpec((1, 1, w2, c2), row, memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(skip.shape, skip.dtype),
         interpret=pallas_interpret(),
+        name="encoder_join",
     )(skip, y, aff_skip, aff_y)
 
 

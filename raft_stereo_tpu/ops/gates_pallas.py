@@ -65,7 +65,7 @@ def _combine_kernel(zx_ref, cz_ref, qx_ref, cq_ref, h_ref, out_ref):
     out_ref[...] = ((1.0 - z) * h + z * q).astype(out_ref.dtype)
 
 
-def _run_elementwise(kernel, args):
+def _run_elementwise(kernel, args, name):
     """Flatten (B,H,W,C) operands to (N, C) rows and grid over row blocks —
     elementwise math, so any aligned 2D tiling is fine; C stays on lanes."""
     shape = args[0].shape
@@ -83,15 +83,16 @@ def _run_elementwise(kernel, args):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n, c), args[0].dtype),
         interpret=pallas_interpret(),
+        name=name,
     )(*flat)
     return out.reshape(shape)
 
 
 def fused_rh(rx: Array, cr: Array, h: Array) -> Array:
     """sigmoid(rx + cr) * h in one VPU pass."""
-    return _run_elementwise(_rh_kernel, (rx, cr, h))
+    return _run_elementwise(_rh_kernel, (rx, cr, h), "gates_rh")
 
 
 def fused_combine(zx: Array, cz: Array, qx: Array, cq: Array, h: Array) -> Array:
     """(1 - z) * h + z * tanh(qx + cq) with z = sigmoid(zx + cz), one pass."""
-    return _run_elementwise(_combine_kernel, (zx, cz, qx, cq, h))
+    return _run_elementwise(_combine_kernel, (zx, cz, qx, cq, h), "gates_combine")

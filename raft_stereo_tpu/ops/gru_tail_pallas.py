@@ -87,6 +87,7 @@ def fused_gru_tail(zx: Array, cz: Array, qx: Array, cq: Array, h: Array) -> Arra
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n, c), h.dtype),
         interpret=pallas_interpret(),
+        name="gru_tail",
     )(*flat)
     return out.reshape(shape)
 
@@ -115,5 +116,6 @@ def fused_motion_tail(pre: Array, flow: Array) -> Array:
         out_specs=pl.BlockSpec((_BLOCK_ROWS, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, c), pre.dtype),
         interpret=pallas_interpret(),
+        name="motion_tail",
     )(pre_f, flow_f)
     return out.reshape(*shape[:-1], c)

@@ -19,9 +19,12 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from raft_stereo_tpu.obs.scopes import scoped
+
 Array = jax.Array
 
 
+@scoped("sequence_loss")
 def sequence_loss(
     flow_preds: Array,
     flow_gt: Array,

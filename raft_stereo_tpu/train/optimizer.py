@@ -14,6 +14,8 @@ from typing import Tuple
 
 import optax
 
+from raft_stereo_tpu.obs.scopes import scoped
+
 
 def onecycle_linear(
     peak_lr: float,
@@ -43,8 +45,11 @@ def make_optimizer(
     grad_clip_norm: float = 1.0,
 ) -> Tuple[optax.GradientTransformation, optax.Schedule]:
     schedule = onecycle_linear(lr, num_steps + 100)
+    clip = optax.clip_by_global_norm(grad_clip_norm)
     tx = optax.chain(
-        optax.clip_by_global_norm(grad_clip_norm),
+        # Same transformation, its update traced under the `grad_clip` scope
+        # (the train step wraps the whole update in `optimizer`).
+        optax.GradientTransformation(clip.init, scoped("grad_clip")(clip.update)),
         optax.adamw(schedule, b1=0.9, b2=0.999, eps=1e-8, weight_decay=wdecay),
     )
     return tx, schedule

@@ -35,6 +35,8 @@ import optax
 
 from raft_stereo_tpu.config import TrainConfig, finalize_train_config
 from raft_stereo_tpu.models import RAFTStereo, init_model_variables
+from raft_stereo_tpu.obs import scopes
+from raft_stereo_tpu.obs.trace import span
 from raft_stereo_tpu.parallel.mesh import make_mesh
 from raft_stereo_tpu.parallel.sharding import ShardingEngine
 from raft_stereo_tpu.train.io_spine import AsyncCheckpointCommitter, build_io_spine_block
@@ -109,9 +111,11 @@ def make_train_step(
             )
 
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
-        grad_norm = optax.global_norm(grads)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("grad_clip"):
+            grad_norm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         finite = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
         if config.nan_policy in ("skip", "rollback"):
             # Conditional apply ON DEVICE: a non-finite loss or gradient
@@ -120,8 +124,9 @@ def make_train_step(
             # how lazily the host polls the `nonfinite` flag
             # (utils/resilience.py NonFiniteGuard does the host-side policy).
             keep = lambda new, old: jnp.where(finite, new, old)
-            params = jax.tree.map(keep, params, state.params)
-            opt_state = jax.tree.map(keep, opt_state, state.opt_state)
+            with jax.named_scope("optimizer"):
+                params = jax.tree.map(keep, params, state.params)
+                opt_state = jax.tree.map(keep, opt_state, state.opt_state)
         new_state = state.replace(step=state.step + 1, params=params, opt_state=opt_state)
         metrics = dict(metrics, live_loss=loss, grad_norm=grad_norm)
         # Host-side guard flag: 1.0 when this step's loss/grads were NaN/Inf.
@@ -204,6 +209,7 @@ class Trainer:
                 donate_argnums=(0,),
             )
         )
+        self._register_program()
         self._ckpt_mgr = None
         # Async checkpoint commit (train/io_spine.py): with
         # cfg.async_checkpoint the post-snapshot half of each save (orbax
@@ -258,6 +264,29 @@ class Trainer:
         batch layout (the `train --explain_sharding` payload)."""
         return self.sharding.explain(self.state)
 
+    def _abstract_batch(self) -> Dict[str, jax.ShapeDtypeStruct]:
+        """One global batch as abstract shapes (no allocation), each under
+        the sharding `place_batch` commits it to: lowered against these, the
+        step is the very module a fit runs (a batch without shardings lowers
+        to a module that differs in nothing but its name, and misses the
+        compile cache)."""
+        h, w, c = self._sample_shape
+        b = self.config.batch_size
+        shapes = {"image1": (b, h, w, c), "image2": (b, h, w, c), "flow": (b, h, w, 1), "valid": (b, h, w)}
+        shardings = self.sharding.batch_shardings()
+        return {
+            name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=shardings[name])
+            for name, shape in shapes.items()
+        }
+
+    def _register_program(self) -> None:
+        """Tell obs.scopes how to print the optimized module of the train
+        step: the lowering `hlo_audit_record` does, against an abstract state
+        (shapes, dtypes, shardings) so that the registry pins no buffer and
+        no Trainer. Nothing is lowered until a reader asks."""
+        step, batch, state = self.train_step, self._abstract_batch(), scopes.abstract(self.state)
+        scopes.register("train/step", lambda: step.lower(state, batch).compile().as_text())
+
     def hlo_audit_record(self) -> Dict[str, Any]:
         """tools/graftaudit record of THE production train step: lower the
         exact jitted object `fit()` dispatches (same in/out shardings, same
@@ -274,14 +303,9 @@ class Trainer:
         )
 
         cfg = self.config
-        h, w, c = self._sample_shape
+        h, w, _ = self._sample_shape
         b = cfg.batch_size
-        batch = {
-            "image1": jax.ShapeDtypeStruct((b, h, w, c), jnp.float32),
-            "image2": jax.ShapeDtypeStruct((b, h, w, c), jnp.float32),
-            "flow": jax.ShapeDtypeStruct((b, h, w, 1), jnp.float32),
-            "valid": jax.ShapeDtypeStruct((b, h, w), jnp.float32),
-        }
+        batch = self._abstract_batch()
         compiled = self.train_step.lower(self.state, batch).compile()
         preset = cfg.sharding_rules
         return snapshot_compiled(
@@ -622,20 +646,34 @@ class Trainer:
         watchdog-killed), `self.last_run_report` holds the machine-readable
         run-health report (utils/run_report.py schema) and the same dict is
         written atomically to <cfg.log_dir>/run_report.json for external
-        orchestrators; cli.py maps it onto distinct process exit codes."""
+        orchestrators; cli.py maps it onto distinct process exit codes.
+
+        Spans (obs/trace.py; each also a `rs/<name>` event in a profiler
+        trace): `train/fit` around the call, with the phases `train/start`
+        (entry to the first batch being asked for), `train/steps` (from there
+        to the loop's exit), `train/drain` (the wait for the last dispatched
+        step — the one device wait the tail keeps) and `train/final_save`;
+        inside `train/steps`, per step, `data-wait`, `step` (placement and
+        dispatch, not device time), and `checkpoint-save` / `coord-sync`
+        where they happen."""
+        with span("train/fit"):
+            return self._fit(data, metrics_logger, validate_fn)
+
+    def _fit(self, data, metrics_logger, validate_fn):
+        """`fit`'s body; the caller holds the `train/fit` span."""
         import contextlib
 
         from raft_stereo_tpu.obs import (
             Registry,
             Tracer,
             observability_block,
+            profile,
             serve_registry,
             set_memory_gauges,
         )
         from raft_stereo_tpu.parallel.coordination import HostCoordinator
         from raft_stereo_tpu.utils import run_report as rr
         from raft_stereo_tpu.utils.jit_hygiene import JitHygiene
-        from raft_stereo_tpu.utils.profiling import StepTimer, trace
         from raft_stereo_tpu.utils.resilience import (
             FailureBudgetExceeded,
             NonFiniteGuard,
@@ -646,11 +684,12 @@ class Trainer:
 
         # Re-finalize: tests (and power users) swap host-side knobs on
         # trainer.config between fits; None fields resolve here. Idempotent.
+        phase = span("train/start").begin()
         self.config = cfg = finalize_train_config(self.config)
         primary = is_metrics_host()
         step = int(jax.device_get(self.state.step))
         start_step = step
-        timer = StepTimer()
+        last_tick: Optional[float] = None  # the previous step's dispatch, for the cadence histogram
         profile_window = (
             range(start_step + 2, start_step + 2 + cfg.profile_steps)
             if cfg.profile_steps
@@ -940,10 +979,9 @@ class Trainer:
             has heard (fatal_synced / decision.rollback), so no host ever
             abandons its peers mid-collective."""
             nonlocal local_rollback, pod_rollback, fatal_synced
-            t_sync0 = time.perf_counter()
             # Whitelisted: the tiny reduce program compiles once at the
             # first sync — possibly after the grace window.
-            with hygiene.whitelist("coord_sync"):
+            with tracer.timed("coord-sync", step=step), hygiene.whitelist("coord_sync"):
                 handle = coord.submit(
                     stop=pguard.stop_requested,
                     nonfinite=bool(fatal),
@@ -958,7 +996,6 @@ class Trainer:
                 if checked_drain(prefetched=fetched[: len(window)]) == "rollback":
                     local_rollback = True
                 decision = coord.complete(fetched[len(window)])
-            tracer.span("coord-sync", t0=t_sync0, t1=time.perf_counter(), step=step)
             watchdog.beat(step)
             if decision.stop and not pguard.stop_requested:
                 pod["peer_stop"] = True
@@ -990,6 +1027,26 @@ class Trainer:
                 "peers indefinitely — set --step_timeout_s so the watchdog "
                 "can convert that into a clean exit"
             )
+        def waited_batches():
+            """One pass over `data`, each wait for a batch under a
+            `data-wait` span: host wait between the previous step's boundary
+            work and the loader yielding (prefetch miss, disk stall,
+            quarantine churn) — the first thing to look at when step cadence
+            degrades without device work changing. The wait that finds the
+            data exhausted leaves no span."""
+            batches = None
+            while True:
+                with tracer.timed("data-wait", step=step + 1) as wait:
+                    try:
+                        if batches is None:
+                            batches = iter(data)
+                        batch = next(batches)
+                    except StopIteration:
+                        wait.drop()
+                        return
+                data_wait_hist.observe(wait.seconds * 1e3)
+                yield batch
+
         stop_cause = "completed"
         error_repr = None
         try:
@@ -1013,39 +1070,33 @@ class Trainer:
                     # the compile-heavy first train step still lies ahead;
                     # re-grant the compile allowance for it.
                     watchdog.grant(cfg.watchdog_grace_s)
+                phase.end()
+                phase = span("train/steps").begin()
                 while step < cfg.num_steps and not stopping:
                     epoch_batches = 0
-                    # Step-boundary clock for the data-wait span: the gap
-                    # between the previous boundary and the loader yielding
-                    # is host wait (prefetch miss, disk stall, quarantine
-                    # churn) — the first thing to look at when step cadence
-                    # degrades without device work changing.
-                    boundary_t = time.perf_counter()
-                    for batch in data:
+                    for batch in waited_batches():
                         epoch_batches += 1
-                        t_batch = time.perf_counter()
-                        data_wait_hist.observe((t_batch - boundary_t) * 1e3)
-                        tracer.span("data-wait", t0=boundary_t, t1=t_batch, step=step + 1)
                         pending_reseed = False
                         if profile_window and step == profile_window.start:
-                            profile_ctx = trace(os.path.join(cfg.log_dir, "profile"))
+                            profile_ctx = profile(os.path.join(cfg.log_dir, "profile"))
                             profile_ctx.__enter__()
-                        if prefetcher is not None:
-                            # Already placed on the mesh by the prefetch
-                            # thread — while the PREVIOUS step ran.
-                            device_batch = batch
-                        else:
-                            arrays = {k: v for k, v in batch.items() if k in ("image1", "image2", "flow", "valid")}
-                            device_batch = self.sharding.place_batch(arrays)
-                        self.state, metrics = self.train_step(self.state, device_batch)
-                        tick_delta = timer.tick()
                         # Dispatch wall only — the device may still be
                         # running (async); a sync here would break the
                         # zero-transfer contract this layer observes.
-                        tracer.span("step", t0=t_batch, t1=time.perf_counter(), step=step + 1)
+                        with tracer.timed("step", step=step + 1):
+                            if prefetcher is not None:
+                                # Already placed on the mesh by the prefetch
+                                # thread — while the PREVIOUS step ran.
+                                device_batch = batch
+                            else:
+                                arrays = {k: v for k, v in batch.items() if k in ("image1", "image2", "flow", "valid")}
+                                device_batch = self.sharding.place_batch(arrays)
+                            self.state, metrics = self.train_step(self.state, device_batch)
+                        tick = time.perf_counter()
                         steps_counter.inc()
-                        if tick_delta is not None:
-                            step_hist.observe(tick_delta * 1e3)
+                        if last_tick is not None:
+                            step_hist.observe((tick - last_tick) * 1e3)
+                        last_tick = tick
                         step += 1
                         # Step boundary for the recompile monitor: raises
                         # RecompileError (strict mode) when a non-whitelisted
@@ -1105,15 +1156,8 @@ class Trainer:
                                 # save still fires, just later.
                                 watchdog.grant(cfg.watchdog_grace_s)
                                 watchdog.mark_phase("checkpoint-save")
-                                t_save0 = time.perf_counter()
-                                with hygiene.whitelist("checkpoint_save"):
+                                with tracer.timed("checkpoint-save", step=step), hygiene.whitelist("checkpoint_save"):
                                     self.save(run_state=make_run_state())
-                                tracer.span(
-                                    "checkpoint-save",
-                                    t0=t_save0,
-                                    t1=time.perf_counter(),
-                                    step=step,
-                                )
                                 # Save boundary = the memory high-water
                                 # sampling point (host-side allocator
                                 # introspection, no device work).
@@ -1186,11 +1230,10 @@ class Trainer:
                             # different sample order past the offending window.
                             break
                         watchdog.beat(step)
-                        # New step boundary AFTER all boundary work
-                        # (checkpoint/validation/sync carry their own
-                        # spans): the next data-wait span isolates loader
-                        # wait instead of re-counting them.
-                        boundary_t = time.perf_counter()
+                        # The next data-wait span begins when the next batch
+                        # is asked for, AFTER all boundary work (checkpoint /
+                        # validation / sync carry their own spans): it
+                        # isolates loader wait instead of re-counting them.
                         if stopping or step >= cfg.num_steps:
                             break
                     if epoch_batches == 0:
@@ -1215,6 +1258,13 @@ class Trainer:
                         )
                 if profile_ctx is not None:
                     profile_ctx.__exit__(None, None, None)
+                phase.end()
+                # The one device wait the tail keeps: everything after it
+                # (the flag drain, the step fetch, the save) finds the device
+                # idle, so `train/steps` + `train/drain` is the time the
+                # steps had the device.
+                with span("train/drain"):
+                    jax.block_until_ready(self.state.params)
                 # One FINAL pod sync: every host reaches this point at the
                 # same pod-agreed boundary (num_steps or a synced stop), so
                 # all dispatch it. It settles anything that happened after
@@ -1246,36 +1296,29 @@ class Trainer:
                 # flags are replicated, so under coordination every host
                 # raises (or doesn't) identically — no sync needed here.
                 drain_flags()
-                stats = timer.report(sync_on=self.state.params)
-                if stats:
-                    logger.info("step timing: %s", stats)
-                final_step = int(jax.device_get(self.state.step))
-                if self._last_saved_step == final_step and self._ckpt_mgr is not None:
-                    # The periodic cadence already saved this exact step (e.g.
-                    # num_steps % checkpoint_every == 0) — re-saving it would make
-                    # orbax re-write (or reject) a finished step; just make sure
-                    # the (possibly async) commit has landed and was clean
-                    # before reporting success.
-                    watchdog.grant(cfg.watchdog_grace_s)
-                    watchdog.mark_phase("final-save")
-                    try:
-                        self._committer.barrier()
-                        self._ckpt_mgr.wait_until_finished()
-                    finally:
-                        watchdog.mark_phase(None)
-                else:
-                    watchdog.grant(cfg.watchdog_grace_s)
-                    watchdog.mark_phase("final-save")
-                    t_save0 = time.perf_counter()
-                    with hygiene.whitelist("checkpoint_save"):
-                        self.save(wait=True, run_state=make_run_state())
-                    tracer.span(
-                        "checkpoint-save",
-                        t0=t_save0,
-                        t1=time.perf_counter(),
-                        step=final_step,
-                        final=True,
+                if step_hist.count():
+                    logger.info(
+                        "step timing: p50 %.1f ms, p95 %.1f ms over %d steps (raft_train_step_ms)",
+                        step_hist.quantile(0.5), step_hist.quantile(0.95), step_hist.count() + 1,
                     )
+                final_step = int(jax.device_get(self.state.step))
+                watchdog.grant(cfg.watchdog_grace_s)
+                watchdog.mark_phase("final-save")
+                try:
+                    with span("train/final_save", step=final_step):
+                        if self._last_saved_step == final_step and self._ckpt_mgr is not None:
+                            # The periodic cadence already saved this exact step
+                            # (e.g. num_steps % checkpoint_every == 0) — re-saving
+                            # it would make orbax re-write (or reject) a finished
+                            # step; just make sure the (possibly async) commit has
+                            # landed and was clean before reporting success.
+                            self._committer.barrier()
+                            self._ckpt_mgr.wait_until_finished()
+                        else:
+                            with tracer.timed("checkpoint-save", step=final_step, final=True), \
+                                    hygiene.whitelist("checkpoint_save"):
+                                self.save(wait=True, run_state=make_run_state())
+                finally:
                     watchdog.mark_phase(None)
                 set_memory_gauges(registry)
                 watchdog.beat(final_step)
@@ -1304,6 +1347,7 @@ class Trainer:
             error_repr = repr(e)
             raise
         finally:
+            phase.end()  # a phase an exception cut short still leaves its span
             if not watchdog.fired:
                 # The watchdog path wrote its own report from the monitor
                 # thread (the main thread never unwinds from a real hang);

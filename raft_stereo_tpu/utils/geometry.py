@@ -13,6 +13,8 @@ import jax
 from jax import lax
 import jax.numpy as jnp
 
+from raft_stereo_tpu.obs.scopes import scoped
+
 
 def coords_grid_x(batch: int, height: int, width: int, dtype=jnp.float32) -> jax.Array:
     """Base x-coordinate grid, shape (B, H, W).
@@ -105,7 +107,7 @@ def avg_pool2x(x: jax.Array) -> jax.Array:
     Not 9 strided slices either: XLA:TPU lowers stride-2 slices on the
     row/column axes as row-index GATHERS — measured 9 x 0.64 ms per GRU
     iteration at Middlebury-F, ~22% of the whole iteration
-    (scripts/trace_ops.py). Instead, stride-2 sampling is expressed as
+    (device trace, round 2). Instead, stride-2 sampling is expressed as
     reshape-to-pairs + unit-stride slices, which compile to plain loop
     fusions at full bandwidth:
 
@@ -146,6 +148,7 @@ def extract_3x3_patches(x: jax.Array) -> jax.Array:
     return jnp.stack(taps, axis=3)
 
 
+@scoped("upsample")
 def convex_upsample_blocked(field: jax.Array, mask: jax.Array, factor: int) -> jax.Array:
     """`convex_upsample` stopping at the einsum's native blocked form.
 

@@ -1,33 +1,17 @@
-"""Aux subsystems: profiling hooks, multi-host init, CLI surface."""
+"""Aux subsystems: the profile capture, multi-host init, CLI surface."""
 
 import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 
-def test_step_timer_reports_stats():
-    import time
-
-    from raft_stereo_tpu.utils.profiling import StepTimer
-
-    t = StepTimer(window=10)
-    for _ in range(5):
-        t.tick()
-        time.sleep(0.002)
-    stats = t.report(sync_on=jnp.ones((4,)))
-    assert set(stats) == {"steps_per_sec", "step_ms_p50", "step_ms_p95"}
-    assert stats["steps_per_sec"] > 0
-    assert stats["step_ms_p95"] >= stats["step_ms_p50"] > 0
-
-
 def test_trace_writes_profile(tmp_path):
-    from raft_stereo_tpu.utils.profiling import trace
+    from raft_stereo_tpu.obs import profile
 
     logdir = str(tmp_path / "prof")
-    with trace(logdir):
+    with profile(logdir):
         jax.block_until_ready(jnp.ones((64, 64)) @ jnp.ones((64, 64)))
     found = [
         os.path.join(r, f)
@@ -36,17 +20,6 @@ def test_trace_writes_profile(tmp_path):
         if f.endswith((".trace.json.gz", ".xplane.pb"))
     ]
     assert found, f"no trace artifacts under {logdir}"
-
-
-def test_annotate_runs_inside_jit():
-    from raft_stereo_tpu.utils.profiling import annotate
-
-    @jax.jit
-    def f(x):
-        with annotate("test-region"):
-            return x * 2
-
-    np.testing.assert_array_equal(np.asarray(f(jnp.ones(3))), 2.0)
 
 
 def test_init_multihost_single_process_noop():

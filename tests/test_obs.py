@@ -15,6 +15,11 @@ The acceptance criteria, each machine-checked here:
   samples (a percentile of nothing is not a number);
 - device-memory telemetry degrades to a typed `available: false` block on
   CPU and never raises;
+- ONE span entry point (`obs.span`): under a profiler session its spans are
+  `rs/<name>` events of the host plane carrying their parent, the same spans
+  land in the bounded process-wide log (plain scalars only), `Tracer.timed`
+  goes through it, and `Evaluator.__call__` gains its three child spans
+  without an extra executable, a sync or a change to the seconds it returns;
 - THE strict-mode acceptance: a warmed serving run and a short training fit
   with every pillar on (tracing + prom + memory sampling) complete with
   compiles_post_grace == 0 and compile exactly the same executables as an
@@ -44,9 +49,12 @@ from raft_stereo_tpu.obs import (
     load_flight_recorder,
     memory_block,
     observability_block,
+    process_spans,
     serve_registry,
     set_memory_gauges,
+    span,
 )
+from raft_stereo_tpu.obs import trace as obs_trace
 from raft_stereo_tpu.serving.batcher import ServingMetrics
 
 pytestmark = pytest.mark.obs
@@ -240,6 +248,176 @@ def test_observability_block_shape():
     assert all(isinstance(v, int) for k, v in live.items() if k != "enabled")
 
 
+# -- the span entry point and the process-wide log --------------------------
+
+
+def _host_events(trace_dir, prefix="rs/"):
+    """{name: [stats dict]} of the host-plane events under `prefix`."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith(prefix):
+                    found.setdefault(event.name, []).append(
+                        dict(event.stats, start_ns=event.start_ns, duration_ns=event.duration_ns))
+    return found
+
+
+@pytest.fixture(scope="module")
+def tiny_evaluator():
+    import jax
+    import jax.numpy as jnp
+
+    from raft_stereo_tpu.config import RAFTStereoConfig
+    from raft_stereo_tpu.evaluate import Evaluator
+    from raft_stereo_tpu.models import RAFTStereo
+
+    cfg = RAFTStereoConfig(hidden_dims=(32, 32, 32), n_gru_layers=1, corr_levels=2)
+    model = RAFTStereo(cfg)
+    image = jnp.zeros((1, 32, 64, 3))
+    variables = jax.jit(lambda r: model.init(r, image, image, iters=1))(jax.random.PRNGKey(0))
+    evaluator = Evaluator(cfg, variables, iters=2)
+    pair = [np.random.default_rng(i).uniform(0, 255, (30, 60, 3)).astype(np.float32) for i in (1, 2)]
+    evaluator(*pair)  # compile the one shape, and the pad / unpad programs
+    return evaluator, pair
+
+
+def test_span_parent_root_and_attrs_in_the_process_log():
+    before = len(process_spans())
+    with span("outer/call", frame=3) as outer:
+        with span("outer/part") as part:
+            pass
+        with span("outer/part"):
+            with span("outer/leaf") as leaf:
+                pass
+    mine = process_spans()[before:]
+    assert [r["name"] for r in mine] == ["outer/part", "outer/leaf", "outer/part", "outer/call"]
+    by_id = {r["id"]: r for r in mine}
+    assert by_id[outer.id]["parent"] is None and by_id[outer.id]["attrs"] == {"frame": 3}
+    assert by_id[part.id]["parent"] == outer.id
+    assert by_id[by_id[leaf.id]["parent"]]["name"] == "outer/part"
+    assert {r["root"] for r in mine} == {outer.id}
+    assert all(r["t1"] >= r["t0"] for r in mine)
+    assert outer.seconds == by_id[outer.id]["t1"] - by_id[outer.id]["t0"]
+    assert [r["name"] for r in process_spans("outer/leaf")[-1:]] == ["outer/leaf"]
+    # a thread starts with no open span: its spans are roots of their own
+    seen = []
+    with span("outer/call"):
+        worker = threading.Thread(target=lambda: seen.append(span("worker/job").begin()))
+        worker.start()
+        worker.join()
+    assert seen[0].parent is None and seen[0].root == seen[0].id
+
+
+def test_process_log_is_bounded_and_holds_no_array():
+    import jax.numpy as jnp
+
+    with span("log/array", image=jnp.ones((4, 4)), host=np.ones(3), ids=[1, 2], label="x", lr=1e-3):
+        pass
+    attrs = process_spans("log/array")[-1]["attrs"]
+    assert attrs == {"image": "<ArrayImpl>", "host": "<ndarray>", "ids": [1, 2], "label": "x", "lr": 1e-3}
+    json.dumps(process_spans())  # plain data, all of it
+    for _ in range(obs_trace.PROCESS_LOG_CAPACITY + 10):
+        with span("log/fill"):
+            pass
+    assert len(process_spans()) == obs_trace.PROCESS_LOG_CAPACITY
+    # a dropped span, and a span ended twice, leave one record or none
+    count = len(process_spans("log/once"))
+    dropped = span("log/once").begin()
+    dropped.drop()
+    once = span("log/once").begin()
+    once.end()
+    once.end()
+    assert len(process_spans("log/once")) == count + 1 and dropped.dropped
+
+
+def test_tracer_writes_through_to_the_process_log():
+    tracer = Tracer(capacity=8)
+    with tracer.timed("chunk", trace=7, batch=2):
+        tracer.span("stage", trace=7, t0=1.0, t1=2.5)
+        tracer.event("compile")
+    rows = {r["name"]: r for r in process_spans()[-2:]}
+    assert rows["chunk"]["attrs"] == {"batch": 2, "trace": 7}
+    assert rows["stage"]["parent"] == rows["chunk"]["id"] and rows["stage"]["t1"] == 2.5
+    ring = tracer.recorder.records()
+    assert [r["name"] for r in ring] == ["stage", "compile", "chunk"]
+    assert ring[2]["trace"] == 7 and ring[2]["attrs"] == {"batch": 2}
+    # a disabled ring still counts, and the log still gets the span
+    off = Tracer(capacity=0)
+    with off.timed("chunk"):
+        pass
+    assert off.recorder.counters()["spans_total"] == 1 and off.recorder.records() == []
+    assert process_spans()[-1]["name"] == "chunk"
+
+
+def test_evaluator_spans_land_in_the_profile_with_their_parent(tiny_evaluator, tmp_path):
+    import jax
+
+    evaluator, pair = tiny_evaluator
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        evaluator(*pair)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    assert {"rs/evaluate/call", "rs/evaluate/stage", "rs/evaluate/forward", "rs/evaluate/fetch"} <= set(events)
+    (call,) = events["rs/evaluate/call"]
+    assert call["parent"] == 0 and call["root"] == call["span"]
+    for child in ("stage", "forward", "fetch"):
+        (event,) = events[f"rs/evaluate/{child}"]
+        assert event["parent"] == call["span"] and event["root"] == call["span"]
+        assert call["start_ns"] <= event["start_ns"]
+        assert event["start_ns"] + event["duration_ns"] <= call["start_ns"] + call["duration_ns"]
+    # the same spans, by the same ids, in the process-wide log
+    logged = {r["id"]: r for r in process_spans() if r["root"] == call["span"]}
+    assert {r["name"] for r in logged.values()} == {
+        "evaluate/call", "evaluate/stage", "evaluate/forward", "evaluate/fetch"}
+    assert logged[events["rs/evaluate/forward"][0]["span"]]["name"] == "evaluate/forward"
+
+
+def test_evaluator_call_returns_forward_seconds_and_adds_no_executable(tiny_evaluator, tmp_path):
+    """Tracing on (a live profiler session) or off, a warmed call compiles
+    nothing, and the seconds it returns are the forward span's own: dispatch
+    to block_until_ready, as before the spans."""
+    import jax
+
+    from raft_stereo_tpu.utils.jit_hygiene import RecompileMonitor
+
+    evaluator, pair = tiny_evaluator
+    with RecompileMonitor(grace_steps=0) as monitor:
+        quiet_map, quiet_s = evaluator(*pair)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            traced_map, traced_s = evaluator(*pair)
+        finally:
+            jax.profiler.stop_trace()
+        stats = monitor.stats()
+    assert stats["compiles_total"] == 0, stats
+    np.testing.assert_array_equal(quiet_map, traced_map)
+    forward, previous = process_spans("evaluate/forward")[-1], process_spans("evaluate/forward")[-2]
+    assert traced_s == forward["t1"] - forward["t0"] and quiet_s == previous["t1"] - previous["t0"]
+    call = process_spans("evaluate/call")[-1]
+    parts = [r for r in process_spans() if r["root"] == call["id"] and r["id"] != call["id"]]
+    assert [r["name"] for r in parts] == ["evaluate/stage", "evaluate/forward", "evaluate/fetch"]
+    assert sum(r["t1"] - r["t0"] for r in parts) <= call["t1"] - call["t0"]
+    assert 0 < traced_s < call["t1"] - call["t0"]
+
+
+def test_prom_histogram_quantile_interpolates_within_a_bucket():
+    hist = Registry().histogram("q_ms", "t", buckets=(10.0, 20.0, 40.0))
+    assert hist.quantile(0.5) is None
+    for value in (12.0, 14.0, 16.0, 18.0):
+        hist.observe(value)
+    assert hist.quantile(0.5) == 15.0 and hist.quantile(1.0) == 20.0
+    hist.observe(1000.0)  # the +Inf bucket reads as the last finite bound
+    assert hist.quantile(0.99) == 40.0
+
+
 # -- percentile semantics --------------------------------------------------
 
 
@@ -295,6 +473,7 @@ def test_memory_block_is_typed_consistent_and_never_raises():
         "device_count",
         "bytes_in_use",
         "peak_bytes_in_use",
+        "peak_bytes_reserved",
         "bytes_limit",
         "live_buffer_count",
         "live_buffer_bytes",
@@ -316,6 +495,7 @@ def test_set_memory_gauges_populates_registry():
     for name in (
         "raft_device_memory_bytes_in_use",
         "raft_device_memory_peak_bytes_in_use",
+        "raft_device_memory_peak_bytes_reserved",
         "raft_device_memory_bytes_limit",
         "raft_live_buffer_count",
         "raft_live_buffer_bytes",
@@ -670,3 +850,19 @@ def test_strict_mode_training_fit_with_observability_on(tmp_path):
     ]
     assert len(steps) == cfg.num_steps
     assert all(r["ms"] >= 0.0 for r in steps)
+
+    # the fit's phases, in the process-wide log under one root: start, steps
+    # (holding the per-step spans), drain, final save — the strict-mode guard
+    # above proves the spans added no transfer and no compile
+    fit = process_spans("train/fit")[-1]
+    under = [r for r in process_spans() if r["root"] == fit["id"] and r["id"] != fit["id"]]
+    phases = [r for r in under if r["parent"] == fit["id"]]
+    assert [r["name"] for r in phases] == ["train/start", "train/steps", "train/drain", "train/final_save"]
+    assert all(a["t1"] <= b["t0"] for a, b in zip(phases, phases[1:]))
+    steps_id = phases[1]["id"]
+    per_step = [r for r in under if r["parent"] == steps_id]
+    assert [r["name"] for r in per_step if r["name"] == "step"] == ["step"] * cfg.num_steps
+    assert sum(r["name"] == "data-wait" for r in per_step) == cfg.num_steps
+    assert [r["attrs"]["step"] for r in per_step if r["name"] == "checkpoint-save"] == [4]
+    final = [r for r in under if r["name"] == "checkpoint-save" and r["attrs"].get("final")]
+    assert len(final) == 1 and final[0]["parent"] == phases[3]["id"]
