@@ -153,13 +153,20 @@ class RAFTStereoConfig:
     # 2–4; retired-with-numbers and PRUNED in round 5 — the fused cell
     # measured 5.68 vs 3.34 ms/cell against XLA's ~160 TF/s conv emitter.
     # Verdict in ROADMAP "Round-3 kernel verdicts"; code in git history.)
-    # With remat_iterations on, additionally SAVE the correlation-lookup
-    # outputs across the forward pass instead of recomputing them in
-    # backward ("save_only_these_names" checkpoint policy on the taps).
-    # The taps are small (B, H/2^K, W/2^K, levels*(2r+1)) but expensive to
-    # recompute (the fused gather kernel); the reference recipe's tap stack
-    # (22 iters, batch 4, 320x720 crops, K=2) is ~0.18 GB — well within
-    # budget.
+    # With remat_iterations on, KEEP across the backward what is small to
+    # hold and dear to rebuild ("save_only_these_names" checkpoint policy,
+    # models/raft_stereo.REMAT_SAVED_NAMES): the correlation lookup's taps
+    # (the gather kernel), and the pre-activation sums of the three GRU gates
+    # at every scale, so the recompute pass re-runs no GRU convolution — only
+    # the gates' elementwise nonlinearities, the motion encoder, the flow
+    # head and the cross-scale pool / interpolation. Cost, bf16, per
+    # iteration and sample: the taps (H/2^K * W/2^K * levels*(2r+1), padded
+    # to 128 lanes) plus three hidden-state-sized tensors per GRU scale; at
+    # the reference recipe (22 iters, batch 4, 320x720 crops, K=2) 0.32 GB
+    # for the taps and 1.13 GB measured for the sums (1.36 GB by padded
+    # shape), for 25 ms of a 431 ms step (PERF.md section 6, PR 28). Off:
+    # plain remat, everything recomputed — the switch for a geometry that
+    # does not fit.
     remat_save_corr: bool = True
     # Emit `with_sharding_constraint` on the correlation pyramid and the GRU
     # hidden state, H rows over the mesh's spatial axis

@@ -38,7 +38,7 @@ from raft_stereo_tpu.models.extractor import (
     MultiBasicEncoder,
 )
 from raft_stereo_tpu.models.layers import Conv, ResidualBlock
-from raft_stereo_tpu.models.update import BasicMultiUpdateBlock, UpsampleMaskHead
+from raft_stereo_tpu.models.update import GATE_SUM, BasicMultiUpdateBlock, UpsampleMaskHead
 from raft_stereo_tpu.ops.corr import (
     corr_pyramid,
     corr_volume,
@@ -55,6 +55,16 @@ from raft_stereo_tpu.utils.geometry import (
 )
 
 Array = jax.Array
+
+# What the remat of the iteration body keeps across the backward when
+# `config.remat_save_corr` is on: the lookup's taps, and the pre-activation
+# sums of every GRU gate (named in models/update.py), so the recompute pass
+# re-runs no GRU convolution. Still recomputed, by choice (each buys under
+# 8 ms of a step per GB kept at the recipe's geometry, PERF.md section 6,
+# PR 28): the motion encoder, the flow head, the cross-scale pool /
+# interpolation.
+CORR_TAPS = "corr_taps"
+REMAT_SAVED_NAMES = (CORR_TAPS, GATE_SUM)
 
 
 def _corr_state(cfg: RAFTStereoConfig, fmap1: Array, fmap2: Array, fused: bool = False):
@@ -166,7 +176,7 @@ class _IterationBody(nn.Module):
         )
         # Named so the remat policy can keep the taps across backward
         # (config.remat_save_corr) instead of re-running the gather kernel.
-        corr = checkpoint_name(corr, "corr_taps")
+        corr = checkpoint_name(corr, CORR_TAPS)
         flow = (coords1 - coords0)[..., None]  # (B,H,W,1)
 
         update_block = BasicMultiUpdateBlock(
@@ -423,14 +433,15 @@ class RAFTStereo(nn.Module):
         factor = cfg.downsample_factor
 
         # remat: recompute the iteration's internals during backward instead
-        # of saving 22+ iterations of GRU/corr activations (config docstring).
+        # of saving 22+ iterations of GRU/corr activations (config docstring),
+        # but for REMAT_SAVED_NAMES, which the policy keeps.
         # prevent_cse=False: under scan the per-iteration CSE barrier is
         # unnecessary (jax.checkpoint docs) and costs fusion opportunities.
         # Never remat in test_mode: with no backward it buys nothing, and its
         # barriers make XLA re-copy the (loop-invariant) correlation state
         # every iteration at full-res scale.
         remat_policy = (
-            jax.checkpoint_policies.save_only_these_names("corr_taps")
+            jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
             if cfg.remat_save_corr
             else None
         )

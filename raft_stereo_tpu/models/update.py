@@ -26,6 +26,7 @@ from typing import Optional, Sequence, Tuple
 
 from flax import linen as nn
 import jax
+from jax.ad_checkpoint import checkpoint_name
 import jax.numpy as jnp
 
 from raft_stereo_tpu.models.layers import Conv, ConvParams, im2col_conv
@@ -33,6 +34,14 @@ from raft_stereo_tpu.obs.scopes import scoped
 from raft_stereo_tpu.utils.geometry import avg_pool2x, resize_bilinear_align_corners
 
 Array = jax.Array
+
+# The name the iteration body's checkpoint policy keeps across the backward
+# (raft_stereo.REMAT_SAVED_NAMES). It sits on the nonlinearity's INPUT: the
+# derivative rules of `logistic` and `tanh` read their own output variable, so
+# a name on z, r or q saves a copy nobody asks for while the convolution is
+# still re-run to rebuild the variable the rule wants. From the saved sum the
+# backward re-runs only the elementwise nonlinearity.
+GATE_SUM = "gru_gate_sum"
 
 
 class FlowHead(nn.Module):
@@ -164,9 +173,9 @@ class ConvGRU(nn.Module):
             rh = gates_pallas.fused_rh(rx, cr, h)
             qx = _segmented_conv3x3(kq, bq, (rh, *inputs))
             return gates_pallas.fused_combine(zx, cz, qx, cq, h)
-        z = jax.nn.sigmoid(_segmented_conv3x3(kz, bz, (h, *inputs)) + cz)
-        r = jax.nn.sigmoid(_segmented_conv3x3(kr, br, (h, *inputs)) + cr)
-        q = jnp.tanh(_segmented_conv3x3(kq, bq, (r * h, *inputs)) + cq)
+        z = jax.nn.sigmoid(checkpoint_name(_segmented_conv3x3(kz, bz, (h, *inputs)) + cz, GATE_SUM))
+        r = jax.nn.sigmoid(checkpoint_name(_segmented_conv3x3(kr, br, (h, *inputs)) + cr, GATE_SUM))
+        q = jnp.tanh(checkpoint_name(_segmented_conv3x3(kq, bq, (r * h, *inputs)) + cq, GATE_SUM))
         return (1.0 - z) * h + z * q
 
 

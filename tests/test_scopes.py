@@ -200,6 +200,12 @@ def test_compiled_train_step_tells_the_phases_apart(train_step_module):
         by_component[component].add(phase)
     assert {"loss", "optimizer", "encoder", "lookup", "gru08", "upsample"} <= set(by_component)
     assert by_component["gru08"] == {"forward", "backward", "recompute"}
+    # what `gru08` recomputes is elementwise: the policy keeps its gate sums
+    # (tests/test_remat_saved.py), and a forward convolution stays one here
+    convolutions = collections.Counter(
+        scopes.component(op_name, opcode)
+        for op_name, opcode in train_step_module.values() if opcode == "convolution")
+    assert convolutions["gru08", "forward"] > 0 and convolutions["gru08", "recompute"] == 0
     assert {"forward", "backward"} <= by_component["encoder"]
     assert {"forward", "backward"} <= by_component["loss"]
     assert by_component["optimizer"] == {"forward"}
