@@ -6,26 +6,16 @@ per tap). Nothing here knows how the program implements a layer: a
 restructured convolution, a recomputed activation or a padded channel adds
 no required operation. Training counts forward + backward as three forwards
 and no recomputation.
+
+The readers call a count one way, `fn(config, spec)` (benchmark/families.py):
+the functions at the end of this file are those, thin adapters that take the
+model and the stored pyramid's type from a configuration's file, the sizes
+from a workload's file, and leave the arithmetic above as it is.
 """
 
 from __future__ import annotations
 
-import json
-import os
 from typing import Dict, Tuple
-
-_HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-def peaks(device_kind: str) -> Dict[str, float]:
-    with open(os.path.join(_HERE, "peaks.json")) as f:
-        table = json.load(f)
-    if device_kind not in table:
-        raise KeyError(
-            f"device kind {device_kind!r} is not in benchmark/peaks.json "
-            f"(known: {sorted(table)}); add its published peaks with their source"
-        )
-    return table[device_kind]
 
 
 def conv_flops(oh: int, ow: int, kh: int, kw: int, cin: int, cout: int) -> int:
@@ -172,3 +162,40 @@ def scatter_bytes(cfg: Dict, h8: int, w8: int, grad_bytes: int, out_bytes: int) 
     read = (levels * (2 * r + 1) * grad_bytes + 4) * h8 * w8
     written = h8 * w8 * sum(_pyramid_widths(cfg, w8)) * out_bytes
     return read + written
+
+
+# -- what the layer metrics' files name: fn(config, spec) ---------------------
+
+_BYTES = {"bfloat16": 2, "float32": 4}
+
+
+def _stored_bytes(config: Dict) -> int:
+    """Bytes of a stored correlation, of a tap and of a tap's gradient: the
+    program keeps the pyramid, and the lookup's output with it, in the
+    configuration's `program.corr_dtype`."""
+    return _BYTES[config["program"]["corr_dtype"]]
+
+
+def inference_flops_per_map(config: Dict, spec: Dict) -> int:
+    return inference_flops(config["model"], *spec["image_hw"], spec["iters"])
+
+
+def train_flops_per_sample(config: Dict, spec: Dict) -> int:
+    return train_sample_flops(config["model"], *spec["image_hw"], spec["iters"])
+
+
+def lookup_flops_per_call(config: Dict, spec: Dict) -> int:
+    """One lookup (or its backward) of one pair: a call is a pair x an
+    iteration."""
+    model = config["model"]
+    return lookup_flops(model, *coarse_hw(model, *spec["image_hw"]))
+
+
+def lookup_bytes_per_call(config: Dict, spec: Dict) -> int:
+    model, width = config["model"], _stored_bytes(config)
+    return lookup_bytes(model, *coarse_hw(model, *spec["image_hw"]), width, width)
+
+
+def scatter_bytes_per_call(config: Dict, spec: Dict) -> int:
+    model, width = config["model"], _stored_bytes(config)
+    return scatter_bytes(model, *coarse_hw(model, *spec["image_hw"]), width, width)

@@ -4,7 +4,9 @@
     python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Everything a cell is comes from data: `BENCHMARK.json` names the cell, its
-configuration's file and the metrics; `workloads/<cell>.json` names the driver
+configuration's file and the metrics; the configuration's file names its
+family, and `families/<family>.json` the modules that hold the family's
+counts, reference and weight draw; `workloads/<cell>.json` names the driver
 and holds the traffic's parameters; `layer_metrics/<metric>.json` names the
 reader of each per-layer metric. This file holds no cell's constants.
 
@@ -140,8 +142,8 @@ def measure(
     t0: float = T0,
 ) -> dict:
     """Everything after the gate. `devices` are the jax devices the cell may
-    use; `data_dir` holds workloads/, layer_metrics/ (tests point it at a
-    throwaway copy)."""
+    use; `data_dir` holds workloads/, layer_metrics/, families/ (tests point
+    it at a throwaway copy)."""
     from raft_stereo_tpu.utils.compile_cache import setup_compile_cache
 
     import jax
@@ -188,9 +190,11 @@ def measure(
         reduced = tracer.reduce()
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
+        from benchmark import families
+
         context = {
             "window": window, "trace": reduced, "device": device, "config": config,
-            "spec": spec, "chips": cell["chips"],
+            "family": families.load(data_dir, config["family"]), "spec": spec, "chips": cell["chips"],
         }
         metrics = {}
         for metric in bench["per_layer"]:

@@ -15,6 +15,7 @@ import jax
 import numpy as np
 import pytest
 
+from bench_fixtures import compile_cache  # noqa: F401  (a fixture)
 from benchmark import run, traffic
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -42,37 +43,14 @@ needs = lambda name: pytest.mark.skipif(name not in TINY, reason=f"{name}'s cell
 
 
 @pytest.fixture(scope="module")
-def compile_cache(tmp_path_factory):
-    """The runs of this module compile the same programs again and again;
-    share them through a persistent cache of the module's own, and leave the
-    session as conftest.py set it up (cache off)."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    cache_dir = str(tmp_path_factory.mktemp("jax_cache"))
-    saved_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    saved_dir = jax.config.jax_compilation_cache_dir
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir  # setup_compile_cache() then sets nothing
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
-    yield cache_dir
-    jax.config.update("jax_enable_compilation_cache", False)
-    jax.config.update("jax_compilation_cache_dir", saved_dir)
-    compilation_cache.reset_cache()
-    if saved_env is None:
-        del os.environ["JAX_COMPILATION_CACHE_DIR"]
-    else:
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = saved_env
-
-
-@pytest.fixture(scope="module")
 def throwaway(tmp_path_factory, compile_cache):
     """BENCHMARK.json plus one entry per throwaway cell, and a data directory
     with one new workload file each: nothing that is there is edited."""
     bench = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     bench = copy.deepcopy(bench)
     data_dir = str(tmp_path_factory.mktemp("bench_data"))
-    shutil.copytree(os.path.join(BENCH_DIR, "layer_metrics"), os.path.join(data_dir, "layer_metrics"))
+    for sub in ("layer_metrics", "families"):
+        shutil.copytree(os.path.join(BENCH_DIR, sub), os.path.join(data_dir, sub))
     os.makedirs(os.path.join(data_dir, "workloads"))
     # A throwaway configuration too: the published model computed in float32,
     # since the CPU sums bf16 gradients in bf16 and reads gaps a TPU does not.

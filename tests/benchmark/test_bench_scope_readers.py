@@ -135,14 +135,46 @@ def test_program_span_takes_the_newest_calls_of_the_window():
     assert program_span.read(context({"attempted": 0}), root=calls, name="evaluate/stage") is None
 
 
+READERS = ("trace_scope", "program_span")
+BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+
+
+def _reader(metric):
+    return json.load(open(os.path.join(METRICS_DIR, metric + ".json")))["reader"]
+
+
+def _family(cell):
+    """The family of a cell's configuration."""
+    (config,) = [w["config"] for w in BENCH["workloads"] if w["name"] == cell]
+    (path,) = [c["file"] for c in BENCH["configs"] if c["name"] == config]
+    return json.load(open(os.path.join(ROOT, path)))["family"]
+
+
+# What the module and the spans above stand for is a RAFT-Stereo program: the
+# metrics of this pair of readers in that family's cells are run on them. A
+# metric of another family's cells comes with a fixture of its own.
 NEW_METRICS = sorted(
-    name[: -len(".json")] for name in os.listdir(METRICS_DIR)
-    if json.load(open(os.path.join(METRICS_DIR, name)))["reader"] in ("trace_scope", "program_span")
+    m["name"] for m in BENCH["per_layer"]
+    if _reader(m["name"]) in READERS and {_family(cell) for cell in m.get("workloads", ())} == {"raft-stereo"}
 )
 
 
-def test_the_new_metrics_are_the_fourteen():
-    assert len(NEW_METRICS) == 14, NEW_METRICS
+def test_every_scope_and_span_metric_is_declared_and_is_run_below():
+    """No count is pinned: the files these two readers serve are exactly the
+    `per_layer` entries whose file names one of them; each lists its cells,
+    all of one family; and each one of RAFT-Stereo's is a case of the
+    parametrised test below, none left out."""
+    on_disk = sorted(name[: -len(".json")] for name in os.listdir(METRICS_DIR)
+                     if name.endswith(".json") and _reader(name[: -len(".json")]) in READERS)
+    declared = sorted(m["name"] for m in BENCH["per_layer"] if _reader(m["name"]) in READERS)
+    assert on_disk == declared and declared
+    for metric in BENCH["per_layer"]:
+        if metric["name"] in declared:
+            assert metric.get("workloads"), metric["name"]
+            assert len({_family(cell) for cell in metric["workloads"]}) == 1, metric["name"]
+    (cases,) = [mark.args[1] for mark in test_new_layer_metric_reads_with_its_own_args.pytestmark
+                if mark.name == "parametrize"]
+    assert list(cases) == NEW_METRICS
 
 
 @pytest.mark.parametrize("metric", NEW_METRICS)
@@ -166,8 +198,7 @@ def test_new_layer_metric_reads_with_its_own_args(registered_module, metric):
     section = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
     layers = {line.split("|")[1].strip() for line in section.splitlines() if line.startswith("| ")}
     assert meta["layer"] in layers, (meta["layer"], layers)
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    (entry,) = [m for m in bench["per_layer"] if m["name"] == metric]
+    (entry,) = [m for m in BENCH["per_layer"] if m["name"] == metric]
     kind = metric.rsplit(".", 1)[1]
     assert entry["workloads"] and all(kind in cell for cell in entry["workloads"])
     assert entry["unit"] == ("%" if "_pct" in metric else "ms") and entry["better"] == "lower"
