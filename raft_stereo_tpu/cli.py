@@ -159,9 +159,31 @@ def _load_variables(restore_ckpt: Optional[str], config: RAFTStereoConfig):
     return jax.tree.map(jnp.asarray, load_variables(restore_ckpt, config))
 
 
+def _token_model_config(args):
+    """The `sdar-moe` family's model from a published `config.json`-shaped
+    file; a `program` group in it (expert_parallel, expert_shard,
+    block_length, ...; benchmark/configs/ has one) sets the chip's share and
+    the program's own keys."""
+    import json
+
+    from raft_stereo_tpu.config import SDARMoEConfig
+
+    with open(args.token_config) as f:
+        published = json.load(f)
+    return SDARMoEConfig.from_hf_config(published, **published.get("program", {}))
+
+
 def _train_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(prog="train")
     p.add_argument("--name", default="raft-stereo")
+    p.add_argument("--token_config", default=None,
+                   help="train the sdar-moe family (a routed-expert decoder "
+                   "under block diffusion) instead of RAFT-Stereo: path to a "
+                   "config.json-shaped file, optionally with a `program` "
+                   "group; batches come from a seeded Zipf source "
+                   "(data/tokens.py)")
+    p.add_argument("--seq_len", type=int, default=4096,
+                   help="tokens a sample (token family only)")
     p.add_argument("--restore_ckpt", default=None)
     p.add_argument("--auto_resume", action="store_true",
                    help="at startup, restore the newest checkpoint of this "
@@ -397,7 +419,7 @@ def cmd_train(argv: List[str]) -> int:
 
 def _train_config_from_args(args) -> TrainConfig:
     return TrainConfig(
-        model=_model_config(args),
+        model=_token_model_config(args) if args.token_config else _model_config(args),
         augment=AugmentConfig(
             crop_size=tuple(args.image_size),
             min_scale=args.spatial_scale[0],
@@ -460,6 +482,16 @@ def _run_train(args, config: TrainConfig) -> int:
 
         setup_compile_cache(config.compilation_cache_dir)
         init_multihost()  # no-op single-host; connects the pod otherwise
+        if args.token_config:
+            trainer, loader = _token_trainer(args, config)
+            if getattr(args, "explain_sharding", False):
+                print(trainer.explain_sharding())
+                return 0
+            maybe_resume(trainer, config)
+            return run_training(
+                trainer, loader,
+                metrics_logger=MetricsLogger(log_every=config.log_every, log_dir=config.log_dir),
+            )
         if getattr(args, "explain_sharding", False):
             # Dry run: initialize the state tree and dump every leaf ->
             # PartitionSpec decision, without touching datasets or ckpts.
@@ -522,6 +554,19 @@ def _run_train(args, config: TrainConfig) -> int:
         metrics_logger=MetricsLogger(log_every=config.log_every, log_dir=config.log_dir),
         validate_fn=validate_fn,
     )
+
+
+def _token_trainer(args, config: TrainConfig):
+    """The token family's trainer and its loader: the same `Trainer`, a
+    seeded token source (data/tokens.py), no validation set."""
+    from raft_stereo_tpu.data.tokens import TokenBatches
+    from raft_stereo_tpu.train.trainer import Trainer
+
+    model = config.model
+    # The mask token's row is never data.
+    rows = model.mask_token_id if model.mask_token_id == model.vocab_size - 1 else model.vocab_size
+    loader = TokenBatches(config.batch_size, args.seq_len, model.block_length, rows, seed=config.seed)
+    return Trainer(config, sample_shape=(args.seq_len,)), loader
 
 
 def cmd_evaluate(argv: List[str]) -> int:

@@ -11,7 +11,7 @@ and demo, and hashes cleanly as a static argument under `jax.jit`.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 # Data modalities of the gated-stereo fork (reference core/extractor.py:140-143):
 # "RGB" and "1 Passive Gated" are 3-channel, "All Gated" stacks 5 gated slices.
@@ -210,6 +210,79 @@ class RAFTStereoConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SDARMoEConfig:
+    """The second model family, `sdar-moe`: a routed-expert decoder
+    (`model_type: sdar_moe`, a Qwen3-MoE block) trained by block diffusion
+    (BD3-LM, arXiv:2503.09573). Key names are the published `config.json`'s;
+    `from_hf_config` reads such a file. Defaults are the 30B-A3B release.
+
+    The counts may be ONE CHIP'S SHARE of a deployment that divides every
+    layer over `expert_parallel` chips: `num_experts` is the experts HELD
+    here (the router still scores `num_experts * expert_parallel` and picks
+    `num_experts_per_tok` among all of them; this chip holds experts
+    `expert_shard * num_experts ...`), and `vocab_size` the vocabulary rows
+    held here (ids, logits and loss are over that slice). What absent experts
+    would add is left out; nothing stands in for the exchange."""
+
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 768
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1e6
+    # -- the chip's share --
+    expert_parallel: int = 1
+    expert_shard: int = 0
+    # -- block diffusion --
+    block_length: int = 4
+    mask_token_id: int = 151935
+    # -- program --
+    mixed_precision: bool = True  # bf16 compute, float32 parameters
+    remat_layers: bool = True
+    # Static bounds of the expert layer (ops/grouped_matmul.py): positions
+    # are walked in chunks of `moe_chunk`, each with row buffers for the
+    # worst case (every choice of every position held here), so no token is
+    # dropped at any imbalance and no buffer grows with the batch.
+    moe_chunk: int = 4096
+    moe_tile_rows: int = 128
+    attention_tile: int = 512
+    # Positions a pass of the output head and loss holds logits for.
+    loss_chunk: int = 4096
+
+    @property
+    def router_width(self) -> int:
+        return self.num_experts * self.expert_parallel
+
+    def __post_init__(self):
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+        if not 0 <= self.expert_shard < self.expert_parallel:
+            raise ValueError(f"expert_shard {self.expert_shard} not in [0, {self.expert_parallel})")
+        if self.num_experts_per_tok > self.router_width:
+            raise ValueError("num_experts_per_tok exceeds the router's width")
+        if not 0 <= self.mask_token_id < self.vocab_size:
+            raise ValueError(f"mask_token_id {self.mask_token_id} is not a row of the {self.vocab_size} held")
+
+    @classmethod
+    def from_hf_config(cls, published: Dict[str, Any], **program) -> "SDARMoEConfig":
+        """From a `config.json`-shaped dict (keys this class does not model
+        are ignored) and the program's own keys."""
+        names = {f.name for f in dataclasses.fields(cls)}
+        merged = {k: v for k, v in {**published, **program}.items() if k in names}
+        merged.setdefault("mask_token_id", merged.get("vocab_size", cls.vocab_size) - 1)
+        return cls(**merged)
+
+
+ModelConfig = Union[RAFTStereoConfig, SDARMoEConfig]
+
+
+@dataclasses.dataclass(frozen=True)
 class CameraConfig:
     """Gated-stereo rig intrinsics, hardcoded in the reference
     (core/utils/frame_utils.py:127-128, demo.py:21-22)."""
@@ -241,7 +314,9 @@ class AugmentConfig:
 class TrainConfig:
     """Training-loop config (reference train_stereo.py:234-272)."""
 
-    model: RAFTStereoConfig = dataclasses.field(default_factory=RAFTStereoConfig)
+    # One of the two model families: the trainer takes how to initialise,
+    # what a batch holds and the loss from it (train/families.py).
+    model: ModelConfig = dataclasses.field(default_factory=RAFTStereoConfig)
     augment: AugmentConfig = dataclasses.field(default_factory=AugmentConfig)
     camera: CameraConfig = dataclasses.field(default_factory=CameraConfig)
 

@@ -64,7 +64,8 @@ class DevicePrefetcher:
     fraction of consumer fetches that found the next batch already staged
     (i.e. the transfer genuinely overlapped the step)."""
 
-    def __init__(self, loader: Any, sharding: Any, hygiene: Optional[Any] = None):
+    def __init__(self, loader: Any, sharding: Any, hygiene: Optional[Any] = None, batch_keys=BATCH_KEYS):
+        self._batch_keys = tuple(batch_keys)
         self._loader = loader
         self._sharding = sharding
         self._hygiene = hygiene
@@ -130,7 +131,7 @@ class DevicePrefetcher:
                     for batch in self._loader:
                         if stop.is_set():
                             break
-                        arrays = {k: batch[k] for k in BATCH_KEYS}
+                        arrays = {k: batch[k] for k in self._batch_keys}
                         placed = self._sharding.place_batch(arrays)
                         # Snapshot AFTER the pull: the loader's cursor now
                         # sits just past this batch, which is exactly what a

@@ -21,7 +21,12 @@ Scopes the program writes (beside flax's module names `cnet`, `fnet`,
 `mask_head`): `corr_build` (ops/corr.py, ops/corr_pallas.py), `corr_lookup`
 (every lookup implementation and its VJP), `interp_pool` (models/update.py),
 `upsample` (utils/geometry.py), `sequence_loss` (train/loss.py),
-`grad_clip` and `optimizer` (train/optimizer.py, train/trainer.py).
+`grad_clip` and `optimizer` (train/optimizer.py, train/trainer.py). The
+`sdar-moe` family (models/sdar_moe.py) writes flax's module names `embed`,
+`layers/{input_norm,attention,post_attention_norm,router,experts}`, `norm`,
+`lm_head`, and the scopes `block_attention` (ops/block_attention.py, under
+`attention`), `grouped_matmul` (ops/grouped_matmul.py, under `experts`) and
+`block_diffusion_loss`; the two norms before a sublayer count with it.
 
 Pure: jax is touched only by `scoped` (at trace time) and `abstract`; nothing
 is lowered or parsed until `registered()` is called.
@@ -41,8 +46,10 @@ Scopes = Dict[str, Tuple[str, str]]  # instruction name -> (op_name, opcode)
 
 COMPONENTS = (
     "encoder", "corr_build", "lookup", "motion_encoder", "gru08", "gru16",
-    "gru32", "flow_head", "interp_pool", "mask_head", "upsample", "loss",
-    "optimizer", "collective", "other", "unscoped",
+    "gru32", "flow_head", "interp_pool", "mask_head", "upsample",
+    # the `sdar-moe` family's
+    "embed", "attention", "router", "experts", "lm_head",
+    "loss", "optimizer", "collective", "other", "unscoped",
 )
 PHASES = ("forward", "backward", "recompute")
 
@@ -62,7 +69,12 @@ _ROWS = tuple(
         (r"interp_pool", "interp_pool"),
         (r"mask_head", "mask_head"),
         (r"upsample", "upsample"),
-        (r"sequence_loss", "loss"),
+        (r"embed", "embed"),
+        (r"attention|input_norm", "attention"),
+        (r"router|post_attention_norm", "router"),
+        (r"experts", "experts"),
+        (r"lm_head(?:\.\w+)?|norm", "lm_head"),  # flax names a method other than __call__ `module.method`
+        (r"sequence_loss|block_diffusion_loss", "loss"),
         (r"grad_clip|optimizer", "optimizer"),
     )
 )

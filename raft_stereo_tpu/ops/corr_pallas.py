@@ -50,12 +50,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
-from jax.sharding import PartitionSpec as P
-
 from raft_stereo_tpu.obs.scopes import scoped
 from raft_stereo_tpu.ops.corr import corr_pyramid, corr_volume
+from raft_stereo_tpu.ops.data_axis import over_data_axis
 from raft_stereo_tpu.ops.pallas_mode import pallas_interpret
-from raft_stereo_tpu.parallel.mesh import DATA_AXIS, SPATIAL_AXIS
 
 Array = jax.Array
 
@@ -92,43 +90,6 @@ def _query_layout(coords: Array):
         ((0, 0), (0, w1_pad - w1), (0, 0)),
     )
     return rows, w1_blk, w1_pad, coords_flat
-
-
-def _rows_over_data_axis(call, *operands):
-    """Run a row-gridded kernel call — every operand and result laid out
-    (rows = B*H, ...) — on the mesh a multi-device step is traced under.
-
-    XLA cannot partition a Mosaic kernel ("Mosaic kernels cannot be
-    automatically partitioned", raised by the chip's compiler for the
-    data-parallel train step), so the call is `shard_map`ped: each device
-    runs the kernel on its own rows. Rows are batch-major, so splitting them
-    over the data axis is exactly the batch sharding the operands already
-    have — no resharding. `call` must size its grid from the shapes it is
-    handed (they are the per-device shapes inside the map).
-
-    The mesh is jax's own context mesh (`jax.set_mesh`, entered by
-    ShardingEngine.wrap around every multi-device step), which is part of
-    the trace cache key. With none set — every single-device path — this is
-    a plain call."""
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.size == 1:
-        return call(*operands)
-    if mesh.shape.get(SPATIAL_AXIS, 1) > 1:
-        raise NotImplementedError(
-            "the Pallas correlation kernels are wired for the data mesh axis "
-            f"only, and this mesh is {dict(mesh.shape)}; use "
-            "corr_implementation='reg' with a spatial preset on several devices"
-        )
-    rows = operands[0].shape[0]
-    if rows % mesh.shape[DATA_AXIS]:
-        raise ValueError(
-            f"{rows} correlation rows (batch x height) do not divide over the "
-            f"{mesh.shape[DATA_AXIS]} devices of the data axis"
-        )
-    spec = P(DATA_AXIS)
-    return jax.shard_map(
-        call, in_specs=(spec,) * len(operands), out_specs=spec, check_vma=False
-    )(*operands)
 
 
 def _lookup_kernel(coords_ref, *rest, radius: int, w2_padded: Tuple[int, ...]):
@@ -297,7 +258,7 @@ def _scatter_pallas_padded(
             name="corr_scatter",
         )(coords_flat, grad_flat)
 
-    return _rows_over_data_axis(call, coords_flat, grad_flat)
+    return over_data_axis(call, (coords_flat, grad_flat))
 
 
 @scoped("corr_build")
@@ -377,7 +338,7 @@ def _lookup_pallas_padded(padded, coords: Array, radius: int, out_dtype=jnp.floa
             name="corr_lookup",
         )(coords_flat, *padded)
 
-    out = _rows_over_data_axis(call, coords_flat, *padded)
+    out = over_data_axis(call, (coords_flat, *padded))
     return out[:, :w1, :].reshape(b, h, w1, num_levels * k)
 
 

@@ -202,6 +202,9 @@ def make_shard_and_gather_fns(mesh: Mesh, spec_tree):
 BATCH_RULES: Tuple[Rule, ...] = (
     (r"^(image1|image2|flow)$", P(DATA_AXIS, SPATIAL_AXIS, None, None)),
     (r"^valid$", P(DATA_AXIS, SPATIAL_AXIS, None)),
+    # The token family's batch (train/families.py): rows over the data axis,
+    # a row's positions (or its blocks' noise levels) whole.
+    (r"^(tokens|masked|noise_t)$", P(DATA_AXIS, None)),
     (r".*", P()),
 )
 
@@ -221,13 +224,24 @@ REPLICATE_ALL: Tuple[Rule, ...] = ((r".*", P()),)
 # demoted to replicated by `ShardingEngine.state_specs` — same
 # divide-evenly-or-leave-alone policy `constrain_spatial` applies to ragged
 # pyramid levels.
+# The `sdar-moe` tree (models/sdar_moe.py) has no leaf called `kernel`: its
+# matrices are `w_*` with every layer stacked on a leading axis, and the
+# same placement splits each one's LAST axis (a product's output features)
+# over the data axis: the experts' (layers, experts, in, out), the attention
+# and router matrices' (layers, in, out), the embedding's and the head's
+# (rows, features) / (features, rows). Spreading the EXPERTS over an axis,
+# with the all-to-all that needs, is not among these presets.
 FSDP_RULES: Tuple[Rule, ...] = (
     (r"kernel$", P(None, None, None, DATA_AXIS)),
+    (r"experts/w_(gate|up|down)$", P(None, None, None, DATA_AXIS)),
+    (r"(attention/w_[qkvo]|router/w_router)$", P(None, None, DATA_AXIS)),
+    (r"(embed/embedding|lm_head/w_head)$", P(None, DATA_AXIS)),
     (r".*", P()),
 )
 
-# The canonical train-batch template (name -> rank); mirrors what the data
-# pipeline emits and what the legacy batch_sharding_tree hard-wired.
+# The stereo family's train-batch template (name -> rank); mirrors what the
+# data pipeline emits and what the legacy batch_sharding_tree hard-wired. A
+# trainer of another family passes its own (train/families.py).
 BATCH_TEMPLATE: Dict[str, int] = {"image1": 4, "image2": 4, "flow": 4, "valid": 3}
 
 
@@ -481,11 +495,14 @@ class ShardingEngine:
             out[name] = NamedSharding(self.mesh, spec)
         return out
 
-    def input_sharding(self, ndim: int = 4) -> NamedSharding:
-        """Sharding for a single image-like input of the given rank (the
-        test-mode forward and serving staging path)."""
+    def input_sharding(self, ndim: int = 4, name: Optional[str] = None) -> NamedSharding:
+        """Sharding for a single input placed as the batch leaf `name` is.
+        Without a name the input is image-like (the test-mode forward and
+        serving staging path): `image1` at rank 4, `valid` below."""
+        if name is None:
+            name = "image1" if ndim == 4 else "valid"
         probe = jax.ShapeDtypeStruct((2,) * ndim, np.float32)
-        _, spec = _match_leaf(self.preset.batch_rules, "image1" if ndim == 4 else "valid", probe)
+        _, spec = _match_leaf(self.preset.batch_rules, name, probe)
         return NamedSharding(self.mesh, spec)
 
     # -- placement ----------------------------------------------------------

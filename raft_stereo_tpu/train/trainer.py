@@ -34,13 +34,12 @@ import numpy as np
 import optax
 
 from raft_stereo_tpu.config import TrainConfig, finalize_train_config
-from raft_stereo_tpu.models import RAFTStereo, init_model_variables
 from raft_stereo_tpu.obs import scopes
 from raft_stereo_tpu.obs.trace import span
 from raft_stereo_tpu.parallel.mesh import make_mesh
 from raft_stereo_tpu.parallel.sharding import ShardingEngine
+from raft_stereo_tpu.train.families import family_of, make_loss
 from raft_stereo_tpu.train.io_spine import AsyncCheckpointCommitter, build_io_spine_block
-from raft_stereo_tpu.train.loss import sequence_loss
 from raft_stereo_tpu.train.optimizer import make_optimizer
 
 logger = logging.getLogger(__name__)
@@ -61,18 +60,13 @@ class TrainState(struct.PyTreeNode):
 
 
 def create_train_state(
-    config: TrainConfig, rng: jax.Array, sample_shape: Tuple[int, int, int]
+    config: TrainConfig, rng: jax.Array, sample_shape: Tuple[int, ...]
 ) -> Tuple[TrainState, optax.GradientTransformation, optax.Schedule]:
-    """Initialize model params + optimizer. `sample_shape` is (H, W, C) of one
-    image; init runs on a batch of 1 (shapes don't affect params)."""
-    h, w, c = sample_shape
-    # Per-config cached jitted init (models/init_cache.py): a fresh
-    # jax.jit wrapper here would re-compile flax init for every Trainer
-    # construction; eager init is worse still (hundreds of tiny per-op XLA
-    # compiles — tests/conftest.py docstring).
-    variables = init_model_variables(
-        config.model, image_hw=(h, w), rng=rng, channels=c
-    )
+    """Initialize model params + optimizer. `sample_shape` is the shape of
+    one sample as the model's family reads it (train/families.py): (H, W, C)
+    of an image, (L,) tokens; init runs on a batch of 1 (shapes don't affect
+    params)."""
+    variables = family_of(config.model, sample_shape).init_variables(config, rng)
     tx, schedule = make_optimizer(
         config.lr, config.num_steps, config.wdecay, config.grad_clip_norm
     )
@@ -90,25 +84,20 @@ def make_train_step(
     tx: optax.GradientTransformation,
     schedule: Optional[optax.Schedule] = None,
 ):
-    """Build the jitted sharded train step. Batch dict:
-    image1/image2 (B,H,W,C), flow (B,H,W,1), valid (B,H,W).
+    """Build the jitted sharded train step. The batch dict and the loss are
+    the model family's (train/families.py; for a RAFTStereoConfig:
+    image1/image2 (B,H,W,C), flow (B,H,W,1), valid (B,H,W)). The metrics dict
+    carries whatever the family's loss reports beside `live_loss` and
+    `grad_norm`.
 
     When `schedule` is given, the per-step learning rate rides the metrics
     dict — the reference Logger writes `learning_rate` every 100 steps
     (/root/reference/train_stereo.py:92,190-191)."""
-    model = RAFTStereo(config.model)
+    family_loss = make_loss(config)
 
     def step_fn(state: TrainState, batch: Dict[str, jax.Array]):
         def loss_fn(params):
-            flows = model.apply(
-                {"params": params, "batch_stats": state.batch_stats},
-                batch["image1"],
-                batch["image2"],
-                iters=config.train_iters,
-            )
-            return sequence_loss(
-                flows, batch["flow"], batch["valid"], config.loss_gamma, config.max_flow
-            )
+            return family_loss(params, state.batch_stats, batch)
 
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params)
         with jax.named_scope("grad_clip"):
@@ -169,17 +158,19 @@ def _restore_host_rng(snapshot: Dict[str, Any]) -> None:
 class Trainer:
     """Owns mesh, state, the compiled step, and checkpointing."""
 
-    def __init__(self, config: TrainConfig, sample_shape: Tuple[int, int, int]):
+    def __init__(self, config: TrainConfig, sample_shape: Tuple[int, ...]):
         # Resolve backend-dependent defaults (nan_check_every, coord_interval)
         # once, here — everything downstream sees concrete values.
         self.config = config = finalize_train_config(config)
-        self._sample_shape = tuple(sample_shape)  # (H, W, C) — hlo_audit_record
+        # How to initialise and what a batch holds (train/families.py);
+        # `sample_shape` is one sample's: (H, W, C) of an image, (L,) tokens.
+        self.family = family_of(config.model, sample_shape)
         self.mesh = make_mesh(config.mesh_shape)
         # All in/out shardings, batch placement, and activation constraints
         # come from the rule engine; the `dp` preset reproduces the old
         # hand-wired layout (replicated state, batch over data) exactly.
         self.sharding = ShardingEngine(self.mesh, config.sharding_rules)
-        if self.sharding.constrain_activations and not config.model.spatial_constraints:
+        if self.sharding.constrain_activations and not getattr(config.model, "spatial_constraints", True):
             # Spatial presets pin the corr pyramid + GRU hidden state to
             # H-row shards from inside the model (raft_stereo.py). The flag
             # changes no params and no math — only constraint emission — so
@@ -204,7 +195,7 @@ class Trainer:
         self.train_step = self.sharding.wrap(
             jax.jit(
                 make_train_step(config, self.tx, self.schedule),
-                in_shardings=(state_shardings, self.sharding.batch_shardings()),
+                in_shardings=(state_shardings, self._batch_shardings()),
                 out_shardings=(state_shardings, self.sharding.replicated()),
                 donate_argnums=(0,),
             )
@@ -262,7 +253,7 @@ class Trainer:
     def explain_sharding(self) -> str:
         """Every leaf -> PartitionSpec decision for this run's state tree and
         batch layout (the `train --explain_sharding` payload)."""
-        return self.sharding.explain(self.state)
+        return self.sharding.explain(self.state, self._batch_template())
 
     def _abstract_batch(self) -> Dict[str, jax.ShapeDtypeStruct]:
         """One global batch as abstract shapes (no allocation), each under
@@ -270,14 +261,20 @@ class Trainer:
         step is the very module a fit runs (a batch without shardings lowers
         to a module that differs in nothing but its name, and misses the
         compile cache)."""
-        h, w, c = self._sample_shape
-        b = self.config.batch_size
-        shapes = {"image1": (b, h, w, c), "image2": (b, h, w, c), "flow": (b, h, w, 1), "valid": (b, h, w)}
-        shardings = self.sharding.batch_shardings()
+        shardings = self._batch_shardings()
         return {
-            name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=shardings[name])
-            for name, shape in shapes.items()
+            name: jax.ShapeDtypeStruct(shape, dtype, sharding=shardings[name])
+            for name, (shape, dtype) in self.family.batch_shapes(self.config.batch_size).items()
         }
+
+    def _batch_template(self) -> Dict[str, int]:
+        """The family's batch leaves, name -> rank, as the rule engine reads
+        a template."""
+        shapes = self.family.batch_shapes(self.config.batch_size)
+        return {name: len(shape) for name, (shape, _) in shapes.items()}
+
+    def _batch_shardings(self):
+        return self.sharding.batch_shardings(self._batch_template())
 
     def _register_program(self) -> None:
         """Tell obs.scopes how to print the optimized module of the train
@@ -303,8 +300,6 @@ class Trainer:
         )
 
         cfg = self.config
-        h, w, _ = self._sample_shape
-        b = cfg.batch_size
         batch = self._abstract_batch()
         compiled = self.train_step.lower(self.state, batch).compile()
         preset = cfg.sharding_rules
@@ -317,10 +312,9 @@ class Trainer:
             carry_out_index=0,
             donated_params=donated_param_numbers((self.state, batch), (0,)),
             meta={
-                "corr_dtype": cfg.model.corr_dtype,
+                **self.family.audit_meta(cfg),
                 "mesh_shape": list(cfg.mesh_shape),
-                "batch_size": b,
-                "sample": [h, w],
+                "batch_size": cfg.batch_size,
             },
         )
 
@@ -745,10 +739,11 @@ class Trainer:
         # tell it from the loader. Its batches arrive already placed on the
         # mesh; the step loop below skips its own place_batch for them.
         prefetcher = None
+        batch_keys = tuple(self.family.batch_shapes(cfg.batch_size))
         if cfg.device_prefetch:
             from raft_stereo_tpu.data.prefetch import DevicePrefetcher
 
-            data = prefetcher = DevicePrefetcher(data, self.sharding, hygiene=hygiene)
+            data = prefetcher = DevicePrefetcher(data, self.sharding, hygiene=hygiene, batch_keys=batch_keys)
         quarantine = getattr(data, "quarantine", None)
         if coord.active and hasattr(data, "set_global_budget_mode"):
             # Budget decisions become pod-global: the loader keeps counting
@@ -1089,7 +1084,7 @@ class Trainer:
                                 # thread — while the PREVIOUS step ran.
                                 device_batch = batch
                             else:
-                                arrays = {k: v for k, v in batch.items() if k in ("image1", "image2", "flow", "valid")}
+                                arrays = {k: v for k, v in batch.items() if k in batch_keys}
                                 device_batch = self.sharding.place_batch(arrays)
                             self.state, metrics = self.train_step(self.state, device_batch)
                         tick = time.perf_counter()

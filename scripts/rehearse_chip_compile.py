@@ -10,6 +10,11 @@ Programs, at the sizes `chip_smoke.py` drives:
   serve     prelude / chunk / finalize at the 384x1248 bucket, batch 1 and 2
   train     the train step, batch 4, 320x720 crops, 22 iters, one chip
   train-dp  the same step on a (4, 1) data mesh, global batch 8
+  train-tokens[-N]  the token family's step as the benchmark's cell runs it
+            (benchmark/configs/sdar-30b-a3b-ep8-shard.json, batch 4 x 4096
+            tokens), at the file's depth or at N layers; not in the default set
+  train-tokens-dp   the same step on a (4, 1) data mesh, global batch 8 (the
+            kernels shard_mapped over the data axis); not in the default set
 
   JAX_PLATFORMS=cpu python scripts/rehearse_chip_compile.py [names...]
 
@@ -103,40 +108,52 @@ def serve(chip):
         _report(f"serve finalize 384x1248 b{batch}", finalize.lower(variables, state))
 
 
-def _train(name, devices, mesh_shape, batch):
+def _token_model(layers=None):
+    import dataclasses
+    import json
+
+    from raft_stereo_tpu.config import SDARMoEConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "sdar-30b-a3b-ep8-shard.json")) as f:
+        published = json.load(f)
+    model = SDARMoEConfig.from_hf_config(published, **published["program"])
+    return dataclasses.replace(model, num_hidden_layers=layers) if layers else model
+
+
+def _train(name, devices, mesh_shape, batch, model=MODEL, sample=(320, 720, 3)):
     """The Trainer's own step, shardings and trace scope (train/trainer.py
     __init__), on a mesh of described devices instead of jax.devices()."""
     import numpy as np
+
+    from raft_stereo_tpu.train.families import family_of
 
     from raft_stereo_tpu.parallel.mesh import DATA_AXIS, SPATIAL_AXIS
     from raft_stereo_tpu.parallel.sharding import ShardingEngine
     from raft_stereo_tpu.train.trainer import create_train_state, make_train_step
 
     cfg = TrainConfig(
-        model=MODEL, batch_size=batch, train_iters=22, mesh_shape=mesh_shape
+        model=model, batch_size=batch, train_iters=22, mesh_shape=mesh_shape
     )
+    shapes = family_of(model, sample).batch_shapes(batch)
     mesh = Mesh(np.asarray(devices).reshape(mesh_shape), (DATA_AXIS, SPATIAL_AXIS))
     engine = ShardingEngine(mesh, cfg.sharding_rules)
     made = {}
 
     def build(rng):
-        state, made["tx"], made["schedule"] = create_train_state(cfg, rng, (320, 720, 3))
+        state, made["tx"], made["schedule"] = create_train_state(cfg, rng, sample)
         return state
 
     state_shapes = jax.eval_shape(build, jax.random.PRNGKey(0))
     state_shardings = engine.state_shardings(state_shapes)
-    batch_shardings = engine.batch_shardings()
+    batch_shardings = engine.batch_shardings({k: len(shape) for k, (shape, _) in shapes.items()})
     state = jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
         state_shapes, state_shardings,
     )
-    shapes = {
-        "image1": (batch, 320, 720, 3), "image2": (batch, 320, 720, 3),
-        "flow": (batch, 320, 720, 1), "valid": (batch, 320, 720),
-    }
     data = {
-        k: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=batch_shardings[k])
-        for k, shape in shapes.items()
+        k: jax.ShapeDtypeStruct(shape, dtype, sharding=batch_shardings[k])
+        for k, (shape, dtype) in shapes.items()
     }
     step = engine.wrap(
         jax.jit(
@@ -168,8 +185,15 @@ def main(names):
             _train("train b4 320x720x22", topo.devices[:1], (1, 1), 4)
         elif name == "train-dp":
             _train("train-dp (4,1) b8 320x720x22", topo.devices, (4, 1), 8)
+        elif name == "train-tokens-dp":
+            _train("train-tokens-dp (4,1) b8 x 4096", topo.devices, (4, 1), 8, _token_model(), (4096,))
+        elif name.startswith("train-tokens"):
+            layers = int(name.split("-")[2]) if name.count("-") == 2 else None
+            model = _token_model(layers)
+            _train(f"train-tokens b4 x 4096, {model.num_hidden_layers} layers", topo.devices[:1], (1, 1), 4,
+                   model, (4096,))
         else:
-            raise SystemExit(f"unknown program {name!r}; choose from {PROGRAMS}")
+            raise SystemExit(f"unknown program {name!r}; choose from {PROGRAMS} or train-tokens[-N]")
 
 
 if __name__ == "__main__":

@@ -272,7 +272,10 @@ def _kernel_calls():
     x = jnp.ones((1, 8, 16, 128), jnp.float32)
     aff = jnp.ones((1, 2, 128), jnp.float32)
     g = jnp.ones((1, 8, 16, 32))
+    attention, grouped = _token_kernel_calls()
     return {
+        **attention,
+        **grouped,
         "corr_lookup": lambda: corr_pallas.pallas_corr_lookup_padded(state, coords, 2),
         "corr_scatter": lambda: jax.grad(
             lambda s: corr_pallas.pallas_corr_lookup_padded(s, coords, 2).sum())(state),
@@ -288,7 +291,32 @@ def _kernel_calls():
     }
 
 
+def _token_kernel_calls():
+    """The `sdar-moe` family's kernels at a tiny size: 2 x 16 positions in
+    blocks of 4; 16 rows in tiles of 8 over 2 experts."""
+    from raft_stereo_tpu.ops import block_attention as ba
+    from raft_stereo_tpu.ops import grouped_matmul as gm
+
+    q, kv = jnp.ones((1, 2, 32, 8)), jnp.ones((1, 1, 32, 8))
+    attend = lambda q, k, v: ba.block_attention(q, k, v, 16, 4, 8).sum()
+    layout = gm.group_layout(jnp.asarray([0, 1, 2, 1] * 4, jnp.int32), 2, 8)
+    groups = (layout["tile_expert"], layout["num_tiles"], 8)
+    lhs, rhs = jnp.ones((gm.rows_bound(16, 2, 8), 8)), jnp.ones((2, 8, 16))
+    product = lambda lhs, rhs: gm.grouped_matmul(lhs, rhs, *groups).sum()
+    attention = {
+        "block_attention": lambda: attend(q, kv, kv),
+        "block_attention_dq": lambda: jax.grad(attend, 0)(q, kv, kv),
+        "block_attention_dkv": lambda: jax.grad(attend, 1)(q, kv, kv),
+    }
+    grouped = {
+        "grouped_matmul": lambda: product(lhs, rhs),
+        "grouped_matmul_drhs": lambda: jax.grad(product, 1)(lhs, rhs),
+    }
+    return attention, grouped
+
+
 KERNELS = [
+    "block_attention", "block_attention_dq", "block_attention_dkv", "grouped_matmul", "grouped_matmul_drhs",
     "corr_lookup", "corr_scatter", "corr_lookup_prefetch", "corr_pyramid", "encoder_conv_s2d",
     "encoder_join", "gates_rh", "gates_combine", "gru_tail", "motion_tail",
 ]

@@ -1,0 +1,393 @@
+"""The `sdar-moe` family on the CPU at a tiny size (hidden 64, 4 heads of 16
+on 2 key-value heads, 8 experts of 32 with 2 a token, 2 layers, 32 tokens a
+row): the decoder against the plain reference on seeded weights, the two
+kernels against their dense forms, the chip's share against the uncut model,
+the trainer's rules, scopes and entry point for the family, and the stereo
+step left as it was.
+"""
+
+import hashlib
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import sdar_reference
+from raft_stereo_tpu.config import RAFTStereoConfig, SDARMoEConfig, TrainConfig
+from raft_stereo_tpu.models import sdar_moe
+from raft_stereo_tpu.ops import block_attention as ba
+from raft_stereo_tpu.ops import grouped_matmul as gm
+
+SEQ = 32
+PUBLISHED = dict(
+    vocab_size=96, hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32, norm_topk_prob=True, rms_norm_eps=1e-6,
+    rope_theta=1e6)
+TILES = dict(mixed_precision=False, moe_chunk=64, moe_tile_rows=8, attention_tile=16, loss_chunk=32)
+
+
+def _configs(block_length=4, expert_parallel=1, expert_shard=0, **tiles):
+    """(the program's config, the configuration-file dict the reference
+    reads) for one chip's share of the tiny model."""
+    published = dict(PUBLISHED, num_experts=PUBLISHED["num_experts"] // expert_parallel)
+    program = dict(expert_parallel=expert_parallel, expert_shard=expert_shard, block_length=block_length,
+                   mask_token_id=95)
+    config = SDARMoEConfig.from_hf_config(published, **program, **dict(TILES, **tiles))
+    return config, dict(published, program=program)
+
+
+def _batch(block_length, batch=2, seed=0):
+    rng = np.random.default_rng(seed)
+    tokens = jnp.asarray(rng.integers(0, 95, (batch, SEQ)), jnp.int32)
+    noise_t = jnp.asarray(rng.uniform(0.001, 1.0, (batch, SEQ // block_length)), jnp.float32)
+    masked = jnp.asarray(rng.uniform(size=(batch, SEQ)) < np.repeat(np.asarray(noise_t), block_length, axis=1))
+    return {"tokens": tokens, "masked": masked, "noise_t": noise_t}
+
+
+def _close(got, want, tol=2e-5):
+    scale = float(jnp.abs(want).max()) + 1e-12
+    return float(jnp.abs(got - want).max()) / scale < tol
+
+
+# -- the decoder against the reference -------------------------------------------------
+
+
+@pytest.mark.parametrize("block_length", [4, 16])
+@pytest.mark.parametrize("share", [(1, 0), (2, 1), (4, 2)], ids=["whole", "half-1", "quarter-2"])
+def test_decoder_loss_gradients_and_logits_match_the_reference(block_length, share):
+    config, file_config = _configs(block_length, *share)
+    params = sdar_moe.init_sdar_variables(config, jax.random.PRNGKey(1), SEQ)["params"]
+    assert jax.tree.map(lambda x: tuple(x.shape), params) == sdar_reference.param_shapes(file_config)
+    batch = _batch(block_length)
+    model = sdar_moe.SDARDecoder(config)
+    loss = lambda p: model.apply({"params": p}, batch["tokens"], batch["masked"], batch["noise_t"], method="loss")
+    (got, aux), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+    (want, held), want_grads = jax.value_and_grad(
+        lambda p: sdar_reference.loss(file_config, p, batch), has_aux=True)(params)
+    assert abs(float(got) - float(want)) < 1e-5 * abs(float(want))
+    assert float(aux["moe_held_rows"]) == float(held)  # no row dropped, none invented
+    assert all(jax.tree.leaves(jax.tree.map(_close, grads, want_grads)))
+    logits, _ = jax.jit(lambda p: model.apply({"params": p}, batch["tokens"], batch["masked"]))(params)
+    want_logits, _ = sdar_reference.forward(file_config, params, batch["tokens"], batch["masked"])
+    assert _close(logits, want_logits)
+
+
+def test_chunked_experts_and_loss_equal_the_unchunked():
+    chunked, _ = _configs()
+    whole, _ = _configs(moe_chunk=4096, loss_chunk=4096)
+    params = sdar_moe.init_sdar_variables(chunked, jax.random.PRNGKey(2), SEQ)["params"]
+    batch = _batch(4)
+    values = []
+    for config in (chunked, whole):
+        model = sdar_moe.SDARDecoder(config)
+        loss = lambda p: model.apply({"params": p}, batch["tokens"], batch["masked"], batch["noise_t"], method="loss")[0]
+        values.append(jax.jit(jax.value_and_grad(loss))(params))
+    assert abs(float(values[0][0]) - float(values[1][0])) < 1e-6
+    assert all(jax.tree.leaves(jax.tree.map(_close, values[0][1], values[1][1])))
+
+
+# -- the chip's share against the uncut model ------------------------------------------
+
+
+@pytest.mark.parametrize("expert_parallel", [2, 4, 8])
+def test_the_shards_partial_results_add_up_to_the_uncut_layers(expert_parallel):
+    """Every shard routes over all 8 experts and computes its own experts'
+    part; the parts sum to what the uncut reference's expert layer gives."""
+    whole, whole_file = _configs()
+    params = sdar_moe.init_sdar_variables(whole, jax.random.PRNGKey(3), SEQ)["params"]["layers"]
+    first = jax.tree.map(lambda x: x[0], params)
+    m = jax.random.normal(jax.random.PRNGKey(4), (2 * 2 * SEQ, 64))
+    want, want_rows = sdar_reference._experts(
+        lambda x: x, whole_file, sdar_reference._dims(whole_file), first["experts"], first["router"], m, None)
+    total, rows = 0.0, 0
+    held = 8 // expert_parallel
+    for shard in range(expert_parallel):
+        config, _ = _configs(4, expert_parallel, shard)
+        mine = jax.tree.map(lambda x: x[shard * held:(shard + 1) * held], first["experts"])
+
+        @jax.jit
+        def part(m):
+            chosen, weights = sdar_moe.Router(config).apply({"params": first["router"]}, m)
+            return sdar_moe.Experts(config).apply({"params": mine}, m, chosen, weights)
+
+        y, counts = part(m)
+        total, rows = total + y, rows + int(counts.sum())
+    assert rows == int(want_rows) == m.shape[0] * 2
+    assert _close(total, want)
+
+
+@pytest.mark.parametrize("shards", [2, 4, 8])
+def test_the_vocabulary_slices_logits_concatenate_to_the_uncut_heads(shards):
+    h = jax.random.normal(jax.random.PRNGKey(5), (2, SEQ, 64))
+    w_head = jax.random.normal(jax.random.PRNGKey(6), (64, 96))
+    rows = 96 // shards
+    slices = []
+    for shard in range(shards):
+        config = SDARMoEConfig.from_hf_config(dict(PUBLISHED, vocab_size=rows), **TILES)
+        mine = {"w_head": w_head[:, shard * rows:(shard + 1) * rows]}
+        slices.append(sdar_moe.LMHead(config).apply({"params": mine}, h))
+    assert _close(jnp.concatenate(slices, axis=-1), jnp.dot(h, w_head, precision="highest"))
+
+
+# -- block_attention -------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("seq,block,tile,heads", [(32, 4, 16, (4, 2)), (32, 16, 16, (4, 2)), (32, 4, 32, (2, 2)),
+                                                  (64, 8, 16, (8, 1))])
+def test_block_attention_forward_and_backward_match_the_dense_form(seq, block, tile, heads):
+    hq, hkv = heads
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(keys[0], (2, hq, 2 * seq, 16))
+    k = jax.random.normal(keys[1], (2, hkv, 2 * seq, 16))
+    v = jax.random.normal(keys[2], (2, hkv, 2 * seq, 16))
+    w = jax.random.normal(keys[3], q.shape)
+    kernel = lambda q, k, v: (ba.block_attention(q, k, v, seq, block, tile) * w).sum()
+    dense = lambda q, k, v: (ba.block_attention_dense(q, k, v, seq, block) * w).sum()
+    assert _close(ba.block_attention(q, k, v, seq, block, tile), ba.block_attention_dense(q, k, v, seq, block))
+    got, want = jax.grad(kernel, (0, 1, 2))(q, k, v), jax.grad(dense, (0, 1, 2))(q, k, v)
+    assert all(_close(a, b) for a, b in zip(got, want))
+
+
+@pytest.mark.parametrize("seq,block", [(32, 4), (32, 16), (48, 8)])
+def test_mask_codes_give_the_rule_and_the_reference_s_mask(seq, block):
+    mask = np.asarray(ba.block_mask(seq, block))
+    assert np.array_equal(mask, np.asarray(sdar_reference.block_mask(seq, block)))
+    b = (np.arange(2 * seq) % seq) // block
+    noised = np.arange(2 * seq) < seq
+    for i in (0, block, seq - 1, seq, seq + block, 2 * seq - 1):
+        want = np.where(noised[i], (noised & (b == b[i])) | (~noised & (b < b[i])), ~noised & (b <= b[i]))
+        assert np.array_equal(mask[i], want), i
+    assert mask.sum() == seq * block + seq * seq
+
+
+def test_tiles_no_query_can_see_are_never_visited():
+    """The grid's last axis walks only visible tiles: the live steps over all
+    query tiles count the tiles the dense mask touches."""
+    seq, block, tile = 64, 4, 16
+    nh = seq // tile
+    mask = np.asarray(ba.block_mask(seq, block)).reshape(2 * nh, tile, 2 * nh, tile).any(axis=(1, 3))
+    for qt in range(2 * nh):
+        steps = int(ba._fwd_steps(jnp.int32(qt), nh)[1])
+        visited = {int(ba._fwd_key_tile(jnp.int32(qt), jnp.int32(s), nh)) for s in range(steps)}
+        assert visited == set(np.flatnonzero(mask[qt])), qt
+        assert int(ba._fwd_key_tile(jnp.int32(qt), jnp.int32(nh), nh)) in visited  # a dead step copies nothing new
+    for kt in range(2 * nh):
+        steps = int(ba._bwd_steps(jnp.int32(kt), nh)[1])
+        visited = {int(ba._bwd_query_tile(jnp.int32(kt), jnp.int32(u), nh)) for u in range(steps)}
+        assert visited == set(np.flatnonzero(mask[:, kt])), kt
+
+
+# -- grouped_matmul --------------------------------------------------------------------
+
+E, TILE, A = 4, 8, 64
+ROUTINGS = {
+    "mixed": lambda rng: rng.integers(0, E + 1, A),
+    "all_held": lambda rng: rng.integers(0, E, A),
+    "none_held": lambda rng: np.full(A, E),
+    "one_takes_all": lambda rng: np.full(A, 2),
+}
+
+
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_group_layout_places_every_held_assignment_once(routing):
+    expert = ROUTINGS[routing](np.random.default_rng(8))
+    layout = jax.tree.map(np.asarray, gm.group_layout(jnp.asarray(expert, jnp.int32), E, TILE))
+    tiles = int(layout["num_tiles"][0])
+    assert layout["row_live"].sum() == layout["held"].sum() == (expert < E).sum()
+    assert np.array_equal(layout["counts"], np.bincount(expert, minlength=E + 1)[:E])
+    for a in np.flatnonzero(expert < E):
+        row = layout["slot_row"][a]
+        assert layout["row_live"][row] and layout["row_source"][row] == a
+        assert layout["tile_expert"][row // TILE] == expert[a]
+    live_tiles = layout["tile_expert"][:tiles]
+    assert list(live_tiles) == sorted(live_tiles) and set(live_tiles) == set(range(E))  # every expert a tile
+    assert not layout["row_live"][tiles * TILE:].any()
+    assert layout["row_live"].shape[0] == gm.rows_bound(A, E, TILE)  # room for the worst case
+
+
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_grouped_matmul_forward_and_both_backward_products_match_the_dense_form(routing):
+    expert = jnp.asarray(ROUTINGS[routing](np.random.default_rng(9)), jnp.int32)
+    layout = gm.group_layout(expert, E, TILE)
+    groups = (layout["tile_expert"], layout["num_tiles"], TILE)
+    rows = gm.rows_bound(A, E, TILE)
+    x = jax.random.normal(jax.random.PRNGKey(10), (A, 32))
+    w = jax.random.normal(jax.random.PRNGKey(11), (E, 32, 48))
+    g = jax.random.normal(jax.random.PRNGKey(12), (rows, 48))
+    live_tile = jnp.repeat(jnp.arange(rows // TILE) < layout["num_tiles"][0], TILE)[:, None]
+
+    def through(product):
+        def value(x, w):
+            lhs = jnp.where(layout["row_live"][:, None], x[layout["row_source"]], 0.0)
+            return (jnp.where(live_tile, product(lhs, w, *groups), 0.0) * g).sum()
+        return jax.value_and_grad(value, (0, 1))(x, w)
+
+    (got, got_grads), (want, want_grads) = through(gm.grouped_matmul), through(gm.grouped_matmul_dense)
+    assert abs(float(got) - float(want)) < 1e-3
+    assert all(_close(a, b) for a, b in zip(got_grads, want_grads))
+
+
+# -- the trainer's rules for the family -----------------------------------------------
+
+
+def _tiny_train_config(tmp_path, **kwargs):
+    config, _ = _configs(4, 2, 1)
+    return TrainConfig(model=config, batch_size=2, num_steps=2, checkpoint_every=100, handle_signals=False,
+                       checkpoint_dir=str(tmp_path / "ckpt"), log_dir=str(tmp_path / "logs"), **kwargs)
+
+
+def test_token_batch_and_state_rules():
+    from jax.sharding import PartitionSpec as P
+
+    from raft_stereo_tpu.parallel.mesh import DATA_AXIS, make_mesh
+    from raft_stereo_tpu.parallel.sharding import ShardingEngine
+    from raft_stereo_tpu.train.families import family_of
+
+    mesh = make_mesh((4, 1))
+    config, file_config = _configs()
+    template = {name: len(shape) for name, (shape, _) in family_of(config, (SEQ,)).batch_shapes(4).items()}
+    shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s, jnp.float32), sdar_reference.param_shapes(file_config),
+                          is_leaf=lambda s: isinstance(s, tuple))
+    dp, fsdp = ShardingEngine(mesh, "dp"), ShardingEngine(mesh, "fsdp")
+    assert all(s.spec == P(DATA_AXIS, None) for s in dp.batch_shardings(template).values())
+    assert dp.input_sharding(2, "tokens").spec == P(DATA_AXIS, None)
+    assert dp.input_sharding(4).spec[0] == DATA_AXIS  # image-like still by rank
+    assert set(jax.tree.leaves(dp.state_specs(shapes), is_leaf=lambda s: isinstance(s, P))) == {P()}
+    specs = fsdp.state_specs(shapes)
+    assert specs["layers"]["experts"]["w_gate"] == P(None, None, None, DATA_AXIS)
+    assert specs["layers"]["attention"]["w_q"] == specs["layers"]["router"]["w_router"] == P(None, None, DATA_AXIS)
+    assert specs["embed"]["embedding"] == specs["lm_head"]["w_head"] == P(None, DATA_AXIS)
+    assert specs["norm"]["weight"] == specs["layers"]["attention"]["q_norm"]["weight"] == P()
+    assert "tokens" in dp.explain(batch_template=template)
+
+
+@pytest.mark.parametrize("preset", ["dp", "fsdp"])
+def test_two_device_step_gives_the_one_device_steps_loss(tmp_path, preset):
+    from raft_stereo_tpu.train.trainer import Trainer
+
+    batch = jax.tree.map(np.asarray, _batch(4))
+    losses = []
+    for mesh_shape, rules in (((1, 1), "dp"), ((2, 1), preset)):
+        trainer = Trainer(_tiny_train_config(tmp_path / rules / str(mesh_shape[0]), mesh_shape=mesh_shape,
+                                             sharding_rules=rules, seed=3), sample_shape=(SEQ,))
+        state, metrics = trainer.train_step(trainer.state, trainer.sharding.place_batch(batch))
+        losses.append((float(metrics["live_loss"]), float(metrics["grad_norm"]), float(metrics["moe_held_rows"])))
+    assert losses[0][2] == losses[1][2]
+    assert abs(losses[0][0] - losses[1][0]) < 1e-5 and abs(losses[0][1] - losses[1][1]) < 1e-4
+
+
+def test_token_steps_instructions_are_placed(tmp_path):
+    """A token step's lowered instructions land in the family's rows of the
+    ONE table, in every phase the step has; what the model wrote and no row
+    takes is the layer scan's own plumbing. (Interpreted kernels print their
+    bodies as nameless calls here; on the chip a kernel is one named custom
+    call: PERF.md section 5 has the chip's shares.)"""
+    from raft_stereo_tpu.obs import scopes
+    from raft_stereo_tpu.train.trainer import Trainer
+
+    trainer = Trainer(_tiny_train_config(tmp_path), sample_shape=(SEQ,))
+    text = trainer.train_step.lower(scopes.abstract(trainer.state), trainer._abstract_batch()).compile().as_text()
+    seen = {}
+    for op_name, opcode in scopes.instruction_scopes(text).values():
+        component, phase = scopes.component(op_name, opcode)
+        seen.setdefault(component, set()).add(phase)
+        if component == "other" and "/layers/" in op_name:
+            assert op_name.endswith(("/layers/add", "closed_call")) or "/layers/" not in op_name.split("while/body")[-1], op_name
+    assert {"embed", "attention", "router", "experts", "lm_head", "loss", "optimizer"} <= set(seen)
+    assert {"forward", "backward", "recompute"} <= seen["attention"] and "recompute" in seen["experts"]
+    assert not set(seen) & {"encoder", "lookup", "gru08"}  # no stereo row takes a token step's instruction
+
+
+@pytest.mark.parametrize("path,want", [
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/embed/take", ("embed", "forward")),
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/input_norm/mul", ("attention", "forward")),
+    ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/layers/attention/block_attention/pallas_call", ("attention", "backward")),
+    ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/rematted_computation/layers/attention/q_norm/mul", ("attention", "recompute")),
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/post_attention_norm/rsqrt", ("router", "forward")),
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/router/top_k", ("router", "forward")),
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/experts/while/body/closed_call/checkpoint/grouped_matmul/pallas_call", ("experts", "forward")),
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/norm/mul", ("lm_head", "forward")),
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/lm_head.loss_sum/while/body/checkpoint/dot_general", ("lm_head", "forward")),
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/lm_head.loss_sum/while/body/checkpoint/block_diffusion_loss/reduce_max", ("loss", "forward")),
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/block_diffusion_loss/div", ("loss", "forward")),
+])
+def test_the_one_table_places_the_familys_scopes(path, want):
+    from raft_stereo_tpu.obs import scopes
+
+    assert scopes.component(path, "fusion") == want
+
+
+# -- the entry point, and the family that was there -----------------------------------
+
+
+def test_cmd_train_runs_two_token_steps_end_to_end(tmp_path, monkeypatch):
+    from raft_stereo_tpu import cli
+    from raft_stereo_tpu.utils import run_report as rr
+
+    _, file_config = _configs(4, 2, 1)
+    file_config["program"].update(TILES, mixed_precision=True)
+    file_config["model_type"] = "sdar_moe"
+    path = tmp_path / "config.json"
+    path.write_text(json.dumps(file_config))
+    monkeypatch.chdir(tmp_path)
+    code = cli.cmd_train(["--token_config", str(path), "--seq_len", str(SEQ),
+                          "--batch_size", "2", "--num_steps", "2", "--name", "tokens", "--mesh_shape", "1", "1"])
+    assert code == rr.EXIT_OK
+    report = json.loads((tmp_path / "runs" / "run_report.json").read_text())
+    assert report["final_step"] == 2 and report["stop_cause"] == "completed"
+
+
+def test_token_batches_are_seeded_and_leave_the_mask_row_out():
+    from raft_stereo_tpu.data.tokens import TokenBatches
+
+    first = next(iter(TokenBatches(2, SEQ, 4, 95, seed=5)))
+    again = next(iter(TokenBatches(2, SEQ, 4, 95, seed=5)))
+    assert all(np.array_equal(first[k], again[k]) for k in first)
+    assert first["tokens"].shape == (2, SEQ) and first["tokens"].max() < 95
+    assert first["masked"].dtype == bool and first["noise_t"].shape == (2, SEQ // 4)
+    with pytest.raises(ValueError):
+        TokenBatches(2, 30, 4, 95)
+
+
+def test_family_of_gives_each_config_its_batch():
+    from raft_stereo_tpu.train.families import family_of
+
+    stereo = family_of(RAFTStereoConfig(), (32, 48, 3)).batch_shapes(2)
+    assert {k: v[0] for k, v in stereo.items()} == {
+        "image1": (2, 32, 48, 3), "image2": (2, 32, 48, 3), "flow": (2, 32, 48, 1), "valid": (2, 32, 48)}
+    tokens = family_of(_configs()[0], (SEQ,)).batch_shapes(2)
+    assert {k: (v[0], np.dtype(v[1]).name) for k, v in tokens.items()} == {
+        "tokens": ((2, SEQ), "int32"), "masked": ((2, SEQ), "bool"), "noise_t": ((2, SEQ // 4), "float32")}
+    with pytest.raises(ValueError):
+        family_of(_configs()[0], (30,))
+    with pytest.raises(TypeError):
+        family_of(object(), (1,))
+
+
+# The stereo step as the parent commit lowered it (sha256 of the StableHLO
+# text of a tiny trainer's step, recorded on the parent with this very
+# function): taking the family out of the trainer changed no operation of it,
+# nor did handing the correlation kernels' `shard_map` to `ops/data_axis.py`.
+STEREO_STEP_SHA256 = {
+    "reg": "2c7fa7a197b3553fe92a3856adbf8d2bcd9a5528bceeabfb14a4f66d77646146",
+    "pallas": "4dc0fd5fba444a4d29acdb23178de812247875e823fd036c4488888f4186866e",
+}
+
+
+def stereo_step_text(corr_implementation="reg"):
+    from raft_stereo_tpu.obs import scopes
+    from raft_stereo_tpu.train.trainer import Trainer
+
+    model = RAFTStereoConfig(hidden_dims=(16, 16, 16), n_gru_layers=1, corr_levels=2, corr_radius=2,
+                             corr_implementation=corr_implementation)
+    config = TrainConfig(model=model, batch_size=1, train_iters=2, num_steps=10)
+    trainer = Trainer(config, sample_shape=(32, 48, 3))
+    return trainer.train_step.lower(scopes.abstract(trainer.state), trainer._abstract_batch()).as_text()
+
+
+@pytest.mark.parametrize("corr_implementation", sorted(STEREO_STEP_SHA256))
+def test_the_stereo_step_lowers_to_the_parents_text(corr_implementation):
+    text = stereo_step_text(corr_implementation)
+    assert hashlib.sha256(text.encode()).hexdigest() == STEREO_STEP_SHA256[corr_implementation]
