@@ -248,7 +248,8 @@ class SDARMoEConfig:
     # Static bounds of the expert layer (ops/grouped_matmul.py): positions
     # are walked in chunks of `moe_chunk`, each with row buffers for the
     # worst case (every choice of every position held here), so no token is
-    # dropped at any imbalance and no buffer grows with the batch.
+    # dropped at any imbalance and no buffer grows with the batch. Only the
+    # live tiles of a buffer are computed or copied (ops/tile_rows.py).
     moe_chunk: int = 4096
     moe_tile_rows: int = 128
     attention_tile: int = 512

@@ -38,6 +38,7 @@ from raft_stereo_tpu.config import SDARMoEConfig
 from raft_stereo_tpu.ops.block_attention import block_attention
 from raft_stereo_tpu.ops.data_axis import over_data_axis
 from raft_stereo_tpu.ops.grouped_matmul import group_layout, grouped_matmul
+from raft_stereo_tpu.ops.tile_rows import fits, gather_rows, scatter_add_rows
 
 Array = jax.Array
 
@@ -119,11 +120,64 @@ class Router(nn.Module):
         return chosen.astype(jnp.int32), weights
 
 
-# -- dispatch and combine: gathers in both directions ------------------------------
+# -- dispatch and combine -------------------------------------------------------------
 #
 # A row's source and an assignment's row are each other's inverse
-# (`group_layout`), so a scatter-add in either direction is written as the
-# gather the other map gives.
+# (`group_layout`). Two formulations of the same copies:
+#
+# - `_dispatch_live` / `_combine_live`: the kernels of `ops/tile_rows.py`,
+#   which walk the tile table `grouped_matmul` walks and move the LIVE rows
+#   only (with 16 of 128 experts held, an eighth of a chunk's buffer). The
+#   combine is a scatter-add by rows, its backward a gather by rows, and the
+#   router weights' gradient a row's dot product, gathered as a scalar.
+# - `_dispatch` / `_combine`: `jax.numpy` gathers in both directions over every
+#   row of the worst-case buffer (a scatter-add in either direction written
+#   as the gather the other map gives). The path for a table that does not
+#   fit the kernels' VMEM budget (`tile_rows.fits`), and what the tests hold
+#   the kernels to.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _dispatch_live(m, source, num_tiles, tile, positions, k):
+    """rows[r] = m[source[r] // k], a zero row where `source[r]` (the
+    assignment row r holds) is negative; rows of dead tiles are not written.
+    m: (positions, D)."""
+    return gather_rows(m, source, num_tiles, tile, k)
+
+
+def _dispatch_live_fwd(m, source, num_tiles, tile, positions, k):
+    return _dispatch_live(m, source, num_tiles, tile, positions, k), (source, num_tiles)
+
+
+def _dispatch_live_bwd(tile, positions, k, residuals, d_rows):
+    source, num_tiles = residuals
+    return scatter_add_rows(d_rows, source, num_tiles, tile, positions, k), None, None
+
+
+_dispatch_live.defvjp(_dispatch_live_fwd, _dispatch_live_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _combine_live(rows, weights, source, slot_row, held, num_tiles, tile):
+    """y[c] = sum over the live rows r of position c of weights[assignment of
+    r] * rows[r], in float32. rows: (R, D); weights: (C, k) float32."""
+    c, k = weights.shape
+    return scatter_add_rows(rows, source, num_tiles, tile, c, k, weights.reshape(-1))
+
+
+def _combine_live_fwd(rows, weights, source, slot_row, held, num_tiles, tile):
+    out = _combine_live(rows, weights, source, slot_row, held, num_tiles, tile)
+    return out, (rows, weights, source, slot_row, held, num_tiles)
+
+
+def _combine_live_bwd(tile, residuals, d_y):
+    rows, weights, source, slot_row, held, num_tiles = residuals
+    d_rows, d_row_weight = gather_rows(
+        d_y, source, num_tiles, tile, weights.shape[1], weights=weights.reshape(-1), dot_with=rows)
+    return d_rows, jnp.where(held, d_row_weight[slot_row], 0.0), None, None, None, None
+
+
+_combine_live.defvjp(_combine_live_fwd, _combine_live_bwd)
 
 
 @jax.custom_vjp
@@ -174,9 +228,10 @@ class Experts(nn.Module):
     config: SDARMoEConfig
 
     @nn.compact
-    def __call__(self, m: Array, chosen: Array, weights: Array) -> Tuple[Array, Array]:
+    def __call__(self, m: Array, chosen: Array, weights: Array) -> Tuple[Array, Array, Array]:
         """m: (N, D); chosen / weights: (N, k). -> (this chip's experts' part
-        of the layer's output (N, D), rows each held expert took (E,))."""
+        of the layer's output (N, D), rows each held expert took (E,), the
+        live tiles' share of the row buffers they lie in)."""
         cfg = self.config
         d = m.shape[1]
         e, f, k = cfg.num_experts, cfg.moe_intermediate_size, cfg.num_experts_per_tok
@@ -190,33 +245,43 @@ class Experts(nn.Module):
         expert = jnp.where((local >= 0) & (local < e), local, e)
 
         def one_chunk(m_c, expert_c, weights_c, w_gate_up, w_down):
+            c = m_c.shape[0]
             layout = group_layout(expert_c.reshape(-1), e, tile)
-            row_token, row_slot = layout["row_source"] // k, layout["row_source"] % k
+            row_source, row_live = layout["row_source"], layout["row_live"]
             slot_row, held = layout["slot_row"].reshape(-1, k), layout["held"].reshape(-1, k)
             groups = (layout["tile_expert"], layout["num_tiles"], tile)
-            rows = _dispatch(m_c, row_token, layout["row_live"], slot_row, held)
+            live_copies = fits(c, d, m_c.dtype, row_live.shape[0], tile, k)
+            if live_copies:
+                source = jnp.where(row_live, row_source, -1)
+                rows = _dispatch_live(m_c, source, layout["num_tiles"], tile, c, k)
+            else:
+                row_token, row_slot = row_source // k, row_source % k
+                rows = _dispatch(m_c, row_token, row_live, slot_row, held)
             gate_up = grouped_matmul(rows, w_gate_up, *groups).astype(jnp.float32)
             hidden = (jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]).astype(m_c.dtype)
             out_rows = grouped_matmul(hidden, w_down, *groups)
-            y = _combine(out_rows, weights_c, row_token, row_slot, layout["row_live"], slot_row, held)
-            return y, layout["counts"]
+            if live_copies:
+                y = _combine_live(out_rows, weights_c, source, slot_row, held, layout["num_tiles"], tile)
+            else:
+                y = _combine(out_rows, weights_c, row_token, row_slot, row_live, slot_row, held)
+            return y, layout["counts"], layout["num_tiles"] * (tile / row_live.shape[0])
 
         def positions_here(m, expert, weights, w_gate_up, w_down):
             """A device's own positions (all of them on one device)."""
             n = m.shape[0]
             chunk = cfg.moe_chunk if n % cfg.moe_chunk == 0 else n
             if chunk == n:
-                y, counts = one_chunk(m, expert, weights, w_gate_up, w_down)
-                return y, counts[None]
+                y, counts, live = one_chunk(m, expert, weights, w_gate_up, w_down)
+                return y, counts[None], live
             shape = lambda x: x.reshape(n // chunk, chunk, *x.shape[1:])
             # The worst-case row buffers live for one chunk: a chunk's products
             # are rebuilt in the backward from its inputs alone.
             body = jax.checkpoint(lambda _, xs: (None, one_chunk(*xs, w_gate_up, w_down)), prevent_cse=False)
-            _, (y, counts) = jax.lax.scan(body, None, (shape(m), shape(expert), shape(weights)))
-            return y.reshape(n, d), jnp.sum(counts, axis=0)[None]
+            _, (y, counts, live) = jax.lax.scan(body, None, (shape(m), shape(expert), shape(weights)))
+            return y.reshape(n, d), jnp.sum(counts, axis=0)[None], jnp.mean(live, axis=0)
 
-        y, counts = over_data_axis(positions_here, (m, expert, weights), (w_gate_up, w_down))
-        return y, jnp.sum(counts, axis=0)
+        y, counts, live = over_data_axis(positions_here, (m, expert, weights), (w_gate_up, w_down))
+        return y, jnp.sum(counts, axis=0), jnp.mean(live)
 
 
 class DecoderLayer(nn.Module):
@@ -230,8 +295,8 @@ class DecoderLayer(nn.Module):
         h = h + Attention(cfg, name="attention")(a, *tables)
         m = RMSNorm(cfg.rms_norm_eps, name="post_attention_norm")(h).reshape(b * s, d)
         chosen, weights = Router(cfg, name="router")(m)
-        y, counts = Experts(cfg, name="experts")(m, chosen, weights)
-        return h + y.reshape(b, s, d), counts
+        y, counts, live = Experts(cfg, name="experts")(m, chosen, weights)
+        return h + y.reshape(b, s, d), (counts, live)
 
 
 class LMHead(nn.Module):
@@ -283,10 +348,10 @@ class SDARDecoder(nn.Module):
             length=cfg.num_hidden_layers,
         )(cfg)
 
-    def hidden(self, tokens: Array, masked: Array) -> Tuple[Array, Array]:
+    def hidden(self, tokens: Array, masked: Array) -> Tuple[Array, Array, Array]:
         """tokens (B, L) int32, masked (B, L) bool -> (the last norm's output
         at the noised half (B, L, D), rows each held expert took in each layer
-        (layers, E))."""
+        (layers, E), each layer's live share of its row buffers (layers,))."""
         cfg = self.config
         seq_len = tokens.shape[1]
         dtype = jnp.bfloat16 if cfg.mixed_precision else jnp.float32
@@ -296,12 +361,12 @@ class SDARDecoder(nn.Module):
         h = self.embed(ids).astype(dtype)
         with jax.named_scope("attention"):
             tables = rotary_tables(seq_len, cfg.head_dim, cfg.rope_theta)
-        h, counts = self.layers(h, tables)
-        return self.norm(h[:, :seq_len]), counts
+        h, (counts, live) = self.layers(h, tables)
+        return self.norm(h[:, :seq_len]), counts, live
 
     def __call__(self, tokens: Array, masked: Array) -> Tuple[Array, Array]:
         """Logits at the noised half, (B, L, V) float32, and the rows count."""
-        h, counts = self.hidden(tokens, masked)
+        h, counts, _ = self.hidden(tokens, masked)
         return self.lm_head(h), counts
 
     def loss(self, tokens: Array, masked: Array, noise_t: Array) -> Tuple[Array, Dict[str, Array]]:
@@ -310,7 +375,7 @@ class SDARDecoder(nn.Module):
         block's noise level."""
         cfg = self.config
         b, seq_len = tokens.shape
-        h, counts = self.hidden(tokens, masked)
+        h, counts, live = self.hidden(tokens, masked)
         with jax.named_scope("block_diffusion_loss"):
             per_position = jnp.repeat(1.0 / noise_t, cfg.block_length, axis=1)
             weights = jnp.where(masked, per_position, 0.0).astype(jnp.float32) / (b * seq_len)
@@ -320,6 +385,9 @@ class SDARDecoder(nn.Module):
         return total, {
             "moe_held_rows": jnp.sum(counts),
             "moe_max_over_mean_load": jnp.mean(load),
+            # rows of the live tiles over the rows of the buffers they lie in:
+            # what the copies of `ops/tile_rows.py` touch of the worst case
+            "moe_live_row_share": jnp.mean(live),
             "masked_tokens": jnp.sum(masked.astype(jnp.float32)),
         }
 

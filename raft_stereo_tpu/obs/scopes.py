@@ -25,7 +25,8 @@ Scopes the program writes (beside flax's module names `cnet`, `fnet`,
 `sdar-moe` family (models/sdar_moe.py) writes flax's module names `embed`,
 `layers/{input_norm,attention,post_attention_norm,router,experts}`, `norm`,
 `lm_head`, and the scopes `block_attention` (ops/block_attention.py, under
-`attention`), `grouped_matmul` (ops/grouped_matmul.py, under `experts`) and
+`attention`), `grouped_matmul` (ops/grouped_matmul.py), `gather_rows` and
+`scatter_add_rows` (ops/tile_rows.py; all three under `experts`) and
 `block_diffusion_loss`; the two norms before a sublayer count with it.
 
 Pure: jax is touched only by `scoped` (at trace time) and `abstract`; nothing

@@ -133,3 +133,30 @@ def test_fused_pyramid_state_compiles_at_middlebury_f(v5e, corr_dtype):
         lambda a, b: corr_pallas.fused_pyramid_state(a, b, LEVELS, corr_dtype=corr_dtype),
         fmap, fmap,
     )
+
+
+@pytest.mark.parametrize("copy", ["dispatch", "combine_backward", "combine", "dispatch_backward"])
+def test_live_row_copies_compile_at_the_token_cells_chunk(v5e, copy):
+    """The expert layer's copies at a chunk of `sdar-a3b-train-blockdiff-4k`:
+    4096 positions of 2048 bf16 (whole in VMEM as 32-bit words), a buffer of
+    32,768 + 16 x 128 rows in tiles of 128, the rows' assignments and the
+    assignments' weights in SMEM."""
+    from raft_stereo_tpu.ops import grouped_matmul, tile_rows
+
+    positions, width, tile = 4096, 2048, 128
+    rows = grouped_matmul.rows_bound(positions * 8, 16, tile)
+    assert tile_rows.fits(positions, width, jnp.bfloat16, rows, tile, 8)
+    shape = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    table, buffer = shape((positions, width), jnp.bfloat16), shape((rows, width), jnp.bfloat16)
+    source, num_tiles, weights = shape((rows,), jnp.int32), shape((1,), jnp.int32), shape((positions * 8,), jnp.float32)
+    if copy == "dispatch":
+        _assert_kernel_compiled(lambda t, s, n: tile_rows.gather_rows(t, s, n, tile, 8), table, source, num_tiles)
+    elif copy == "combine_backward":
+        _assert_kernel_compiled(
+            lambda t, s, n, w, b: tile_rows.gather_rows(t, s, n, tile, 8, w, b), table, source, num_tiles, weights, buffer)
+    elif copy == "combine":
+        _assert_kernel_compiled(
+            lambda b, s, n, w: tile_rows.scatter_add_rows(b, s, n, tile, positions, 8, w), buffer, source, num_tiles, weights)
+    else:
+        _assert_kernel_compiled(
+            lambda b, s, n: tile_rows.scatter_add_rows(b, s, n, tile, positions, 8), buffer, source, num_tiles)

@@ -308,15 +308,21 @@ def _token_kernel_calls():
         "block_attention_dq": lambda: jax.grad(attend, 0)(q, kv, kv),
         "block_attention_dkv": lambda: jax.grad(attend, 1)(q, kv, kv),
     }
+    from raft_stereo_tpu.ops import tile_rows as tr
+
+    source = jnp.where(layout["row_live"], layout["row_source"], -1)
     grouped = {
         "grouped_matmul": lambda: product(lhs, rhs),
         "grouped_matmul_drhs": lambda: jax.grad(product, 1)(lhs, rhs),
+        "gather_rows": lambda: tr.gather_rows(jnp.ones((16, 8)), source, layout["num_tiles"], 8),
+        "scatter_add_rows": lambda: tr.scatter_add_rows(lhs, source, layout["num_tiles"], 8, 16),
     }
     return attention, grouped
 
 
 KERNELS = [
     "block_attention", "block_attention_dq", "block_attention_dkv", "grouped_matmul", "grouped_matmul_drhs",
+    "gather_rows", "scatter_add_rows",
     "corr_lookup", "corr_scatter", "corr_lookup_prefetch", "corr_pyramid", "encoder_conv_s2d",
     "encoder_join", "gates_rh", "gates_combine", "gru_tail", "motion_tail",
 ]

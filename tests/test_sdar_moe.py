@@ -1,7 +1,8 @@
 """The `sdar-moe` family on the CPU at a tiny size (hidden 64, 4 heads of 16
 on 2 key-value heads, 8 experts of 32 with 2 a token, 2 layers, 32 tokens a
 row): the decoder against the plain reference on seeded weights, the two
-kernels against their dense forms, the chip's share against the uncut model,
+kernels against their dense forms, the live-row copies against the gathers
+they replace, the chip's share against the uncut model,
 the trainer's rules, scopes and entry point for the family, and the stereo
 step left as it was.
 """
@@ -19,6 +20,7 @@ from raft_stereo_tpu.config import RAFTStereoConfig, SDARMoEConfig, TrainConfig
 from raft_stereo_tpu.models import sdar_moe
 from raft_stereo_tpu.ops import block_attention as ba
 from raft_stereo_tpu.ops import grouped_matmul as gm
+from raft_stereo_tpu.ops import tile_rows as tr
 
 SEQ = 32
 PUBLISHED = dict(
@@ -112,7 +114,7 @@ def test_the_shards_partial_results_add_up_to_the_uncut_layers(expert_parallel):
             chosen, weights = sdar_moe.Router(config).apply({"params": first["router"]}, m)
             return sdar_moe.Experts(config).apply({"params": mine}, m, chosen, weights)
 
-        y, counts = part(m)
+        y, counts, _ = part(m)
         total, rows = total + y, rows + int(counts.sum())
     assert rows == int(want_rows) == m.shape[0] * 2
     assert _close(total, want)
@@ -229,6 +231,129 @@ def test_grouped_matmul_forward_and_both_backward_products_match_the_dense_form(
     assert all(_close(a, b) for a, b in zip(got_grads, want_grads))
 
 
+# -- the copies of the live rows ------------------------------------------------------
+
+COPY_E, COPY_K, COPY_C = 16, 3, 48  # 144 assignments in tiles of 8: a buffer of 34 tiles (and no power of two a position)
+
+
+def _copy_routing(name, rng):
+    """(C, k) expert ids over the 16 held experts, 16 = held elsewhere."""
+    far = np.full((COPY_C, COPY_K), COPY_E)
+    if name == "most_tiles_dead":  # an eighth of the router's experts is held here
+        chosen = rng.integers(0, 8 * COPY_E, (COPY_C, COPY_K))
+        return np.where(chosen < COPY_E, chosen, COPY_E)
+    if name == "every_assignment_held":  # expert_parallel 1
+        return rng.integers(0, COPY_E, (COPY_C, COPY_K))
+    if name == "no_assignment_held":  # sixteen all-padding tiles
+        return far
+    if name == "two_held_experts_on_one_position":
+        far[::3] = (3, 11, COPY_E)
+        return far
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("routing", ["most_tiles_dead", "every_assignment_held", "no_assignment_held",
+                                     "two_held_experts_on_one_position"])
+def test_live_row_copies_match_the_gathers_they_replace_on_a_poisoned_buffer(routing, dtype):
+    """`gather_rows` and `scatter_add_rows` against `_dispatch` / `_combine`
+    and their backward functions, every dead tile of every buffer a kernel
+    reads holding NaN: none of it may reach a row of a live tile, `y`, a
+    gradient or `d_weights`."""
+    expert = jnp.asarray(_copy_routing(routing, np.random.default_rng(13)), jnp.int32)
+    layout = gm.group_layout(expert.reshape(-1), COPY_E, TILE)
+    row_live, row_source, num_tiles = layout["row_live"], layout["row_source"], layout["num_tiles"]
+    slot_row, held = layout["slot_row"].reshape(-1, COPY_K), layout["held"].reshape(-1, COPY_K)
+    row_token, row_slot = row_source // COPY_K, row_source % COPY_K
+    source = jnp.where(row_live, row_source, -1)
+    rows = row_live.shape[0]
+    live_tile = (jnp.arange(rows) < num_tiles[0] * TILE)[:, None]
+    assert (routing == "no_assignment_held") == (int(num_tiles[0]) == COPY_E and not bool(row_live.any()))
+    keys = jax.random.split(jax.random.PRNGKey(14), 4)
+    m = jax.random.normal(keys[0], (COPY_C, 64)).astype(dtype)
+    d_y = jax.random.normal(keys[1], (COPY_C, 64)).astype(dtype)
+    weights = jax.random.uniform(keys[2], (COPY_C, COPY_K), jnp.float32)
+    buffer = jnp.where(live_tile, jnp.where(row_live[:, None], jax.random.normal(keys[3], (rows, 64)), 0.0), jnp.nan)
+    buffer = buffer.astype(dtype)
+    clean = jnp.where(live_tile, buffer, 0)
+    same = lambda got, want: np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    near = lambda got, want: np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=1e-2 if dtype == "bfloat16" else 1e-5, atol=1e-5)
+    geometry = (source, num_tiles, TILE)
+
+    # dispatch: the copy itself, padding rows of live tiles exact zeros
+    got = tr.gather_rows(m, *geometry, COPY_K)
+    same(jnp.where(live_tile, got, 0), sdar_moe._dispatch(m, row_token, row_live, slot_row, held))
+    same(jnp.where(live_tile, got, 0), tr.gather_rows_dense(m, *geometry, COPY_K))
+    # combine, and the dispatch's backward (a plain sum)
+    y = tr.scatter_add_rows(buffer, *geometry, COPY_C, COPY_K, weights.reshape(-1))
+    near(y, sdar_moe._combine(clean, weights, row_token, row_slot, row_live, slot_row, held))
+    d_m = tr.scatter_add_rows(buffer, *geometry, COPY_C, COPY_K)
+    near(d_m, sdar_moe._dispatch_bwd((slot_row, held), clean)[0])
+    near(d_m, tr.scatter_add_rows_dense(buffer, *geometry, COPY_C, COPY_K))
+    # the combine's backward: rows scaled in float32, the weights' gradient by rows
+    want_rows, want_weights = sdar_moe._combine_bwd((clean, weights, row_token, row_slot, row_live, slot_row, held), d_y)[:2]
+    d_rows, dots = tr.gather_rows(d_y, *geometry, COPY_K, weights=weights.reshape(-1), dot_with=buffer)
+    same(jnp.where(live_tile, d_rows, 0), want_rows)
+    d_weights = jnp.where(held, dots[slot_row], 0.0)
+    near(d_weights, want_weights)
+    assert all(bool(jnp.isfinite(x.astype(jnp.float32)).all()) for x in (y, d_m, d_weights))
+
+
+def _expert_layer_values(config, devices):
+    """value, (counts, live share) and the gradients to `m`, the experts'
+    weights and the router's `weights`, from functions traced anew."""
+    from raft_stereo_tpu.parallel.mesh import make_mesh
+    from raft_stereo_tpu.parallel.sharding import ShardingEngine
+
+    keys = jax.random.split(jax.random.PRNGKey(15), 4)
+    m = jax.random.normal(keys[0], (4 * SEQ, 64))
+    probs, chosen = jax.lax.top_k(jax.nn.softmax(jax.random.normal(keys[1], (4 * SEQ, 8))), 2)
+    g = jax.random.normal(keys[2], m.shape)
+    experts = sdar_moe.Experts(config)
+    params = experts.init(keys[3], m, chosen, probs)["params"]
+
+    def value(params, m, weights):
+        y, counts, live = experts.apply({"params": params}, m, chosen.astype(jnp.int32), weights)
+        return (y * g).sum(), (y, counts, live)
+
+    step = ShardingEngine(make_mesh((devices, 1)), "dp").wrap(jax.jit(jax.value_and_grad(value, (0, 1, 2), has_aux=True)))
+    return step(params, m, probs / probs.sum(-1, keepdims=True))
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+@pytest.mark.parametrize("moe_chunk", [32, 4096], ids=["chunked", "unchunked"])
+def test_experts_with_live_copies_equal_the_gathers_over_every_row(monkeypatch, moe_chunk, devices):
+    config, _ = _configs(4, 2, 1, moe_chunk=moe_chunk)
+    assert tr.fits(32, 64, jnp.float32, gm.rows_bound(64, 4, 8), 8, 2)  # the kernels are what runs here
+    (got, (y, counts, live)), grads = _expert_layer_values(config, devices)
+    asked = []
+    monkeypatch.setattr(sdar_moe, "fits", lambda *shapes: bool(asked.append(shapes)))
+    (want, (want_y, want_counts, want_live)), want_grads = _expert_layer_values(config, devices)
+    assert asked  # the second trace took the other path
+    assert _close(y, want_y) and abs(float(got) - float(want)) < 1e-4 * abs(float(want))
+    assert np.array_equal(counts, want_counts) and float(live) == float(want_live)
+    assert all(jax.tree.leaves(jax.tree.map(_close, grads, want_grads)))
+
+
+def test_live_row_share_counts_the_live_tiles_of_a_hand_made_routing():
+    """Shard 1 of 2 holds experts 4-7. Every position's first choice is
+    expert 4, its second is held elsewhere: 64 rows = 8 tiles for expert 4
+    and the one tile each of the other three, of a buffer of 128 + 4 x 8
+    rows = 20 tiles; in chunks of 32 positions, 4 + 3 of 12 tiles twice."""
+    m = jax.random.normal(jax.random.PRNGKey(16), (2 * SEQ, 64))
+    chosen = jnp.tile(jnp.asarray([[4, 0]], jnp.int32), (2 * SEQ, 1))
+    weights = jnp.full((2 * SEQ, 2), 0.5)
+    for moe_chunk, want in ((4096, 11 / 20), (32, 7 / 12)):
+        config, _ = _configs(4, 2, 1, moe_chunk=moe_chunk)
+        experts = sdar_moe.Experts(config)
+        params = experts.init(jax.random.PRNGKey(17), m, chosen, weights)["params"]
+        _, counts, live = experts.apply({"params": params}, m, chosen, weights)
+        assert list(np.asarray(counts)) == [2 * SEQ, 0, 0, 0] and abs(float(live) - want) < 1e-6
+    layout = gm.group_layout(jnp.asarray([0, 4] * (2 * SEQ), jnp.int32), 4, 8)
+    assert int(layout["num_tiles"][0]) * 8 / layout["row_live"].shape[0] == 11 / 20
+
+
 # -- the trainer's rules for the family -----------------------------------------------
 
 
@@ -273,8 +398,9 @@ def test_two_device_step_gives_the_one_device_steps_loss(tmp_path, preset):
         trainer = Trainer(_tiny_train_config(tmp_path / rules / str(mesh_shape[0]), mesh_shape=mesh_shape,
                                              sharding_rules=rules, seed=3), sample_shape=(SEQ,))
         state, metrics = trainer.train_step(trainer.state, trainer.sharding.place_batch(batch))
-        losses.append((float(metrics["live_loss"]), float(metrics["grad_norm"]), float(metrics["moe_held_rows"])))
-    assert losses[0][2] == losses[1][2]
+        losses.append((float(metrics["live_loss"]), float(metrics["grad_norm"]), float(metrics["moe_held_rows"]),
+                       float(metrics["moe_live_row_share"])))
+    assert losses[0][2] == losses[1][2] and 0.0 < losses[0][3] == losses[1][3] <= 1.0
     assert abs(losses[0][0] - losses[1][0]) < 1e-5 and abs(losses[0][1] - losses[1][1]) < 1e-4
 
 
@@ -308,6 +434,9 @@ def test_token_steps_instructions_are_placed(tmp_path):
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/post_attention_norm/rsqrt", ("router", "forward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/router/top_k", ("router", "forward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/experts/while/body/closed_call/checkpoint/grouped_matmul/pallas_call", ("experts", "forward")),
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/experts/closed_call/while/body/closed_call/checkpoint/scatter_add_rows/scatter_add_rows/pallas_call", ("experts", "forward")),
+    ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/layers/experts/while/body/closed_call/checkpoint/rematted_computation/gather_rows/gather_rows/pallas_call", ("experts", "recompute")),
+    ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/layers/experts/while/body/closed_call/checkpoint/gather_rows/gather_rows/pallas_call", ("experts", "backward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/norm/mul", ("lm_head", "forward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/lm_head.loss_sum/while/body/checkpoint/dot_general", ("lm_head", "forward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/lm_head.loss_sum/while/body/checkpoint/block_diffusion_loss/reduce_max", ("loss", "forward")),
