@@ -697,7 +697,7 @@ def _reload_checkpoint_client(
     weights via POST /reload and report the outcome. The path is resolved
     server-side, so it must be visible to the server process. Uses the
     shared stdlib client (utils/http.py) — the same timeout discipline
-    the frontier and bench clients follow."""
+    the frontier follows."""
     return _admin_post_client(
         f"http://{host}:{port}/reload",
         {"checkpoint": ckpt},

@@ -92,18 +92,6 @@ class RAFTStereoConfig:
     # of layout copies and lose the conv+IN-sum multi-output fusion
     # (round-4 trace — measured, not fundamental; revisit with a newer XLA).
     encoder_s2d: bool = True
-    # TOOLCHAIN-WATCH ONLY — measured slower; never set this expecting a
-    # win on the current toolchain. Unroll factor for the GRU-iteration
-    # scan (lax.scan `unroll`); applies to test_mode only (training keeps
-    # the remat-per-iteration structure the memory budget is built on).
-    # MEASURED NEGATIVE at Middlebury-F (round 4, scripts/exp_unroll.py):
-    # unroll=4 nearly DOUBLES the forward (934 -> 1742 ms; unroll=8 1837) —
-    # XLA's schedule across unrolled bodies regresses far more than the
-    # ~1.5 ms/iter of carry copies save. The knob exists solely so
-    # scripts/exp_unroll.py can re-measure after jax/libtpu upgrades
-    # (the verdict is a layout/scheduler artifact, ROADMAP "Toolchain
-    # watch").
-    scan_unroll: int = 1
     # Rematerialize each GRU iteration in the backward pass (jax.checkpoint
     # on the scanned body). Training memory drops from O(iters * per-iter
     # activations) to O(iters * carry) at the cost of one extra forward per
@@ -124,9 +112,9 @@ class RAFTStereoConfig:
     # backend the kernels run in the Pallas interpreter (ops/pallas_mode.py)
     # — fine for tier-1 parity tests, pathologically slow at full
     # resolution. Every kernel of this strategy compiles for v5e at
-    # Middlebury-F width, bf16 storage included (tests/test_chip_compile.py);
-    # whether it is faster is undecided (PERF.md): measure with
-    # scripts/exp_fused_encoder.py on the chip.
+    # Middlebury-F width, bf16 storage included (tests/test_chip_compile.py).
+    # Measured as `"fused_encoder": true` in a configuration's `program`
+    # group, cell `full-offline-middlebury-f`: PERF.md section 6, "Levers".
     fused_encoder: bool = False
     # Scalar-prefetch windowed correlation lookup ("pallas" corr only): the
     # per-row integer window starts derived from the lookup coordinates ride
@@ -137,22 +125,18 @@ class RAFTStereoConfig:
     # it for coordinate fields too rough to window). TEST-MODE forwards only
     # (no VJP — training keeps pallas_corr_lookup_padded); on the CPU backend
     # the kernel runs in the Pallas interpreter for the tier-1 parity tests.
-    # TPU verdict pending BENCH_r06 (`per_iter.levers.prefetch_lookup` A/B);
-    # retirement discipline in the ops/corr_pallas.py prefetch section
-    # docstring.
+    # Measured as `"prefetch_lookup": true` in a configuration's `program`
+    # group, cell `full-offline-middlebury-f`: PERF.md section 6, "Levers".
     prefetch_lookup: bool = False
     # Fused ConvGRU gate tail + motion-encoder concat (ops/gru_tail_pallas.py):
     # ONE Pallas call per cell computing sigmoid/tanh/blend at the scan-carry
     # materialization boundary, plus one call writing the 128ch motion concat
-    # — the surviving restructure of the retired 3-call gates_pallas
-    # experiment. TEST-MODE forwards only (no VJP; training path proven
-    # untouched by the exact-gradient-equality test). TPU verdict pending
-    # BENCH_r06 (`per_iter.levers.fused_gru_tail` A/B).
+    # — two calls a cell and iteration where XLA emits several elementwise
+    # passes. TEST-MODE forwards only (no VJP; training path proven
+    # untouched by the exact-gradient-equality test). Measured as
+    # `"fused_gru_tail": true` in a configuration's `program` group, cell
+    # `full-offline-middlebury-f`: PERF.md section 6, "Levers".
     fused_gru_tail: bool = False
-    # (A `fused_gru` flag + 260-LoC Pallas cell lived here through rounds
-    # 2–4; retired-with-numbers and PRUNED in round 5 — the fused cell
-    # measured 5.68 vs 3.34 ms/cell against XLA's ~160 TF/s conv emitter.
-    # Verdict in ROADMAP "Round-3 kernel verdicts"; code in git history.)
     # With remat_iterations on, KEEP across the backward what is small to
     # hold and dear to rebuild ("save_only_these_names" checkpoint policy,
     # models/raft_stereo.REMAT_SAVED_NAMES): the correlation lookup's taps
@@ -616,7 +600,7 @@ class VideoConfig:
     # Refinement budget for cold frames (frame 0, post-reset frames).
     cold_iters: int = 32
     # Refinement budget for warm-started frames — the whole point: fewer
-    # iterations at equal EPE (see iters_to_epe_parity in the bench).
+    # iterations at equal EPE (`video.warm_cold_parity` measures how many).
     warm_iters: int = 8
     # Reset gate: reset when the candidate flow's warp error on the new pair
     # exceeds `reset_error_ratio` x the error the SAME flow achieved on its

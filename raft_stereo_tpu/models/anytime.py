@@ -45,9 +45,9 @@ Array = jax.Array
 
 
 class AnytimePrelude(nn.Module):
-    """Images -> refinement state: the loop-invariant forward prefix (the
-    ~235 ms slice BENCH_r05 attributes to encoders + corr build), shared
-    verbatim with RAFTStereo.__call__ through `encode_features`."""
+    """Images -> refinement state: the loop-invariant forward prefix
+    (encoders + corr build), shared verbatim with RAFTStereo.__call__
+    through `encode_features`."""
 
     config: RAFTStereoConfig
 
@@ -88,7 +88,6 @@ class AnytimeChunk(nn.Module):
 
     @nn.compact
     def __call__(self, state):
-        cfg = self.config
         body = nn.scan(
             _IterationBody,
             variable_broadcast="params",
@@ -96,8 +95,7 @@ class AnytimeChunk(nn.Module):
             in_axes=(nn.broadcast, nn.broadcast, nn.broadcast),
             out_axes=0,
             length=self.chunk_iters,
-            unroll=cfg.scan_unroll,
-        )(config=cfg, test_mode=True, name="iteration")
+        )(config=self.config, test_mode=True, name="iteration")
         (net, coords1), _ = body(
             (state["net"], state["coords1"]),
             state["context"],

@@ -72,8 +72,8 @@ class EncoderTrunk(nn.Module):
     `s2d_layer1` evaluates layer1 (and the layer2_0 entry convs) in the
     W-space-to-depth domain: the C=64 convs half-starve the MXU's
     contraction lanes (~28 TF/s); the 128-channel s2d embedding runs ~1.7x
-    faster despite 2x structural-zero FLOPs (measured round 4,
-    scripts/exp_s2d_{layer1,chain}.py; math proven exact in f64). Entry is
+    faster despite 2x structural-zero FLOPs (measured round 4; math proven
+    exact in f64, tests/test_model.py). Entry is
     a pure reshape, exit rides the stride-2 layer2 kernels — no transpose
     anywhere. Param tree is unchanged. Applies when layer1 runs at stem
     resolution with even W and an s2d-capable norm."""

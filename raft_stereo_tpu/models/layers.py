@@ -257,9 +257,9 @@ def im2col_conv(kernel: Array, bias: Array, x: Array) -> Array:
 # XLA:TPU's conv emitter runs the full-res C=64 encoder convs at ~28 TF/s
 # (the 64-channel contraction fills half the MXU's 128 lanes); the same
 # kernel embedded in a 128-channel space-to-depth domain runs at ~48 TF/s
-# useful despite carrying 50% structural zeros (measured round 4,
-# scripts/exp_s2d_layer1.py: direct 14.9 ms vs s2d 8.8 ms per layer1 conv at
-# Middlebury-F; full-chain 81.3 -> 65.0 ms, scripts/exp_s2d_chain.py).
+# useful despite carrying 50% structural zeros (measured round 4, a layer
+# alone: direct 14.9 ms vs s2d 8.8 ms per layer1 conv at Middlebury-F; the
+# layer1 chain 81.3 -> 65.0 ms).
 #
 # The W dimension is chosen because (B,H,W,C) -> (B,H,W/2,2C) is a PURE
 # RESHAPE in row-major (W and C are adjacent), so entering the domain is
@@ -269,7 +269,8 @@ def im2col_conv(kernel: Array, bias: Array, x: Array) -> Array:
 #
 # Replaces the role of the reference's layer1 convs
 # (/root/reference/core/extractor.py:6-60,144-148) with identical math
-# (formulation proven exact in f64, scripts/exp_s2d_chain.py parity).
+# (formulation proven exact in f64, tests/test_model.py
+# test_s2d_kernel_embeddings_match_direct_conv).
 
 
 def w_s2d(x: Array) -> Array:

@@ -46,7 +46,6 @@ from raft_stereo_tpu.ops.corr import (
     corr_lookup_alt,
     pool_fmap_levels,
 )
-from raft_stereo_tpu.ops.gates_pallas import enabled as _gates_pallas_enabled
 from raft_stereo_tpu.parallel.sharding import constrain_spatial_tree
 from raft_stereo_tpu.utils.geometry import (
     convex_upsample,
@@ -184,14 +183,6 @@ class _IterationBody(nn.Module):
             corr_channels=cfg.corr_channels,
             n_gru_layers=cfg.n_gru_layers,
             n_downsample=cfg.n_downsample,
-            # Experiment-only fused gating (scripts/exp_gate_fusion.py):
-            # inference+TPU only — the kernels define no VJP, so a stray
-            # env toggle must never reach a gradient trace.
-            pallas_gates=(
-                _gates_pallas_enabled()
-                and self.test_mode
-                and jax.default_backend() == "tpu"
-            ),
             # Fused gate tail + motion concat (ops/gru_tail_pallas.py): no
             # VJP, so test_mode keeps it out of every gradient trace.
             fused_tail=cfg.fused_gru_tail and self.test_mode,
@@ -457,7 +448,6 @@ class RAFTStereo(nn.Module):
             in_axes=(nn.broadcast, nn.broadcast, nn.broadcast),
             out_axes=0,
             length=iters,
-            unroll=(cfg.scan_unroll if test_mode else 1),
         )(config=cfg, test_mode=test_mode, name="iteration")
 
         (net, coords1), ys = body((net, coords1), context, corr_state, coords0)

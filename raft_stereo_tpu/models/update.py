@@ -134,16 +134,9 @@ class ConvGRU(nn.Module):
     separate convs on purpose: XLA:TPU co-schedules the two same-input convs
     at ~166 TF/s combined, measurably faster than one fused double-width conv
     (110 TF/s) on v5e.
-
-    A fully-fused Pallas cell (convs + gating in one kernel) was built,
-    parity-tested, and RETIRED in rounds 2–4: it measured 5.68 ms/cell vs
-    XLA's 3.34 at Middlebury scale-0 shapes — Mosaic per-tap dots cannot
-    match XLA's ~160 TF/s conv emitter (ROADMAP "Round-3 kernel verdicts";
-    kernel recoverable from git history, ops/gru_pallas.py before round 5).
     """
 
     hidden_dim: int
-    pallas_gates: bool = False  # experiment-only, see ops/gates_pallas.py
     # Single-call fused gate tail (config.fused_gru_tail): z/tanh/blend in one
     # Pallas pass at the carry boundary; r stays in the conv epilogue. No VJP
     # — RAFTStereo sets this only under test_mode. See ops/gru_tail_pallas.py.
@@ -155,8 +148,6 @@ class ConvGRU(nn.Module):
         kz, bz = ConvParams(self.hidden_dim, cin, name="convz")()
         kr, br = ConvParams(self.hidden_dim, cin, name="convr")()
         kq, bq = ConvParams(self.hidden_dim, cin, name="convq")()
-        from raft_stereo_tpu.ops import gates_pallas
-
         if self.fused_tail:
             from raft_stereo_tpu.ops import gru_tail_pallas
 
@@ -164,15 +155,6 @@ class ConvGRU(nn.Module):
             r = jax.nn.sigmoid(_segmented_conv3x3(kr, br, (h, *inputs)) + cr)
             qx = _segmented_conv3x3(kq, bq, (r * h, *inputs))
             return gru_tail_pallas.fused_gru_tail(zx, cz, qx, cq, h)
-        if self.pallas_gates:
-            # EXPERIMENT-ONLY fused gating (scripts/exp_gate_fusion.py;
-            # inference-only — no VJP — so the flag is set by RAFTStereo
-            # only under env toggle + test_mode + TPU). See ops/gates_pallas.py.
-            zx = _segmented_conv3x3(kz, bz, (h, *inputs))
-            rx = _segmented_conv3x3(kr, br, (h, *inputs))
-            rh = gates_pallas.fused_rh(rx, cr, h)
-            qx = _segmented_conv3x3(kq, bq, (rh, *inputs))
-            return gates_pallas.fused_combine(zx, cz, qx, cq, h)
         z = jax.nn.sigmoid(checkpoint_name(_segmented_conv3x3(kz, bz, (h, *inputs)) + cz, GATE_SUM))
         r = jax.nn.sigmoid(checkpoint_name(_segmented_conv3x3(kr, br, (h, *inputs)) + cr, GATE_SUM))
         q = jnp.tanh(checkpoint_name(_segmented_conv3x3(kq, bq, (r * h, *inputs)) + cq, GATE_SUM))
@@ -244,7 +226,6 @@ class BasicMultiUpdateBlock(nn.Module):
     corr_channels: int
     n_gru_layers: int
     n_downsample: int
-    pallas_gates: bool = False  # experiment-only, see ops/gates_pallas.py
     fused_tail: bool = False  # config.fused_gru_tail, see ops/gru_tail_pallas.py
 
     @nn.compact
@@ -265,11 +246,10 @@ class BasicMultiUpdateBlock(nn.Module):
         # Instantiate cells unconditionally so params are stable across the
         # slow_fast_gru call variants (flax setup-by-first-use otherwise
         # depends on call order).
-        pg = self.pallas_gates
         ft = self.fused_tail
-        gru08 = ConvGRU(self.hidden_dims[2], pallas_gates=pg, fused_tail=ft, name="gru08")
-        gru16 = ConvGRU(self.hidden_dims[1], pallas_gates=pg, fused_tail=ft, name="gru16") if n >= 2 else None
-        gru32 = ConvGRU(self.hidden_dims[0], pallas_gates=pg, fused_tail=ft, name="gru32") if n == 3 else None
+        gru08 = ConvGRU(self.hidden_dims[2], fused_tail=ft, name="gru08")
+        gru16 = ConvGRU(self.hidden_dims[1], fused_tail=ft, name="gru16") if n >= 2 else None
+        gru32 = ConvGRU(self.hidden_dims[0], fused_tail=ft, name="gru32") if n == 3 else None
 
         if iter32 and n == 3:
             net[2] = gru32(net[2], *context[2], _pool2x(net[1]))

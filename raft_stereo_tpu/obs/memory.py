@@ -6,11 +6,8 @@ without dispatching device work or syncing any computation, so sampling
 it per serving batch / per training save boundary keeps the
 zero-sync/zero-executable hot-path contract intact. On CPU the method is
 absent or returns None/empty; the block degrades to zeros with
-`available: false` — callers (healthz, bench JSON, prom gauges) always
-get the same typed shape, so the validators hold on CPU CI and the TPU
-numbers light up unchanged when a rig attaches (this is what turns the
-5.41 GB corr-pyramid HBM *estimate* from BENCH_r05 into a measured
-curve).
+`available: false` — callers (healthz, chip_smoke.py, prom gauges) always
+get the same typed shape, on the CPU and on the chip.
 
 `jax.live_arrays()` walks the host-side registry of live jax.Array
 objects (again no device traffic); its count + nbytes total is the
@@ -22,7 +19,7 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional
 
 # Keys lifted from a device's memory_stats() dict when present. PJRT
-# backends vary; the first three are the common core the bench/healthz block
+# backends vary; the first three are the common core the healthz block
 # standardizes on. `peak_bytes_reserved` is the TPU runtime's: a program's
 # temporaries show only there, not in `peak_bytes_in_use` (a Middlebury-F
 # forward: 0.3 GB in use against 5.05 GB reserved, chip run, PR 22).
@@ -76,9 +73,8 @@ def _live_buffers() -> Dict[str, int]:
 
 
 def memory_block(devices: Optional[List[Dict[str, Any]]] = None) -> Dict[str, Any]:
-    """The typed `memory` block for /healthz and bench JSON
-    (scripts/check_bench_json.py `validate_memory`). Sums the per-device
-    view; always complete, zeros + available=false on CPU."""
+    """The typed `memory` block of /healthz. Sums the per-device view;
+    always complete, zeros + available=false on CPU."""
     if devices is None:
         devices = sample_device_memory()
     block: Dict[str, Any] = {

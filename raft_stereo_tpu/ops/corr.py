@@ -46,16 +46,13 @@ Array = jax.Array
 
 # Accuracy budget for the bf16 correlation volume: max end-point-error shift
 # (px) a bf16-stored pyramid may introduce vs the fp32 pyramid on the
-# synthetic eval, enforced three ways from ONE declared number — the tier-1
-# test (tests/test_fast_path.py), the bench `corr_precision` block, and the
-# bench-JSON gate. The eval regime is 2 refinement iterations with fp32
+# synthetic eval, held by the tier-1 test (tests/test_fast_path.py) and
+# read by chip_smoke.py. The eval regime is 2 refinement iterations with fp32
 # compute: at RANDOM init the GRU is not contractive, so pyramid rounding
 # amplifies chaotically with iteration count (measured: 0.012 px at 2 iters
 # vs 6.1 px at 16 on the same weights) — the 2-iter delta is the bounded,
 # lever-isolated quantity a budget can govern; re-anchor at 32 iters when a
-# trained checkpoint lands (ROADMAP R7). scripts/check_bench_json.py
-# holds a LITERAL mirror of this value (the validator must stay stdlib-only);
-# a tier-1 test pins the two together so they can never drift.
+# trained checkpoint lands (ROADMAP R7).
 #
 # One draw of untrained weights is noise around this number, so the tier-1
 # test holds the MEDIAN of five draws to it (CPU, 128x192: 0.010-0.088 px

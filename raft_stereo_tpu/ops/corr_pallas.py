@@ -145,10 +145,7 @@ def _lookup_kernel(coords_ref, *rest, radius: int, w2_padded: Tuple[int, ...]):
             # -1 padding matches none), so select-into-acc replaces the
             # round-3 masked add — one full-vector VPU pass fewer per tile.
             # Measured effect is marginal (3.59-3.85 vs 3.89-3.91 ms/iter in
-            # the 32-chain micro-bench, scripts/exp_lookup.py) but never
-            # slower; kept as the kernel's final form — see ROADMAP
-            # "Round-4 lookup verdict" for why no further structural idea
-            # survives on this toolchain.
+            # a 32-call chain at Middlebury-F, round 4) but never slower.
             acc = jnp.where(tile_id == tile, gathered, acc)
 
         tap0 = acc[:, :k]
@@ -412,9 +409,9 @@ def pallas_corr_lookup(pyramid, coords: Array, radius: int) -> Array:
 # W2, so this path uses its own <= _PF_W1_BLOCK query blocks: more programs,
 # each lighter on VMEM (the dense kernel's (768, sum W2p) resident slice
 # shrinks ~6x), the hypothesis being that deeper DMA/compute overlap beats
-# the per-program overhead the _W1_BLOCK tuning note documents. TPU verdict
-# PENDING BENCH_r06 (`per_iter.levers.prefetch_lookup` A/B); retirement
-# discipline as in ops/encoder_pallas.py.
+# the per-program overhead the _W1_BLOCK tuning note documents. Measured as
+# the `prefetch_lookup` key of a configuration's `program` group: PERF.md
+# section 6, "Levers".
 
 _PF_W1_BLOCK = 256
 

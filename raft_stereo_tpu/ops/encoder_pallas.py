@@ -1,7 +1,8 @@
 """Fused Pallas TPU kernels for the encoder ResidualBlock chain.
 
-Targets the ~235 ms loop-invariant forward prefix (BENCH_r05: feature +
-context encoders + corr-pyramid build dominate low-iteration inference).
+Targets the loop-invariant forward prefix (feature + context encoders +
+corr-pyramid build: 230 ms of a 939 ms Middlebury-F map, PERF.md section 5,
+and most of a low-iteration inference).
 The XLA inference graph pays, per full-res residual block, two conv fusions
 PLUS separate full-resolution elementwise passes for every
 InstanceNorm/FrozenBN apply and residual join — each pass is a ~1.5 GB
@@ -30,8 +31,8 @@ implicit-GEMM Pallas kernels where those epilogues never leave VMEM:
   untouched (the flax glue in models/extractor.py declares the identical
   `ConvParams`/`FrozenBatchNorm` trees and passes raw arrays here).
 
-Memory discipline (the gates_pallas lesson — fuse at BLOCK granularity so no
-layout boundary lands inside a hot loop): conv operands are read through a
+Memory discipline (fuse at BLOCK granularity so no layout boundary lands
+inside a hot loop; PERF.md section 6, "Levers", gate fusion): conv operands are read through a
 manual HBM->VMEM DMA ring (4 row slots, one-row lookahead), so every input
 row is fetched exactly ONCE per conv despite the 3-row stencil — a
 BlockSpec halo would re-fetch each row three times and erase the win. All
@@ -45,15 +46,12 @@ the kernels run in the Pallas interpreter (ops/pallas_mode.py), which the
 tier-1 `-m kernels` parity tests rely on; full-resolution interpret
 execution is pathologically slow.
 
-Verdict: PENDING first end-to-end TPU A/B (ROADMAP D1; scripts/
-exp_fused_encoder.py runs it standalone; bench.py measures the default,
-un-fused configuration only). The kernels have only ever run interpreted;
-what is known from the chip's compiler (PR 22, tests/test_chip_compile.py):
-`fused_conv_s2d` and `fused_pyramid_state` compile for v5e at Middlebury-F
-width, the latter with bf16 storage only since its pooling mask is selected
-in float32 (the bf16 select was refused: "Invalid relayout ... (8,128) ->
-(16,128)"). If the measured end-to-end delta is negative, delete this path
-with its flag, tests and script.
+Measured as the `fused_encoder` key of a configuration's `program` group
+(PERF.md section 6, "Levers"; ROADMAP D1 holds what follows from it). From
+the chip's compiler (PR 22, tests/test_chip_compile.py): `fused_conv_s2d` and
+`fused_pyramid_state` compile for v5e at Middlebury-F width, the latter with
+bf16 storage only since its pooling mask is selected in float32 (the bf16
+select was refused: "Invalid relayout ... (8,128) -> (16,128)").
 """
 
 from __future__ import annotations

@@ -6,23 +6,21 @@ GRU convs (models/update.py):
   tail:   h' = (1-z) * h + z * tanh(qx + cq),  z = sigmoid(zx + cz)
   motion: cat[relu(conv_out 126ch), flow (1ch), zeros (1ch)] -> 128ch
 
-This is the surviving HALF of the retired ops/gates_pallas.py experiment,
-restructured around its post-mortem: that variant paid the Pallas
-layout-boundary tax THREE times per cell (rh kernel + combine kernel forced
-every ~91 MB gate tensor out of XLA's conv fusions). Here each cell makes ONE
-call, placed where a materialization already exists — h' is the scan carry,
-so the tail's output buffer is a boundary XLA pays either way — and the
-r-gate stays in the conv epilogue fusion. The motion kernel replaces a
-relu + 128ch concat + zeros materialization with one write of the already-
-boundary motion tensor feeding the finest GRU. Hypothesis: halving the
-boundary count flips the sign of the gates_pallas verdict; counter-hypothesis:
-any forced operand layout still loses to XLA's epilogue fusion. TPU verdict
-PENDING BENCH_r06 (`per_iter.levers.fused_gru_tail` A/B in bench.py); if
-negative, retire with numbers per the encoder_pallas docstring discipline.
+A Pallas call forces its operands out of XLA's conv fusions (a three-call
+gate fusion that put an r*h kernel and a combine kernel in every cell paid
+that for every ~91 MB gate tensor and was retired; PERF.md section 6,
+"Levers"). Here each cell makes ONE call, placed where a materialization
+already exists — h' is the scan carry, so the tail's output buffer is a
+boundary XLA pays either way — and the r-gate stays in the conv epilogue
+fusion. The motion kernel replaces a relu + 128ch concat + zeros
+materialization with one write of the already-boundary motion tensor feeding
+the finest GRU. Hypothesis: one boundary a cell is cheap enough to win;
+counter-hypothesis: any forced operand layout still loses to XLA's epilogue
+fusion. Measured as the `fused_gru_tail` key of a configuration's `program`
+group: PERF.md section 6, "Levers".
 
-Activation: `RAFTStereoConfig.fused_gru_tail` — a product config flag (unlike
-the env-only gates_pallas experiment) because it is wired as a bench lever
-and CLI knob. TEST-MODE forwards only (the kernels define no VJP; the
+Activation: `RAFTStereoConfig.fused_gru_tail` (CLI `--fused_gru_tail`).
+TEST-MODE forwards only (the kernels define no VJP; the
 exact-gradient-equality test in tests/test_fast_path.py proves the training
 graph untouched). On the CPU backend the kernels run in the Pallas
 interpreter, so the tier-1 parity tests (`-m kernels`) cover identical

@@ -46,7 +46,7 @@ HLO for the four collective families. For the spatial presets the corr
 chain must audit clean (zero collectives — the epipolar-independence
 claim); the *full* forward legitimately carries halo collective-permutes
 and instance-norm all-reduces, which is what the per-preset
-``collectives_expected`` flag in the bench JSON records.
+``collectives_expected`` flag of the multichip dry run's record says.
 """
 
 from __future__ import annotations

@@ -33,9 +33,9 @@ unlinked) with a loud warning, the miss is counted, and the caller falls
 back to trace-and-compile, rewriting the entry for the next boot. Corrupt
 caches therefore self-heal and can never crash or wedge a boot.
 
-`stats()` feeds /healthz, the Prometheus gauges and the bench `boot` block:
+`stats()` feeds /healthz, the Prometheus gauges and the service's boot block:
 `entries == cache_hits + cache_misses` (every warmup lookup is exactly one
-of the two), which check_bench_json's `validate_boot` asserts.
+of the two), which tests/report_checks.py `validate_boot` asserts.
 """
 
 from __future__ import annotations
@@ -271,7 +271,7 @@ class ExecutableCache:
     # -- observability -----------------------------------------------------
     def stats(self) -> Dict[str, object]:
         """`entries` is lookups attempted (hits + misses) — the identity
-        check_bench_json.validate_boot pins."""
+        tests/report_checks.py `validate_boot` pins."""
         with self._lock:
             return {
                 "enabled": True,

@@ -24,7 +24,7 @@ executable (ServingMetrics records per-batch bucket provenance; the tier-1
 test audits it).
 
 `ServingMetrics` is the single counter authority the /metrics endpoint and
-bench_serving read: queue depth, batch-fill ratio, latency percentiles,
+`benchmark/drivers/serve.py` read: queue depth, batch-fill ratio, latency percentiles,
 deadline-miss / early-exit totals, per-bucket request counts.
 """
 
@@ -122,7 +122,7 @@ class ServingMetrics:
         # Fleet accounting: batches requeued onto another replica after a
         # failure/hang, plus per-replica dispatch + in-flight counters (the
         # load-aware router's own state lives in the fleet; these mirrors
-        # are what /metrics and bench_serving read). Keys are "r<idx>".
+        # are what /metrics serves). Keys are "r<idx>".
         self.requeues_total = 0
         # Replica replacements completed by the fleet's respawn path (PR
         # 16): a sticky-failed replica retired for a fresh cache-booted
@@ -272,7 +272,7 @@ class ServingMetrics:
     def attribution_summary(self) -> Dict[str, object]:
         """Per-request latency attribution over the bounded window:
         queue-wait, device-time, host-gap histogram summaries for
-        bench_serving, /healthz, and the prom endpoint. Separate from
+        /healthz, the prom endpoint and `benchmark/drivers/serve.py`. Separate from
         snapshot() on purpose — the legacy /metrics JSON key set is frozen
         byte-compatible."""
         with self._lock:

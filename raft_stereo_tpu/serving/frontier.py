@@ -1443,8 +1443,8 @@ class Frontier:
         return 502, record
 
     def rollout_block(self) -> Dict[str, object]:
-        """The machine-checked rollout summary: bench_serving emits it,
-        check_bench_json.validate_rollout gates it. Generations below are
+        """The machine-checked rollout summary (its invariants:
+        tests/report_checks.py `validate_rollout`). Generations below are
         each backend's last OBSERVED swap generation (0 until first
         observed); fleet_generation is their minimum — the generation the
         whole fleet provably reached."""

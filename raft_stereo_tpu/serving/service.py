@@ -228,7 +228,7 @@ class StereoService:
         return list(getattr(self.engine, "audit_records", []))
 
     def hlo_audit_block(self) -> Dict[str, object]:
-        """The bench/CLI `hlo_audit` block: contract stats over this boot's
+        """The CLI's `hlo_audit` block: contract stats over this boot's
         warmed executables plus rendered violation details (empty list on a
         healthy tree — `serve --warmup_only --audit` exits 4 otherwise)."""
         from tools.graftaudit.contracts import audit_records as _audit
@@ -581,8 +581,8 @@ class StereoService:
     def boot_block(self) -> Dict[str, object]:
         """The instant-boot/recovery numbers: warmup wall time, AOT cache
         hit accounting and replica respawns — served in /healthz, mirrored
-        into prom gauges, and emitted as the bench-serving `boot` block
-        (check_bench_json.validate_boot pins its invariants)."""
+        into prom gauges (tests/report_checks.py `validate_boot` pins its
+        invariants)."""
         ws = self.warm_summary or {}
         cache = ws.get("aot_cache") or {"enabled": False}
         return {

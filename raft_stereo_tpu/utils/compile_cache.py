@@ -1,8 +1,7 @@
 """The one place the persistent XLA compilation cache directory is chosen.
 
-Every entry point that compiles (`train`, `evaluate`, `serve`, `bench.py`,
-`scripts/bench_serving.py`, `chip_smoke.py`) calls `setup_compile_cache()`
-before its first compile, so a second run from the same checkout finds what
+Every entry point that compiles (`train`, `evaluate`, `serve`,
+`benchmark/run.py`, `chip_smoke.py`) calls `setup_compile_cache()` before its first compile, so a second run from the same checkout finds what
 the first compiled. The directory is part of the cache key's world: one that
 moves (a temporary name, a pid, a time) never hits.
 

@@ -1,9 +1,9 @@
 """Shared stdlib HTTP client: one timeout/retry discipline for every
 in-repo HTTP caller.
 
-Promoted for PR 17 so the front-tier router (`serving/frontier.py`), the
-`serve --reload_ckpt` client and `scripts/bench_serving.py --frontier` all
-speak HTTP the same way instead of each hand-rolling urllib calls:
+The front-tier router (`serving/frontier.py`), the `serve --reload_ckpt`
+client and the tests that drive a fleet over HTTP all speak it the same way
+instead of each hand-rolling urllib calls:
 
 - every request carries an explicit timeout (urllib's default is NONE —
   a stalled server would hang the caller forever);

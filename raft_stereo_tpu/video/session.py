@@ -3,8 +3,8 @@
 RAFT-Stereo's iterative ConvGRU refinement is naturally incremental: on
 video, the previous frame's disparity is a far better starting point than
 `coords1 == coords0`, so a warm-started frame reaches cold-start EPE in a
-fraction of the iterations (the `iters_to_epe_parity` A/B in the bench
-measures exactly this). `StreamSession` is the standalone driver: it owns
+fraction of the iterations (`warm_cold_parity` below measures exactly
+this). `StreamSession` is the standalone driver: it owns
 one jitted (prelude, chunk, finalize) triple from models/anytime.py, carries
 the previous frame's low-res flow (and optionally the GRU hidden state)
 across `process()` calls, and feeds it back through the `flow_init` path —

@@ -2,8 +2,9 @@
 
 Answers the question the round-1 review left open: can the host-side loader
 feed the device step rate? The device target is the MEASURED 2.35
-steps/s/chip of the b4 training recipe (round-4 TPU calibration, BENCH_r05
-— i.e. ~0.426 s/step at batch 4, not round 1's 0.62 s estimate; the target
+steps/s/chip of the b4 training recipe (round-4 TPU calibration: ~0.426
+s/step at batch 4; `--step_time` takes the current one, 0.406 s by the
+ledger's `step_ms_p50.train`; the target
 is >= 2x that so input never gates training, and the `input_bound` verdict
 per config says in one bool whether it does). The reference sizes its
 worker pool as SLURM_CPUS_PER_TASK-2 *processes* (reference
@@ -20,7 +21,6 @@ Builds synthetic on-disk trees at REAL frame geometry:
 Prints one JSON line per configuration: items/s, batches/s, MB/s, the ratio
 to the device step rate at that batch size, and the `input_bound` verdict
 (loader slower than the device step — the config would gate training).
-`scripts/check_bench_json.py validate_loader` enforces the line schema.
 
 Usage: python scripts/bench_loader.py [--batch_size 8] [--workers 2 6 10]
        [--step_time 0.4255] [--epochs 3]

@@ -148,8 +148,8 @@ def pytest_configure(config):
         "async-checkpoint + device-prefetch acceptance fit, the SIGKILL-"
         "mid-async-commit crash leg, the 2-process fsdp state spine, and "
         "the fsdp param-placement snapshot. Tier-1; collection-ordered dead "
-        "last (each compiles its own trainer/pod — minutes of CPU) and "
-        "gated in ci_checks (exit 15). Select with -m io_spine",
+        "last (each compiles its own trainer/pod — minutes of CPU). "
+        "Select with -m io_spine",
     )
     config.addinivalue_line(
         "markers",
@@ -159,7 +159,7 @@ def pytest_configure(config):
         "percentile edges, and the strict-mode obs-on serving + training "
         "acceptance runs (compiles_post_grace == 0 with every pillar on). "
         "Tier-1; collection-ordered dead last (warms its own service and "
-        "trainer) and gated in ci_checks (exit 16). Select with -m obs",
+        "trainer). Select with -m obs",
     )
     config.addinivalue_line(
         "markers",
@@ -169,7 +169,7 @@ def pytest_configure(config):
         "replica auto-respawn torture test (sticky-failed replica healed "
         "under traffic, bit-identical outputs, compiles_post_grace == 0). "
         "Tier-1; collection-ordered dead last (boots whole services, some "
-        "twice) and gated in ci_checks (exit 17). Select with -m boot",
+        "twice). Select with -m boot",
     )
     config.addinivalue_line(
         "markers",
@@ -180,8 +180,7 @@ def pytest_configure(config):
         "slowloris hardening, and the kill-a-backend-mid-traffic chaos "
         "drill against a real 2-backend fleet booted from a shared AOT "
         "cache. Tier-1; collection-ordered after `faults_fleet` (it boots "
-        "whole services) and gated in ci_checks (exit 18). Select with "
-        "-m frontier",
+        "whole services). Select with -m frontier",
     )
     config.addinivalue_line(
         "markers",
@@ -194,8 +193,7 @@ def pytest_configure(config):
         "under mixed traffic with a ledger-proved zero mixed-weight "
         "window; mid-roll backend kill rolled BACK bit-identically). "
         "Tier-1; collection-ordered after `frontier` (it boots whole "
-        "services) and gated in ci_checks (exit 19). Select with "
-        "-m rollout",
+        "services). Select with -m rollout",
     )
     config.addinivalue_line(
         "markers",
@@ -205,8 +203,8 @@ def pytest_configure(config):
         "on the real train step, the chunk-boundary sharding fixpoint for "
         "every warmed (bucket, batch) combo under dp AND spatial, and the "
         "scripts/audit.py CLI round-trip. Tier-1; collection-ordered dead "
-        "last (warms real engines on the 8-device mesh) and gated in "
-        "ci_checks (exit 20). Select with -m audit",
+        "last (warms real engines on the 8-device mesh); the fixture "
+        "selftest alone is ci_checks' exit 20. Select with -m audit",
     )
     config.addinivalue_line(
         "markers",

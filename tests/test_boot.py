@@ -26,8 +26,6 @@ test), so the module is collection-ordered dead last (conftest) and gated
 in ci_checks.sh (exit 17).
 """
 
-import os
-import sys
 import threading
 import time
 
@@ -36,8 +34,7 @@ import pytest
 
 from fault_injection import failing_run_batch, hung_chunk
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "scripts"))
-from check_bench_json import validate_boot  # noqa: E402
+from report_checks import validate_boot
 
 pytestmark = pytest.mark.boot
 

@@ -36,7 +36,6 @@ the module is ORDER-DEPENDENT by design and collection-ordered after
 import json
 import os
 import socket
-import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -46,8 +45,7 @@ import pytest
 
 from fault_injection import http_response_fault
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "scripts"))
-from check_bench_json import validate_frontier  # noqa: E402
+from report_checks import validate_frontier
 
 pytestmark = pytest.mark.frontier
 

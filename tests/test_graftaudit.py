@@ -313,6 +313,29 @@ def test_collective_whitelist_table():
     assert "all-to-all" in expected_collectives("eval_forward", "spatial")
 
 
+def test_train_step_all_to_all_is_allowed_by_provenance_only():
+    """The dp train step's all-to-alls come from the batch-axis join of the
+    image pair (`.../jvp(RAFTStereo)/concatenate` and its transpose): GA003
+    lets those through in a train step, names any other provenance, and
+    lets nothing through in a serving stage."""
+    from tools.graftaudit.contracts import _check_collectives
+
+    def a2a(n, provenance):
+        return (f"%all-to-all.{n} = (f32[1,8]{{1,0}}, f32[1,8]{{1,0}}) all-to-all(%a.{n}, %b.{n}), "
+                f'dimensions={{0}}, metadata={{op_name="{provenance}"}}\n'
+                f"%get-tuple-element.{n} = f32[1,8]{{1,0}} get-tuple-element(%all-to-all.{n}), index=0\n")
+
+    join = a2a(1, "jit(step_fn)/jvp(RAFTStereo)/concatenate") + a2a(
+        2, "jit(step_fn)/transpose(jvp(RAFTStereo))/concatenate")
+    step = {"entry": "train:step:dp", "kind": "train_step", "preset": "dp", "hlo": join}
+    assert _check_collectives(step) == []
+    foreign = _check_collectives(dict(step, hlo=join + a2a(3, "jit(step_fn)/jvp(RAFTStereo)/reshape")))
+    assert len(foreign) == 1 and foreign[0].contract == "GA003"
+    assert "jvp(RAFTStereo)/reshape" in foreign[0].render() and "concatenate" not in foreign[0].detail
+    served = _check_collectives(dict(step, kind="chunk", hlo=join))
+    assert len(served) == 1 and served[0].message.startswith("unexpected collective family all-to-all")
+
+
 def test_missing_snapshot_placeholder_fails_ga001():
     """A cache-hit chunk whose entry predates auditing gets a carry-less
     placeholder record (engine._warm_stage) — GA001 must flag the coverage

@@ -258,19 +258,17 @@ def test_shipped_tree_is_lint_clean():
     package + tooling, exactly as scripts/ci_checks.sh does."""
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts", "lint.py"),
-         "raft_stereo_tpu", "scripts", "tools", "bench.py", "__graft_entry__.py"],
+         "raft_stereo_tpu", "scripts", "tools", "__graft_entry__.py"],
         capture_output=True, text=True, cwd=REPO,
     )
     assert proc.returncode == 0, f"tree not lint-clean:\n{proc.stdout}{proc.stderr}"
 
 
 def test_ci_checks_script_passes():
-    """The CI gate (ruff when available + graftlint + validator selftests +
-    bench schema) must pass on the shipped tree — and this test is what
+    """The CI gate (ruff when available + graftlint + the validator and
+    audit selftests) must pass on the shipped tree — and this test is what
     keeps the gate itself from rotting. CI_CHECKS_FAST skips only the
-    nested `-m kernels` pytest: this tier-1 suite already collects those
-    tests directly, and running several minutes of interpreter-mode
-    compiles twice would not fit the tier-1 budget."""
+    script's one pytest call: this suite IS that call."""
     proc = subprocess.run(
         ["bash", os.path.join(REPO, "scripts", "ci_checks.sh")],
         capture_output=True, text=True, cwd=REPO,
@@ -562,7 +560,7 @@ def test_unused_suppression_reporting(tmp_path):
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts", "lint.py"),
          "--report-unused-suppressions",
-         "raft_stereo_tpu", "scripts", "tools", "bench.py", "__graft_entry__.py"],
+         "raft_stereo_tpu", "scripts", "tools", "__graft_entry__.py"],
         capture_output=True, text=True, cwd=REPO,
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr

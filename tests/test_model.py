@@ -5,6 +5,7 @@ gradient flow, and full-forward numerical parity against the torch reference
 All forwards are jitted — see conftest docstring for why.
 """
 
+import hashlib
 import os
 import sys
 
@@ -182,7 +183,7 @@ def test_s2d_kernel_embeddings_match_direct_conv(rng):
     """The W-space-to-depth kernel embeddings (dense stride-1, stride-2
     entry, 1x1 skip) must reproduce the direct conv exactly up to f32
     rounding — the unit-level guard for the encoder_s2d path (round 4;
-    derivation in layers.py, measured in scripts/exp_s2d_layer1.py)."""
+    derivation in layers.py)."""
     from raft_stereo_tpu.models.layers import (
         dense_w_kernel,
         entry_w_kernel,
@@ -353,3 +354,55 @@ def test_sequential_encoder_matches_batched(rng, b):
         lambda v, a, b: model_seq.apply(v, a, b, iters=3, test_mode=True)
     )(variables, i1, i2)
     np.testing.assert_allclose(np.asarray(up_s), np.asarray(up_b), rtol=2e-5, atol=2e-5)
+
+
+# The test-mode forward and the serving chunk as the parent of PR 32 lowered
+# them (sha256 of the StableHLO text at a tiny shape, recorded on that parent
+# with these very functions): a refactor of models/ that means to change no
+# operation keeps them; one that means to records them anew and says so.
+FORWARD_SHA256 = {
+    "reg": "77a68bfb6d41011fe7352e8d08a3ab9949604cd95e71aacaeeba0b636b5e9078",
+    "pallas": "4309df9f5959ac9522af899d07ce5ccee7a52699cf0ac1e103e19ff28a6cc7b7",
+}
+CHUNK_SHA256 = {
+    "reg": "79faf3ab67f7d665bb1cbdbf0a0a92b6b33fcf6249553f8496d3ea4a022d60a8",
+    "pallas": "9b19d32af9f9014e7b487a407afca57a1f1bd03b79d9c597d4fb2579645829d4",
+}
+
+
+def _tiny_abstract(corr_implementation):
+    """A tiny configuration, an abstract image and the model's abstract variables."""
+    cfg = RAFTStereoConfig(hidden_dims=(16, 16, 16), n_gru_layers=2, corr_levels=2, corr_radius=2,
+                           corr_implementation=corr_implementation)
+    image = jax.ShapeDtypeStruct((1, 32, 48, 3), jnp.float32)
+    variables = jax.eval_shape(
+        lambda r, a, b: RAFTStereo(cfg).init(r, a, b, iters=1), jax.random.PRNGKey(0), image, image)
+    return cfg, image, variables
+
+
+def stereo_forward_text(corr_implementation):
+    cfg, image, variables = _tiny_abstract(corr_implementation)
+    forward = jax.jit(lambda v, a, b: RAFTStereo(cfg).apply(v, a, b, iters=3, test_mode=True))
+    return forward.lower(variables, image, image).as_text()
+
+
+def anytime_chunk_text(corr_implementation):
+    from raft_stereo_tpu.models.anytime import AnytimeChunk, AnytimePrelude
+
+    cfg, image, variables = _tiny_abstract(corr_implementation)
+    state = jax.eval_shape(AnytimePrelude(cfg).apply, variables, image, image)
+    return jax.jit(AnytimeChunk(cfg, chunk_iters=2).apply).lower(variables, state).as_text()
+
+
+def _sha256(text):
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("corr_implementation", sorted(FORWARD_SHA256))
+def test_the_stereo_forward_lowers_to_the_parents_text(corr_implementation):
+    assert _sha256(stereo_forward_text(corr_implementation)) == FORWARD_SHA256[corr_implementation]
+
+
+@pytest.mark.parametrize("corr_implementation", sorted(CHUNK_SHA256))
+def test_the_anytime_chunk_lowers_to_the_parents_text(corr_implementation):
+    assert _sha256(anytime_chunk_text(corr_implementation)) == CHUNK_SHA256[corr_implementation]

@@ -633,8 +633,8 @@ def test_request_lifecycle_reconstructible_from_ring(twin_services):
 
 
 def test_metrics_json_snapshot_key_set_is_frozen(twin_services):
-    """The legacy /metrics JSON surface: bench_serving and operator
-    tooling key off these exact names — prom is the additive surface,
+    """The legacy /metrics JSON surface: benchmark/drivers/serve.py and
+    operator tooling key off these exact names — prom is the additive surface,
     this one must not drift."""
     assert set(twin_services["obs"].metrics()) == {
         "requests_total",

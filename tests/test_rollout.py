@@ -31,9 +31,7 @@ after `frontier` (conftest), gated in ci_checks.sh (exit 19).
 """
 
 import json
-import os
 import random
-import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -43,8 +41,7 @@ import pytest
 
 from fault_injection import perturbed_variables
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "scripts"))
-from check_bench_json import validate_rollout  # noqa: E402
+from report_checks import validate_rollout
 
 pytestmark = pytest.mark.rollout
 

@@ -265,7 +265,7 @@ def _lookup_args():
 
 
 def _kernel_calls():
-    from raft_stereo_tpu.ops import encoder_pallas, gates_pallas, gru_tail_pallas
+    from raft_stereo_tpu.ops import encoder_pallas, gru_tail_pallas
 
     corr_pallas, state, coords = _lookup_args()
     fmap = jnp.ones((1, 4, 16, 8))
@@ -284,8 +284,6 @@ def _kernel_calls():
         "encoder_conv_s2d": lambda: encoder_pallas.fused_conv_s2d(
             x, jnp.ones((3, 3, 128, 128)), jnp.ones((128,)), aff, affine_form="in", emit_stats=True),
         "encoder_join": lambda: encoder_pallas.fused_join_s2d(x, x, aff, "in"),
-        "gates_rh": lambda: gates_pallas.fused_rh(g, g, g),
-        "gates_combine": lambda: gates_pallas.fused_combine(g, g, g, g, g),
         "gru_tail": lambda: gru_tail_pallas.fused_gru_tail(g, g, g, g, g),
         "motion_tail": lambda: gru_tail_pallas.fused_motion_tail(jnp.ones((1, 8, 16, 126)), jnp.ones((1, 8, 16, 1))),
     }
@@ -324,7 +322,7 @@ KERNELS = [
     "block_attention", "block_attention_dq", "block_attention_dkv", "grouped_matmul", "grouped_matmul_drhs",
     "gather_rows", "scatter_add_rows",
     "corr_lookup", "corr_scatter", "corr_lookup_prefetch", "corr_pyramid", "encoder_conv_s2d",
-    "encoder_join", "gates_rh", "gates_combine", "gru_tail", "motion_tail",
+    "encoder_join", "gru_tail", "motion_tail",
 ]
 
 
@@ -356,7 +354,7 @@ def test_every_pallas_call_in_ops_is_named():
             assert found, f"a pl.pallas_call in {path} has no name="
             if found.group(2):
                 named.add(found.group(2))
-    assert named | {"gates_rh", "gates_combine"} == set(KERNELS)
+    assert named == set(KERNELS)
 
 
 def test_kernel_bytes_do_not_depend_on_who_lowers(monkeypatch):

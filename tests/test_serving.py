@@ -15,7 +15,7 @@ The acceptance criteria from the serving design, each machine-checked here:
   boots (the monitor starts inside `engine.warm()`), so `compiles_post_grace`
   staying 0 after traffic is attributable to the serving path alone;
 - /healthz validates under the run_report schema; /metrics carries the
-  counter contract bench_serving reads;
+  counter contract benchmark/drivers/serve.py reads;
 - the batcher NEVER mixes buckets in one batch (batch_log audit).
 
 Warmup compiles every (bucket, batch-size) x (prelude, chunk, finalize)
@@ -349,7 +349,7 @@ def test_healthz_validates_under_run_report_schema(served):
 
 
 def test_metrics_snapshot_contract(served):
-    """The exact counter surface /metrics serves and bench_serving reads."""
+    """The exact counter surface /metrics serves and benchmark/drivers/serve.py reads."""
     snap = served.metrics()
     for key in (
         "requests_total",

@@ -139,8 +139,7 @@ def test_corr_volume_h_shards_at_full_middlebury_shape_compile_only():
     # nowhere near the unsharded footprint (>= the 1.03 GB bf16 volume plus
     # its ~2 GB fp32 pre-cast einsum intermediate). The line sits at 0.7:
     # the CPU backend's naive temp_size_in_bytes (no liveness-aware
-    # peak_memory_in_bytes field off-TPU — the same overcount bench.py's
-    # round-3 verdict documents) measures 0.643 GB on this jaxlib, up from
+    # peak_memory_in_bytes field off-TPU) measures 0.643 GB on this jaxlib, up from
     # just under 0.6 when the guard was written; a sharding regression
     # would land at several GB, far above either line.
     ma = compiled.memory_analysis()

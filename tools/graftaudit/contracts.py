@@ -21,9 +21,11 @@ GA002 donation-honored    Every ``donate_argnums`` parameter appears in the
 GA003 collective-whitelist Only the preset's expected collective families
                           appear; on the pure-spatial mesh, zero collectives
                           carry corr provenance (the per-row epipolar
-                          independence claim). all-to-all is whitelisted
-                          nowhere — it always means a spec is fighting the
-                          partitioner.
+                          independence claim). all-to-all is whitelisted on
+                          no serving or train path — it means a spec is
+                          fighting the partitioner — but for the train
+                          step's batch-axis join of the image pair, allowed
+                          by its op_name provenance (TRAIN_STEP_PAIR_JOIN).
 GA004 corr-dtype-pin      With corr_dtype=bfloat16, no f32-from-bf16 convert
                           carries corr provenance (no silent upcast-then-
                           store of pyramid-scale tensors).
@@ -48,6 +50,7 @@ Pure stdlib: records are dicts, checks are regex passes over saved HLO text
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from tools.graftaudit import hlo as H
@@ -112,8 +115,9 @@ def expected_collectives(kind: str, preset: str) -> Tuple[str, ...]:
         # batch) and slice/pad-edge collective-permutes the partitioner
         # inserts even under plain dp — measured on the real step, op_name
         # provenance jvp(RAFTStereo)/slice|pad. fsdp adds param gathers.
-        # all-to-all stays banned: on a train step it always means a spec
-        # is fighting the partitioner.
+        # all-to-all is no family a train step may hold, with ONE exception
+        # by provenance, TRAIN_STEP_PAIR_JOIN below: anywhere else it means a
+        # spec is fighting the partitioner.
         return _SPATIAL_LEGIT
     # Serving stages and the eval forward: dp is single-program — any
     # collective means the partitioner disagreed with the deployment.
@@ -127,6 +131,20 @@ def expected_collectives(kind: str, preset: str) -> Tuple[str, ...]:
         # stage (where all-to-all stays whitelisted nowhere).
         return _SPATIAL_LEGIT + ("all-to-all",)
     return _SPATIAL_LEGIT
+
+
+# The all-to-alls of the dp train step (6 at the audit's slim size, 3 at the
+# recipe's). `encode_features` joins the image pair on the BATCH axis so both
+# ride one 2B batch through the feature encoder, and splits the feature maps
+# again; the batch axis is the sharded one, so the join moves half of each
+# device's rows (3-channel images going in) and the split's transpose moves
+# the feature maps' gradient back. The batch spec is right and this is the
+# partitioner's answer to it; XLA stamps the instructions
+# `.../jvp(RAFTStereo)/concatenate` and
+# `.../transpose(jvp(RAFTStereo))/concatenate`. An all-to-all of any other
+# provenance in a train step is still a violation, and no serving stage has
+# this exception. (A join that interleaves the pair would need none.)
+TRAIN_STEP_PAIR_JOIN = re.compile(r"jvp\(RAFTStereo\)\)?/concatenate$")
 
 
 def corr_line_check_applies(record: dict) -> bool:
@@ -206,7 +224,21 @@ def _check_collectives(record: dict) -> List[Violation]:
     entry, text = record["entry"], record["hlo"]
     expected = expected_collectives(record["kind"], record.get("preset", "dp"))
     out: List[Violation] = []
-    for family, count in sorted(H.unexpected_collectives(text, expected).items()):
+    unexpected = H.unexpected_collectives(text, expected)
+    if record["kind"] == "train_step" and "all-to-all" in unexpected:
+        del unexpected["all-to-all"]
+        names = [H.op_name(line) for line in H.collective_definitions(text, "all-to-all")]
+        foreign = [name or "no op_name" for name in names if not TRAIN_STEP_PAIR_JOIN.search(name)]
+        if foreign:
+            out.append(
+                Violation(
+                    "GA003",
+                    entry,
+                    f"{len(foreign)} all-to-all(s) that are not the image pair's batch-axis join",
+                    f"resharding: {sorted(set(foreign))[:6]}",
+                )
+            )
+    for family, count in sorted(unexpected.items()):
         out.append(
             Violation(
                 "GA003",
@@ -310,7 +342,9 @@ ALL_CONTRACTS: Tuple[Contract, ...] = (
             "all-to-all is whitelisted in exactly one place — the OFFLINE "
             "spatial eval forward, whose pinned out_sharding makes the "
             "convex-upsample pixel shuffle reshard — and nowhere on a "
-            "serving or train hot path. On the pure-spatial mesh the "
+            "serving or train hot path, except, by op_name provenance, the "
+            "train step's batch-axis join of the image pair "
+            "(TRAIN_STEP_PAIR_JOIN). On the pure-spatial mesh the "
             "corr chain must additionally carry ZERO collectives (per-row "
             "epipolar independence). Fix: find the op whose sharding "
             "constraint forces the communication (the HLO line's op_name "

@@ -74,6 +74,21 @@ def collective_lines(hlo: str) -> List[str]:
     return [line for line in hlo.splitlines() if _COLLECTIVE_LINE.search(line)]
 
 
+def collective_definitions(hlo: str, family: str) -> List[str]:
+    """The lines that DEFINE a collective of `family` (its opcode, or the
+    `-start` half, followed by its operand list), without the lines that
+    merely read such an instruction's result by name."""
+    opcode = re.compile(rf"(?<![\w%.-]){re.escape(family)}(?:-start)?\(")
+    return [line for line in hlo.splitlines() if opcode.search(line)]
+
+
+def op_name(line: str) -> str:
+    """The `op_name` provenance XLA stamped on an instruction line ("" where
+    there is none): for a collective, the op whose tensor it reshards."""
+    found = re.search(r'op_name="([^"]*)"', line)
+    return found.group(1) if found else ""
+
+
 def corr_collective_lines(hlo: str) -> List[str]:
     """HLO instruction lines that carry BOTH a collective op and corr-chain
     provenance (op_name / value names mentioning ``corr``). XLA stamps every

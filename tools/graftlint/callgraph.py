@@ -21,7 +21,7 @@ paper over that. This module lifts the analysis to the PROJECT level:
 - **cross-module jit registry**: jit bindings travel to importing modules
   (bare imported names, `module.f` access) and `self.<attr>` bindings are
   visible project-wide, so `trainer.train_step(...)` is a recognized
-  compiled call in bench.py, not just in trainer.py.
+  compiled call in chip_smoke.py, not just in trainer.py.
 - **function summaries** feeding the interprocedural rules:
   * returns-device-value (GL005): a function whose return flows from a
     compiled call taints its callers everywhere;
@@ -69,7 +69,7 @@ _ANY_FN = (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda)
 def module_name_for(path: str, root: str = ".") -> str:
     """Dotted module name for a file path, relative to the project root
     (`raft_stereo_tpu/train/trainer.py` -> `raft_stereo_tpu.train.trainer`,
-    `bench.py` -> `bench`, a package `__init__.py` -> the package name)."""
+    `chip_smoke.py` -> `chip_smoke`, a package `__init__.py` -> the package name)."""
     rel = os.path.relpath(os.path.abspath(path), os.path.abspath(root))
     if rel.endswith(".py"):
         rel = rel[:-3]

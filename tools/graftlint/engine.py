@@ -409,7 +409,7 @@ class ModuleAnalysis:
         attached, bindings travel across module boundaries: a name imported
         from a module that bound it to a jit result, and `self.<attr>`
         bindings made by any project class (`trainer.train_step` is
-        recognized in bench.py, not just in trainer.py)."""
+        recognized in chip_smoke.py, not just in trainer.py)."""
         if isinstance(func, ast.Name):
             b = self.jit_bindings.get(func.id)
             if b is not None and not b.is_attr:
