@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from raft_stereo_tpu.config import SDARMoEConfig
 from raft_stereo_tpu.ops.block_attention import block_attention
 from raft_stereo_tpu.ops.data_axis import over_data_axis
-from raft_stereo_tpu.ops.grouped_matmul import group_layout, grouped_matmul
+from raft_stereo_tpu.ops.grouped_matmul import group_layout, grouped_matmul, swiglu_rows
 from raft_stereo_tpu.ops.tile_rows import fits, gather_rows, scatter_add_rows
 
 Array = jax.Array
@@ -257,8 +257,7 @@ class Experts(nn.Module):
             else:
                 row_token, row_slot = row_source // k, row_source % k
                 rows = _dispatch(m_c, row_token, row_live, slot_row, held)
-            gate_up = grouped_matmul(rows, w_gate_up, *groups).astype(jnp.float32)
-            hidden = (jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]).astype(m_c.dtype)
+            hidden = swiglu_rows(grouped_matmul(rows, w_gate_up, *groups), layout["num_tiles"], tile)
             out_rows = grouped_matmul(hidden, w_down, *groups)
             if live_copies:
                 y = _combine_live(out_rows, weights_c, source, slot_row, held, layout["num_tiles"], tile)

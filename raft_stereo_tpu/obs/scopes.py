@@ -25,9 +25,11 @@ Scopes the program writes (beside flax's module names `cnet`, `fnet`,
 `sdar-moe` family (models/sdar_moe.py) writes flax's module names `embed`,
 `layers/{input_norm,attention,post_attention_norm,router,experts}`, `norm`,
 `lm_head`, and the scopes `block_attention` (ops/block_attention.py, under
-`attention`), `grouped_matmul` (ops/grouped_matmul.py), `gather_rows` and
-`scatter_add_rows` (ops/tile_rows.py; all three under `experts`) and
-`block_diffusion_loss`; the two norms before a sublayer count with it.
+`attention`), `grouped_matmul` and `swiglu_rows` (ops/grouped_matmul.py; the
+activation's backward is the Pallas call `swiglu_rows_bwd` inside the scope
+`swiglu_rows`), `gather_rows` and `scatter_add_rows` (ops/tile_rows.py; all
+four under `experts`) and `block_diffusion_loss`; the two norms before a
+sublayer count with it.
 
 Pure: jax is touched only by `scoped` (at trace time) and `abstract`; nothing
 is lowered or parsed until `registered()` is called.

@@ -1,6 +1,7 @@
 """The product over the experts a chip holds: rows sorted by expert, group
 sizes known only at run time, as three Pallas kernels (forward, and the
-backward's two products).
+backward's two products), and the activation between two such products as
+two more (`swiglu_rows` and its backward).
 
 **Layout** (`group_layout`). An assignment is one (position, chosen expert)
 pair; its expert is a held one, `0..E-1`, or `E` for an expert that lives on
@@ -20,6 +21,12 @@ rhs[expert of r's tile]`. Its VJP is `d_lhs = grouped_matmul(d_out, rhs^T)`
 over e's tiles of lhs[t]^T @ d_out[t]` (`grouped_matmul_drhs`: an expert's
 tiles are consecutive, so its block stays in VMEM while they accumulate).
 Rows of dead tiles are never written: read them only under a mask.
+
+`swiglu_rows(gate_up, num_tiles)`: `out[r] = silu(gate_up[r, :F]) *
+gate_up[r, F:]` for the rows of the live tiles, widened to float32 in VMEM
+and rounded once; its VJP is one more call over the same tiles. The
+activation has no weights, so it reads `num_tiles` alone of the table. Rows
+of dead tiles are neither read nor written by either call.
 """
 
 from __future__ import annotations
@@ -217,3 +224,79 @@ def grouped_matmul_dense(lhs: Array, rhs: Array, tile_expert: Array, num_tiles: 
         mine = (live & (row_expert == e))[:, None]
         out = out + jnp.where(mine, jnp.dot(lhs, rhs[e], preferred_element_type=jnp.float32), 0.0)
     return out.astype(lhs.dtype)
+
+
+# -- the activation between two products ---------------------------------------
+
+
+def _swiglu_kernel(num_tiles_ref, gate_up_ref, out_ref):
+    @pl.when(pl.program_id(0) < num_tiles_ref[0])
+    def _():
+        f = out_ref.shape[1]
+        gate = gate_up_ref[:, :f].astype(jnp.float32)
+        up = gate_up_ref[:, f:].astype(jnp.float32)
+        out_ref[...] = (jax.nn.silu(gate) * up).astype(out_ref.dtype)
+
+
+def _swiglu_bwd_kernel(num_tiles_ref, d_out_ref, gate_up_ref, d_gate_up_ref):
+    @pl.when(pl.program_id(0) < num_tiles_ref[0])
+    def _():
+        f = d_out_ref.shape[1]
+        gate = gate_up_ref[:, :f].astype(jnp.float32)
+        up = gate_up_ref[:, f:].astype(jnp.float32)
+        d_out = d_out_ref[...].astype(jnp.float32)
+        sigmoid = jax.nn.sigmoid(gate)
+        d_gate = d_out * up * sigmoid * (1.0 + gate * (1.0 - sigmoid))
+        d_gate_up_ref[:, :f] = d_gate.astype(d_gate_up_ref.dtype)
+        d_gate_up_ref[:, f:] = (d_out * gate * sigmoid).astype(d_gate_up_ref.dtype)
+
+
+def _by_tile(kernel, name, operands, num_tiles, tile_m, width):
+    """`kernel` over the row tiles of `operands` (each (R, its own width)),
+    one tile a grid step, dead tiles skipped as `_gmm` skips them (eight
+    tiles a step saved 0.011 ms of a call's 0.086 at the token cell's chunk:
+    PERF.md section 5). -> (R, width)."""
+    rows, dtype = operands[0].shape[0], operands[0].dtype
+    spec = lambda n: pl.BlockSpec((tile_m, n), lambda t, nt: (_live(t, nt), 0), memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows // tile_m,),
+            in_specs=[spec(x.shape[1]) for x in operands],
+            out_specs=spec(width),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, width), dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=pallas_interpret(),
+        name=name,
+    )(num_tiles, *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _swiglu_rows(gate_up, num_tiles, tile_m):
+    return _by_tile(_swiglu_kernel, "swiglu_rows", (gate_up,), num_tiles, tile_m, gate_up.shape[1] // 2)
+
+
+def _swiglu_rows_fwd(gate_up, num_tiles, tile_m):
+    return _swiglu_rows(gate_up, num_tiles, tile_m), (gate_up, num_tiles)
+
+
+def _swiglu_rows_bwd(tile_m, residuals, d_out):
+    gate_up, num_tiles = residuals
+    d_gate_up = _by_tile(_swiglu_bwd_kernel, "swiglu_rows_bwd", (d_out, gate_up), num_tiles, tile_m, gate_up.shape[1])
+    return d_gate_up, None
+
+
+_swiglu_rows.defvjp(_swiglu_rows_fwd, _swiglu_rows_bwd)
+
+
+@scoped("swiglu_rows")
+def swiglu_rows(gate_up: Array, num_tiles: Array, tile_m: int) -> Array:
+    """gate_up: (R, 2F), R a multiple of `tile_m`, the gate's columns then the
+    up projection's; `num_tiles` (1,) from `group_layout`. -> `silu(gate) *
+    up`, (R, F) in gate_up's dtype, computed in float32; rows of dead tiles
+    undefined."""
+    if gate_up.shape[0] % tile_m or gate_up.shape[1] % 2:
+        raise ValueError(f"swiglu_rows: gate_up {gate_up.shape}, tiles of {tile_m}")
+    return _swiglu_rows(gate_up, num_tiles, tile_m)

@@ -160,3 +160,21 @@ def test_live_row_copies_compile_at_the_token_cells_chunk(v5e, copy):
     else:
         _assert_kernel_compiled(
             lambda b, s, n: tile_rows.scatter_add_rows(b, s, n, tile, positions, 8), buffer, source, num_tiles)
+
+
+@pytest.mark.parametrize("call", ["forward", "forward_and_backward"])
+def test_expert_activation_compiles_at_the_token_cells_chunk(v5e, call):
+    """`swiglu_rows` and its VJP at a chunk of `sdar-a3b-train-blockdiff-4k`:
+    the two products' (rows, 1536) and (rows, 768) bf16 buffers in tiles of
+    128, `num_tiles` in SMEM."""
+    from raft_stereo_tpu.ops import grouped_matmul
+
+    rows, tile = grouped_matmul.rows_bound(4096 * 8, 16, 128), 128
+    gate_up = jax.ShapeDtypeStruct((rows, 2 * 768), jnp.bfloat16, sharding=v5e)
+    num_tiles = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=v5e)
+    if call == "forward":
+        _assert_kernel_compiled(lambda g, n: grouped_matmul.swiglu_rows(g, n, tile), gate_up, num_tiles)
+        return
+    value = lambda g, n: jnp.sum(grouped_matmul.swiglu_rows(g, n, tile).astype(jnp.float32))
+    text = _assert_kernel_compiled(jax.value_and_grad(value), gate_up, num_tiles)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2  # the activation, its backward

@@ -300,6 +300,51 @@ def test_live_row_copies_match_the_gathers_they_replace_on_a_poisoned_buffer(rou
     assert all(bool(jnp.isfinite(x.astype(jnp.float32)).all()) for x in (y, d_m, d_weights))
 
 
+def _swiglu(gate_up):
+    """`silu(gate) * up` as the expert layer wrote it in `jax.numpy` before
+    the kernel: widened to float32 over every row, rounded once."""
+    f = gate_up.shape[1] // 2
+    wide = gate_up.astype(jnp.float32)
+    return (jax.nn.silu(wide[:, :f]) * wide[:, f:]).astype(gate_up.dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("routing", ["most_tiles_dead", "every_assignment_held", "no_assignment_held"])
+def test_swiglu_rows_and_its_backward_match_the_jax_numpy_form_on_a_poisoned_buffer(routing, dtype):
+    """The activation between the two products and its VJP, every dead tile
+    of both buffers holding NaN: a live tile's rows are the `jax.numpy` form's
+    to a unit in the last place of the dtype, and what a dead tile's rows
+    hold reaches no row of the result (nor is anything written there: the
+    result's dead rows are the same whatever poison the operands held)."""
+    expert = jnp.asarray(_copy_routing(routing, np.random.default_rng(18)), jnp.int32)
+    layout = gm.group_layout(expert.reshape(-1), COPY_E, TILE)
+    num_tiles, rows, f = layout["num_tiles"], layout["row_live"].shape[0], 32
+    live_tile = (jnp.arange(rows) < num_tiles[0] * TILE)[:, None]
+    assert 0 < int(num_tiles[0]) < rows // TILE
+    keys = jax.random.split(jax.random.PRNGKey(19), 2)
+    values = (2.0 * jax.random.normal(keys[0], (rows, 2 * f)), jax.random.normal(keys[1], (rows, f)))
+    poisoned = lambda poison: [jnp.where(live_tile, x, poison).astype(dtype) for x in values]
+    ulp = float(jnp.finfo(dtype).eps)
+
+    def near(got, want):
+        got, want = (np.asarray(jnp.where(live_tile, x, 0), np.float32) for x in (got, want))
+        assert np.isfinite(got).all()
+        np.testing.assert_array_less(np.abs(got - want), ulp * np.abs(want) + 1e-6)
+
+    results = []
+    for poison in (jnp.nan, 1.0):
+        gate_up, d_hidden = poisoned(poison)
+        hidden, backward = jax.vjp(lambda x: gm.swiglu_rows(x, num_tiles, TILE), gate_up)
+        (d_gate_up,) = backward(d_hidden)
+        clean = jnp.where(live_tile, gate_up, 0)
+        near(hidden, _swiglu(clean))
+        near(d_gate_up, jax.vjp(_swiglu, clean)[1](jnp.where(live_tile, d_hidden, 0))[0])
+        assert hidden.dtype == d_gate_up.dtype == jnp.dtype(dtype)
+        results.append((hidden, d_gate_up))
+    for under_nan, under_one in zip(*results):
+        np.testing.assert_array_equal(np.asarray(under_nan, np.float32), np.asarray(under_one, np.float32))
+
+
 def _expert_layer_values(config, devices):
     """value, (counts, live share) and the gradients to `m`, the experts'
     weights and the router's `weights`, from functions traced anew."""
@@ -357,10 +402,10 @@ def test_live_row_share_counts_the_live_tiles_of_a_hand_made_routing():
 # -- the trainer's rules for the family -----------------------------------------------
 
 
-def _tiny_train_config(tmp_path, **kwargs):
-    config, _ = _configs(4, 2, 1)
-    return TrainConfig(model=config, batch_size=2, num_steps=2, checkpoint_every=100, handle_signals=False,
-                       checkpoint_dir=str(tmp_path / "ckpt"), log_dir=str(tmp_path / "logs"), **kwargs)
+def _tiny_train_config(tmp_path, model=None, **kwargs):
+    return TrainConfig(model=model or _configs(4, 2, 1)[0], batch_size=2, num_steps=2, checkpoint_every=100,
+                       handle_signals=False, checkpoint_dir=str(tmp_path / "ckpt"), log_dir=str(tmp_path / "logs"),
+                       **kwargs)
 
 
 def test_token_batch_and_state_rules():
@@ -426,6 +471,27 @@ def test_token_steps_instructions_are_placed(tmp_path):
     assert not set(seen) & {"encoder", "lookup", "gru08"}  # no stereo row takes a token step's instruction
 
 
+def test_the_token_step_holds_no_float32_array_of_a_chunks_row_buffer(tmp_path):
+    """With bfloat16 compute, what lies between the two expert products stays
+    bfloat16 wherever it has a chunk's `rows_bound` rows: a float32 array of
+    (rows, 2f) or (rows, f) in the step's lowered text is an elementwise pass
+    over the worst case come back (on the chip: 214 MB a chunk through HBM,
+    seven eighths of it rows that hold nothing). The interpreted kernels'
+    bodies are in this text too, and widen one tile's block only."""
+    from raft_stereo_tpu.obs import scopes
+    from raft_stereo_tpu.train.trainer import Trainer
+
+    f = 48  # (rows, 96) and (rows, 48) are no other array's shape in the step
+    program = dict(expert_parallel=2, expert_shard=1, block_length=4, mask_token_id=95)
+    model = SDARMoEConfig.from_hf_config(
+        dict(PUBLISHED, num_experts=4, moe_intermediate_size=f), **program, **dict(TILES, mixed_precision=True))
+    trainer = Trainer(_tiny_train_config(tmp_path, model), sample_shape=(SEQ,))
+    text = trainer.train_step.lower(scopes.abstract(trainer.state), trainer._abstract_batch()).as_text()
+    rows = gm.rows_bound(model.moe_chunk * model.num_experts_per_tok, model.num_experts, model.moe_tile_rows)
+    assert f"tensor<{rows}x{2 * f}xbf16>" in text and f"tensor<{rows}x{f}xbf16>" in text  # the products' results
+    assert f"tensor<{rows}x{2 * f}xf32>" not in text and f"tensor<{rows}x{f}xf32>" not in text
+
+
 @pytest.mark.parametrize("path,want", [
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/embed/take", ("embed", "forward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/input_norm/mul", ("attention", "forward")),
@@ -437,6 +503,9 @@ def test_token_steps_instructions_are_placed(tmp_path):
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/experts/closed_call/while/body/closed_call/checkpoint/scatter_add_rows/scatter_add_rows/pallas_call", ("experts", "forward")),
     ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/layers/experts/while/body/closed_call/checkpoint/rematted_computation/gather_rows/gather_rows/pallas_call", ("experts", "recompute")),
     ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/layers/experts/while/body/closed_call/checkpoint/gather_rows/gather_rows/pallas_call", ("experts", "backward")),
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/experts/closed_call/while/body/closed_call/checkpoint/swiglu_rows/swiglu_rows/pallas_call", ("experts", "forward")),
+    ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/layers/experts/while/body/closed_call/checkpoint/rematted_computation/swiglu_rows/swiglu_rows/pallas_call", ("experts", "recompute")),
+    ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/layers/experts/while/body/closed_call/checkpoint/swiglu_rows/swiglu_rows_bwd/pallas_call", ("experts", "backward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/norm/mul", ("lm_head", "forward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/lm_head.loss_sum/while/body/checkpoint/dot_general", ("lm_head", "forward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/lm_head.loss_sum/while/body/checkpoint/block_diffusion_loss/reduce_max", ("loss", "forward")),
