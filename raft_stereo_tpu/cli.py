@@ -160,30 +160,37 @@ def _load_variables(restore_ckpt: Optional[str], config: RAFTStereoConfig):
 
 
 def _token_model_config(args):
-    """The `sdar-moe` family's model from a published `config.json`-shaped
-    file; a `program` group in it (expert_parallel, expert_shard,
-    block_length, ...; benchmark/configs/ has one) sets the chip's share and
-    the program's own keys."""
+    """A token family's model from a published `config.json`-shaped file,
+    the family picked by its `model_type` (`sdar_moe`, `granitemoehybrid`);
+    a `program` group in it (expert_parallel, expert_shard, block_length,
+    ...; benchmark/configs/ has them) sets the chip's share and the program's
+    own keys."""
     import json
 
-    from raft_stereo_tpu.config import SDARMoEConfig
+    from raft_stereo_tpu.config import TOKEN_FAMILIES
 
     with open(args.token_config) as f:
         published = json.load(f)
-    return SDARMoEConfig.from_hf_config(published, **published.get("program", {}))
+    family = TOKEN_FAMILIES.get(published.get("model_type"))
+    if family is None:
+        raise ValueError(
+            f"--token_config: model_type {published.get('model_type')!r} is none of {sorted(TOKEN_FAMILIES)}")
+    return family.from_hf_config(published, **published.get("program", {}))
 
 
 def _train_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(prog="train")
     p.add_argument("--name", default="raft-stereo")
     p.add_argument("--token_config", default=None,
-                   help="train the sdar-moe family (a routed-expert decoder "
-                   "under block diffusion) instead of RAFT-Stereo: path to a "
-                   "config.json-shaped file, optionally with a `program` "
+                   help="train a token family instead of RAFT-Stereo, picked "
+                   "by the file's model_type: sdar_moe (a routed-expert "
+                   "decoder under block diffusion) or granitemoehybrid (a "
+                   "Mamba-2 / attention hybrid on the next-token loss): path "
+                   "to a config.json-shaped file, optionally with a `program` "
                    "group; batches come from a seeded Zipf source "
                    "(data/tokens.py)")
     p.add_argument("--seq_len", type=int, default=4096,
-                   help="tokens a sample (token family only)")
+                   help="tokens a sample (token families only)")
     p.add_argument("--restore_ckpt", default=None)
     p.add_argument("--auto_resume", action="store_true",
                    help="at startup, restore the newest checkpoint of this "
@@ -557,15 +564,17 @@ def _run_train(args, config: TrainConfig) -> int:
 
 
 def _token_trainer(args, config: TrainConfig):
-    """The token family's trainer and its loader: the same `Trainer`, a
-    seeded token source (data/tokens.py), no validation set."""
+    """A token family's trainer and its loader: the same `Trainer`, a seeded
+    token source (data/tokens.py), no validation set."""
     from raft_stereo_tpu.data.tokens import TokenBatches
     from raft_stereo_tpu.train.trainer import Trainer
 
     model = config.model
-    # The mask token's row is never data.
-    rows = model.mask_token_id if model.mask_token_id == model.vocab_size - 1 else model.vocab_size
-    loader = TokenBatches(config.batch_size, args.seq_len, model.block_length, rows, seed=config.seed)
+    block_length = getattr(model, "block_length", 0)  # 0: ids alone, no noise (the causal families)
+    rows = model.vocab_size
+    if block_length and model.mask_token_id == model.vocab_size - 1:
+        rows = model.mask_token_id  # the mask token's row is never data
+    loader = TokenBatches(config.batch_size, args.seq_len, block_length, rows, seed=config.seed)
     return Trainer(config, sample_shape=(args.seq_len,)), loader
 
 

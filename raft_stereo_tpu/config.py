@@ -264,7 +264,92 @@ class SDARMoEConfig:
         return cls(**merged)
 
 
-ModelConfig = Union[RAFTStereoConfig, SDARMoEConfig]
+@dataclasses.dataclass(frozen=True)
+class GraniteHybridConfig:
+    """The third model family, `granite-hybrid` (`model_type:
+    granitemoehybrid` with no routed experts: IBM Granite 4.0-H): Mamba-2
+    layers and grouped-query attention layers in the order `layer_types`
+    gives, each followed by a dense gated MLP, trained on the plain
+    next-token loss. Key names are the published `config.json`'s;
+    `from_hf_config` reads such a file. Defaults are the 4.0-H Micro release.
+
+    `vocab_size` may be the vocabulary rows HELD here (ids, the tied head,
+    logits and loss are over that slice) and `layer_types` the layers of one
+    pipeline stage; the loss is then taken at the stage's output."""
+
+    vocab_size: int = 100352
+    hidden_size: int = 2048
+    layer_types: Tuple[str, ...] = tuple(
+        "attention" if i % 10 == 5 else "mamba" for i in range(40))
+    intermediate_size: int = 8192  # the gated MLP's (`shared_intermediate_size`)
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    mamba_n_heads: int = 64
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    rms_norm_eps: float = 1e-5
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    attention_multiplier: float = 0.015625
+    logits_scaling: float = 8.0
+    # -- program --
+    mixed_precision: bool = True  # bf16 compute, float32 parameters
+    remat_layers: bool = True
+    attention_tile: int = 512
+    # Positions a pass of the output head and loss holds logits for.
+    loss_chunk: int = 4096
+
+    @property
+    def num_hidden_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        unknown = set(self.layer_types) - {"mamba", "attention"}
+        if unknown or not self.layer_types:
+            raise ValueError(f"layer_types holds {sorted(unknown) or 'nothing'}; a layer is 'mamba' or 'attention'")
+        if self.num_attention_heads % self.num_key_value_heads or self.hidden_size % self.num_attention_heads:
+            raise ValueError("hidden_size / num_attention_heads / num_key_value_heads do not divide")
+        if self.mamba_d_inner != self.mamba_expand * self.hidden_size:
+            raise ValueError("mamba_n_heads * mamba_d_head must be mamba_expand * hidden_size")
+        if self.mamba_n_groups != 1:
+            raise NotImplementedError("the scan shares B and C among all heads (mamba_n_groups 1)")
+
+    @classmethod
+    def from_hf_config(cls, published: Dict[str, Any], **program) -> "GraniteHybridConfig":
+        """From a `config.json`-shaped dict (keys this class does not model
+        are ignored; routed experts are refused) and the program's own keys."""
+        if published.get("num_local_experts", 0) or published.get("num_experts_per_tok", 0):
+            raise NotImplementedError("granite-hybrid: routed experts (num_local_experts > 0) are not modelled")
+        if published.get("position_embedding_type", "nope") != "nope":
+            raise NotImplementedError("granite-hybrid: attention carries no positional embedding (nope) only")
+        names = {f.name for f in dataclasses.fields(cls)}
+        merged = {k: v for k, v in {**published, **program}.items() if k in names}
+        if "shared_intermediate_size" in published:
+            merged["intermediate_size"] = published["shared_intermediate_size"]
+        if "layer_types" in merged and "num_hidden_layers" in published:
+            if len(merged["layer_types"]) != published["num_hidden_layers"]:
+                raise ValueError("layer_types does not name num_hidden_layers layers")
+        return cls(**merged)
+
+
+# `model_type` of a published config.json -> the family's config class
+# (`cli --token_config` picks the family by it).
+TOKEN_FAMILIES = {"sdar_moe": SDARMoEConfig, "granitemoehybrid": GraniteHybridConfig}
+
+ModelConfig = Union[RAFTStereoConfig, SDARMoEConfig, GraniteHybridConfig]
 
 
 @dataclasses.dataclass(frozen=True)

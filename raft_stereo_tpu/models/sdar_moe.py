@@ -315,22 +315,34 @@ class LMHead(nn.Module):
     def loss_sum(self, h: Array, targets: Array, weights: Array) -> Array:
         """h: (N, D); targets (N,) int32; weights (N,) float32 -> sum of
         weights * (-log softmax(logits)[target])."""
-        n = h.shape[0]
-        chunk = self.config.loss_chunk if n % self.config.loss_chunk == 0 else n
-        w_head = self.w_head.astype(h.dtype)
+        return chunked_loss_sum(h, self.w_head, targets, weights, self.config.loss_chunk, "block_diffusion_loss")
 
-        @functools.partial(jax.checkpoint, prevent_cse=False)
-        def one_chunk(total, xs):
-            h_c, target_c, weight_c = xs
-            logits = jnp.dot(h_c, w_head, preferred_element_type=jnp.float32)
-            with jax.named_scope("block_diffusion_loss"):
-                log_z = jax.nn.logsumexp(logits, axis=-1)
-                picked = jnp.take_along_axis(logits, target_c[:, None], axis=-1)[:, 0]
-                return total + jnp.sum(weight_c * (log_z - picked)), None
 
-        shape = lambda x: x.reshape(n // chunk, chunk, *x.shape[1:])
-        total, _ = jax.lax.scan(one_chunk, jnp.zeros((), jnp.float32), (shape(h), shape(targets), shape(weights)))
-        return total
+def chunked_loss_sum(h: Array, w_head: Array, targets: Array, weights: Array, loss_chunk: int, scope: str,
+                     logit_scale: float = 1.0) -> Array:
+    """sum of weights * (-log softmax(logit_scale * h w_head)[target]) over N
+    positions, holding the logits of `loss_chunk` of them at a time (each
+    chunk's are rebuilt in the backward pass). h: (N, D); w_head: (D, V)
+    float32; targets (N,) int32; weights (N,) float32. The softmax's part is
+    traced under the named scope `scope`."""
+    n = h.shape[0]
+    chunk = loss_chunk if n % loss_chunk == 0 else n
+    w_head = w_head.astype(h.dtype)
+
+    @functools.partial(jax.checkpoint, prevent_cse=False)
+    def chunk_loss(total, xs):
+        h_c, target_c, weight_c = xs
+        logits = jnp.dot(h_c, w_head, preferred_element_type=jnp.float32)
+        if logit_scale != 1.0:
+            logits = logits * logit_scale
+        with jax.named_scope(scope):
+            log_z = jax.nn.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, target_c[:, None], axis=-1)[:, 0]
+            return total + jnp.sum(weight_c * (log_z - picked)), None
+
+    shape = lambda x: x.reshape(n // chunk, chunk, *x.shape[1:])
+    total, _ = jax.lax.scan(chunk_loss, jnp.zeros((), jnp.float32), (shape(h), shape(targets), shape(weights)))
+    return total
 
 
 class SDARDecoder(nn.Module):

@@ -29,7 +29,15 @@ Scopes the program writes (beside flax's module names `cnet`, `fnet`,
 activation's backward is the Pallas call `swiglu_rows_bwd` inside the scope
 `swiglu_rows`), `gather_rows` and `scatter_add_rows` (ops/tile_rows.py; all
 four under `experts`) and `block_diffusion_loss`; the two norms before a
-sublayer count with it.
+sublayer count with it. The `granite-hybrid` family
+(models/granite_hybrid.py) writes flax's module names `embed`,
+`layers_<i>/{ssm_norm,mixer,input_norm,attention,mlp_norm,mlp}`, `norm`, and
+inside `mixer` the scopes `ssm_proj` (both projections; the layer's norm and
+residual count with it), `ssm_conv` (the causal convolution, `silu`, the
+split and `dt`'s softplus), `ssm_scan` (ops/ssd_scan.py: the kernels
+`ssd_chunk` / `ssd_chunk_bwd`, the running sums, the recurrence over the
+chunks) and `ssm_gate_norm`; `block_attention` under `attention`; `lm_head`
+and `next_token_loss`.
 
 Pure: jax is touched only by `scoped` (at trace time) and `abstract`; nothing
 is lowered or parsed until `registered()` is called.
@@ -52,6 +60,8 @@ COMPONENTS = (
     "gru32", "flow_head", "interp_pool", "mask_head", "upsample",
     # the `sdar-moe` family's
     "embed", "attention", "router", "experts", "lm_head",
+    # the `granite-hybrid` family's, beside `embed`, `attention`, `lm_head`
+    "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm", "mlp",
     "loss", "optimizer", "collective", "other", "unscoped",
 )
 PHASES = ("forward", "backward", "recompute")
@@ -76,8 +86,13 @@ _ROWS = tuple(
         (r"attention|input_norm", "attention"),
         (r"router|post_attention_norm", "router"),
         (r"experts", "experts"),
+        (r"ssm_proj|ssm_norm", "ssm_proj"),
+        (r"ssm_conv", "ssm_conv"),
+        (r"ssm_scan", "ssm_scan"),
+        (r"ssm_gate_norm|gate_norm", "ssm_gate_norm"),
+        (r"mlp|mlp_norm", "mlp"),
         (r"lm_head(?:\.\w+)?|norm", "lm_head"),  # flax names a method other than __call__ `module.method`
-        (r"sequence_loss|block_diffusion_loss", "loss"),
+        (r"sequence_loss|block_diffusion_loss|next_token_loss", "loss"),
         (r"grad_clip|optimizer", "optimizer"),
     )
 )

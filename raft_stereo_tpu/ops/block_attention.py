@@ -25,13 +25,20 @@ dq index k/v by `h // G`, dk/dv sum their group inside the kernel. Scores and
 softmax are float32; the matrix products take the inputs' dtype (bf16 in the
 train step) and accumulate in float32. On a multi-device mesh each device
 runs the kernels on its own rows of the batch (ops/data_axis.py).
+
+`causal_attention` runs the same three kernels under the plain causal mask
+of a row of L positions: the codes are `q_lim = pos`, `q_eq = -1`,
+`k_code = pos`, a query tile walks the key tiles up to its own and a key tile
+the query tiles from its own on, and the caller gives the scale of the scores
+(a model may fix a multiplier other than 1/sqrt(d)). Which tiles a tile sees
+is the one thing the two entries do not share: `_BlockWalk` / `_CausalWalk`.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,16 +75,24 @@ def block_mask(seq_len: int, block_length: int) -> Array:
     return _visible(q_lim[:, None], q_eq[:, None], k_code[None, :])
 
 
+def _dense(q: Array, k: Array, v: Array, mask: Array, scale: float) -> Array:
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
+    probs = jax.nn.softmax(jnp.where(mask, scores, _NEG), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v).astype(q.dtype)
+
+
 def block_attention_dense(q: Array, k: Array, v: Array, seq_len: int, block_length: int) -> Array:
     """The same attention with a materialised mask: what the kernels are
     tested against."""
-    group = q.shape[1] // k.shape[1]
-    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
-    scores = scores / math.sqrt(q.shape[-1])
-    scores = jnp.where(block_mask(seq_len, block_length), scores, _NEG)
-    probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v).astype(q.dtype)
+    return _dense(q, k, v, block_mask(seq_len, block_length), 1.0 / math.sqrt(q.shape[-1]))
+
+
+def causal_attention_dense(q: Array, k: Array, v: Array, scale: float) -> Array:
+    """`causal_attention` with a materialised mask: what the causal entry is
+    tested against."""
+    return _dense(q, k, v, jnp.tril(jnp.ones((q.shape[2], q.shape[2]), bool)), scale)
 
 
 # -- which tiles a tile sees ----------------------------------------------------
@@ -112,6 +127,85 @@ def _bwd_query_tile(kt, u, nh):
     return jnp.where(kt < nh, kt, clean_key)
 
 
+class _BlockWalk(NamedTuple):
+    """The block-diffusion layout in tiles: `nh` noised tiles, then `nh`
+    clean ones. `tiles`: tiles of a row; `fwd_max` / `bwd_max`: the longest
+    walk of a query tile / of a key tile (the grid's last axis)."""
+
+    nh: int
+
+    @property
+    def tiles(self):
+        return 2 * self.nh
+
+    @property
+    def fwd_max(self):
+        return self.nh + 1
+
+    @property
+    def bwd_max(self):
+        return 2 * self.nh
+
+    def fwd_steps(self, qt):
+        return _fwd_steps(qt, self.nh)[1]
+
+    def fwd_key_tile(self, qt, s):
+        return _fwd_key_tile(qt, s, self.nh)
+
+    def bwd_steps(self, kt):
+        return _bwd_steps(kt, self.nh)[1]
+
+    def bwd_query_tile(self, kt, u):
+        return _bwd_query_tile(kt, u, self.nh)
+
+
+class _CausalWalk(NamedTuple):
+    """A causal row of `nt` tiles: query tile qt sees key tiles 0..qt, key
+    tile kt is seen by query tiles kt..nt-1."""
+
+    nt: int
+
+    @property
+    def tiles(self):
+        return self.nt
+
+    fwd_max = bwd_max = tiles
+
+    def fwd_steps(self, qt):
+        return qt + 1
+
+    def fwd_key_tile(self, qt, s):
+        return jnp.minimum(s, qt)
+
+    def bwd_steps(self, kt):
+        return self.nt - kt
+
+    def bwd_query_tile(self, kt, u):
+        return kt + jnp.minimum(u, self.nt - kt - 1)
+
+
+class _Mask(NamedTuple):
+    """What the calls are traced for: `block_length` 0 is the causal mask of
+    `seq_len` positions, otherwise the block-diffusion mask of 2 x `seq_len`."""
+
+    seq_len: int
+    block_length: int
+
+    @property
+    def positions(self):
+        return 2 * self.seq_len if self.block_length else self.seq_len
+
+    def codes(self):
+        if self.block_length:
+            return mask_codes(self.seq_len, self.block_length)
+        pos = jnp.arange(self.seq_len, dtype=jnp.int32)
+        return pos, jnp.full_like(pos, -1), pos
+
+    def walk(self, tile: int):
+        t = _tile(self.seq_len, self.block_length or 1, tile)
+        return t, (_BlockWalk if self.block_length else _CausalWalk)(self.seq_len // t)
+
+
 def _scores(a, b, q_lim, q_eq, k_code, scale):
     """Masked scores a @ b.T (queries down and keys across, or the other way
     round: the codes broadcast to whichever it is) and the mask."""
@@ -133,7 +227,7 @@ def _column(row):
 # -- kernels ----------------------------------------------------------------------
 
 
-def _fwd_kernel(ql_ref, qe_ref, kc_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, nh, scale):
+def _fwd_kernel(ql_ref, qe_ref, kc_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, walk, scale):
     qt, s = pl.program_id(2), pl.program_id(3)
 
     @pl.when(s == 0)
@@ -142,7 +236,7 @@ def _fwd_kernel(ql_ref, qe_ref, kc_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(s < _fwd_steps(qt, nh)[1])
+    @pl.when(s < walk.fwd_steps(qt))
     def _():
         v = v_ref[0, 0]
         sc, mask = _scores(q_ref[0, 0], k_ref[0, 0], ql_ref[...], qe_ref[...], kc_ref[...], scale)
@@ -163,7 +257,7 @@ def _fwd_kernel(ql_ref, qe_ref, kc_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s
 
 def _dq_kernel(
     ql_ref, qe_ref, kc_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_scr, lse_scr, delta_scr,
-    *, nh, scale,
+    *, walk, scale,
 ):
     qt, s = pl.program_id(2), pl.program_id(3)
 
@@ -173,7 +267,7 @@ def _dq_kernel(
         lse_scr[...] = _column(lse_ref[0, 0])
         delta_scr[...] = _column(delta_ref[0, 0])
 
-    @pl.when(s < _fwd_steps(qt, nh)[1])
+    @pl.when(s < walk.fwd_steps(qt))
     def _():
         k, v, do = k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         sc, mask = _scores(q_ref[0, 0], k, ql_ref[...], qe_ref[...], kc_ref[...], scale)
@@ -189,7 +283,7 @@ def _dq_kernel(
 
 def _dkv_kernel(
     ql_ref, qe_ref, kc_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-    *, nh, scale,
+    *, walk, scale,
 ):
     kt, step = pl.program_id(2), pl.program_id(3)
 
@@ -198,7 +292,7 @@ def _dkv_kernel(
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(step % (2 * nh) < _bwd_steps(kt, nh)[1])
+    @pl.when(step % walk.bwd_max < walk.bwd_steps(kt))
     def _():
         # Keys down, queries across: a query's statistics are rows, and both
         # accumulations are plain products.
@@ -235,7 +329,7 @@ def _vmem(shape, index_map):
 
 def _code_specs(tile, q_tile_of, k_tile_of, keys_down=False):
     """Block specs of (q_lim, q_eq, k_code) by the tile functions of the
-    grid: the queries' codes as (2L, 1) columns and the keys' as a (1, 2L)
+    grid: the queries' codes as (S, 1) columns and the keys' as a (1, S)
     row, or with `keys_down` the other way round."""
     column = lambda tile_of: _vmem((tile, 1), lambda *g: (tile_of(*g), 0))
     row = lambda tile_of: _vmem((1, tile), lambda *g: (0, tile_of(*g)))
@@ -243,8 +337,8 @@ def _code_specs(tile, q_tile_of, k_tile_of, keys_down=False):
     return [q_spec(q_tile_of), q_spec(q_tile_of), k_spec(k_tile_of)]
 
 
-def _codes(seq_len, block_length, keys_down=False):
-    q_lim, q_eq, k_code = mask_codes(seq_len, block_length)
+def _codes(mask, keys_down=False):
+    q_lim, q_eq, k_code = mask.codes()
     down, across = (lambda x: x[:, None]), (lambda x: x[None, :])
     queries, keys = (across, down) if keys_down else (down, across)
     return queries(q_lim), queries(q_eq), keys(k_code)
@@ -253,48 +347,45 @@ def _codes(seq_len, block_length, keys_down=False):
 _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
-def _query_major_specs(t, hd, nh, group):
+def _query_major_specs(t, hd, walk, group):
     """Block specs of the grid (batch, query head, query tile, step) the
     forward and dq share: the three codes, a query-tile block, a key-tile
     block of the head's key-value head, a query tile's statistics row."""
     q_tile = lambda b_, h, qt, s: qt
-    k_tile = lambda b_, h, qt, s: _fwd_key_tile(qt, s, nh)
+    k_tile = lambda b_, h, qt, s: walk.fwd_key_tile(qt, s)
     head = lambda tile_of, per: _vmem((1, 1, t, hd), lambda b_, h, qt, s: (b_, h // per, tile_of(b_, h, qt, s), 0))
     stat = _vmem((1, 1, 1, t), lambda b_, h, qt, s: (b_, h, 0, qt))
     return _code_specs(t, q_tile, k_tile), head(q_tile, 1), head(k_tile, group), stat
 
 
-def _forward(q, k, v, seq_len, block_length, tile):
-    b, hq, s2, hd = q.shape
-    t = _tile(seq_len, block_length, tile)
-    nh = seq_len // t
-    codes, by_q, by_k, stat = _query_major_specs(t, hd, nh, hq // k.shape[1])
+def _forward(q, k, v, mask, scale, tile):
+    b, hq, positions, hd = q.shape
+    t, walk = mask.walk(tile)
+    codes, by_q, by_k, stat = _query_major_specs(t, hd, walk, hq // k.shape[1])
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, nh=nh, scale=1.0 / math.sqrt(hd)),
-        grid=(b, hq, 2 * nh, nh + 1),
+        functools.partial(_fwd_kernel, walk=walk, scale=scale),
+        grid=(b, hq, walk.tiles, walk.fwd_max),
         in_specs=codes + [by_q, by_k, by_k],
         out_specs=[by_q, stat],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct((b, hq, 1, s2), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct((b, hq, 1, positions), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((t, 1), jnp.float32), pltpu.VMEM((t, 1), jnp.float32), pltpu.VMEM((t, hd), jnp.float32)],
         compiler_params=_PARAMS,
         interpret=pallas_interpret(),
         name="block_attention",
-    )(*_codes(seq_len, block_length), q, k, v)
+    )(*_codes(mask), q, k, v)
 
 
-def _backward(q, k, v, o, lse, do, seq_len, block_length, tile):
-    b, hq, s2, hd = q.shape
+def _backward(q, k, v, o, lse, do, mask, scale, tile):
+    b, hq, _, hd = q.shape
     hkv = k.shape[1]
     group = hq // hkv
-    t = _tile(seq_len, block_length, tile)
-    nh = seq_len // t
-    scale = 1.0 / math.sqrt(hd)
+    t, walk = mask.walk(tile)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[:, :, None, :]
 
-    codes, by_q, by_k, stat = _query_major_specs(t, hd, nh, group)
+    codes, by_q, by_k, stat = _query_major_specs(t, hd, walk, group)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, nh=nh, scale=scale),
-        grid=(b, hq, 2 * nh, nh + 1),
+        functools.partial(_dq_kernel, walk=walk, scale=scale),
+        grid=(b, hq, walk.tiles, walk.fwd_max),
         in_specs=codes + [by_q, by_k, by_k, by_q, stat, stat],
         out_specs=by_q,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -302,19 +393,19 @@ def _backward(q, k, v, o, lse, do, seq_len, block_length, tile):
         compiler_params=_PARAMS,
         interpret=pallas_interpret(),
         name="block_attention_dq",
-    )(*_codes(seq_len, block_length), q, k, v, do, lse, delta)
+    )(*_codes(mask), q, k, v, do, lse, delta)
 
     # dk/dv: one key tile of one key-value head at a time; the last axis
     # walks the group's query heads, and under each the visible query tiles.
-    q_of = lambda b_, hk, kt, step: _bwd_query_tile(kt, step % (2 * nh), nh)
+    q_of = lambda b_, hk, kt, step: walk.bwd_query_tile(kt, step % walk.bwd_max)
     k_of = lambda b_, hk, kt, step: kt
-    q_head = lambda b_, hk, kt, step: hk * group + step // (2 * nh)
+    q_head = lambda b_, hk, kt, step: hk * group + step // walk.bwd_max
     by_q = _vmem((1, 1, t, hd), lambda b_, hk, kt, step: (b_, q_head(b_, hk, kt, step), q_of(b_, hk, kt, step), 0))
     q_stat = _vmem((1, 1, 1, t), lambda b_, hk, kt, step: (b_, q_head(b_, hk, kt, step), 0, q_of(b_, hk, kt, step)))
     by_k = _vmem((1, 1, t, hd), lambda b_, hk, kt, step: (b_, hk, kt, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, nh=nh, scale=scale),
-        grid=(b, hkv, 2 * nh, group * 2 * nh),
+        functools.partial(_dkv_kernel, walk=walk, scale=scale),
+        grid=(b, hkv, walk.tiles, group * walk.bwd_max),
         in_specs=_code_specs(t, q_of, k_of, keys_down=True) + [by_q, by_k, by_k, by_q, q_stat, q_stat],
         out_specs=[by_k, by_k],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
@@ -322,31 +413,43 @@ def _backward(q, k, v, o, lse, do, seq_len, block_length, tile):
         compiler_params=_PARAMS,
         interpret=pallas_interpret(),
         name="block_attention_dkv",
-    )(*_codes(seq_len, block_length, keys_down=True), q, k, v, do, lse, delta)
+    )(*_codes(mask, keys_down=True), q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _attention(q, k, v, seq_len, block_length, tile):
-    return _forward(q, k, v, seq_len, block_length, tile)[0]
+def _attention(q, k, v, mask, scale, tile):
+    return _forward(q, k, v, mask, scale, tile)[0]
 
 
-def _attention_fwd(q, k, v, seq_len, block_length, tile):
-    o, lse = _forward(q, k, v, seq_len, block_length, tile)
+def _attention_fwd(q, k, v, mask, scale, tile):
+    o, lse = _forward(q, k, v, mask, scale, tile)
     return o, (q, k, v, o, lse)
 
 
-def _attention_bwd(seq_len, block_length, tile, residuals, do):
-    return _backward(*residuals, do, seq_len, block_length, tile)
+def _attention_bwd(mask, scale, tile, residuals, do):
+    return _backward(*residuals, do, mask, scale, tile)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+def _over_rows(q, k, v, mask, scale, tile):
+    if q.shape[2] != mask.positions or k.shape[2] != mask.positions or q.shape[1] % k.shape[1]:
+        raise ValueError(f"attention: q {q.shape} / k {k.shape} do not fit {mask.positions} positions")
+    return over_data_axis(lambda q, k, v: _attention(q, k, v, mask, scale, tile), (q, k, v))
 
 
 @scoped("block_attention")
 def block_attention(q: Array, k: Array, v: Array, seq_len: int, block_length: int, tile: int = 512) -> Array:
     """q: (B, Hq, 2L, d); k, v: (B, Hkv, 2L, d), Hq a multiple of Hkv;
     scores are scaled by 1/sqrt(d). Returns (B, Hq, 2L, d) in q's dtype."""
-    if q.shape[2] != 2 * seq_len or k.shape[2] != 2 * seq_len or q.shape[1] % k.shape[1]:
-        raise ValueError(f"block_attention: q {q.shape} / k {k.shape} do not fit 2 x {seq_len} positions")
-    return over_data_axis(lambda q, k, v: _attention(q, k, v, seq_len, block_length, tile), (q, k, v))
+    return _over_rows(q, k, v, _Mask(seq_len, block_length), 1.0 / math.sqrt(q.shape[-1]), tile)
+
+
+@scoped("block_attention")
+def causal_attention(q: Array, k: Array, v: Array, scale: float, tile: int = 512) -> Array:
+    """q: (B, Hq, L, d); k, v: (B, Hkv, L, d), Hq a multiple of Hkv; scores
+    are scaled by `scale`; position i sees positions 0..i. Returns
+    (B, Hq, L, d) in q's dtype."""
+    return _over_rows(q, k, v, _Mask(q.shape[2], 0), float(scale), tile)

@@ -62,11 +62,12 @@ _PAD = 8  # rows after the table's: the zero row a padding row reads, the row it
 
 def fits(positions: int, width: int, dtype, rows: int, tile_m: int, per_position: int) -> bool:
     """Whether both kernels take a table of `positions` x `width` and a
-    buffer of `rows`: from shapes alone."""
+    buffer of `rows`: from shapes alone (called under a trace with static
+    shapes and dtypes only, which the lint's taint of parameters cannot see)."""
     dtype = jnp.dtype(dtype)
-    if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+    if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):  # graftlint: disable=GL002
         return False
-    if tile_m % _UNROLL or rows % tile_m or width % 2:
+    if tile_m % _UNROLL or rows % tile_m or width % 2:  # graftlint: disable=GL002
         return False
     size = dtype.itemsize
     block = _step_tiles(rows // tile_m) * tile_m

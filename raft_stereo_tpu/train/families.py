@@ -5,7 +5,7 @@ run report) is shared as it is.
 
 `family_of(model_config, sample_shape)` picks the family by the type of
 `TrainConfig.model`; `sample_shape` is the shape of ONE sample: (H, W, C) of
-an image for the stereo family, (L,) tokens for the token family.
+an image for the stereo family, (L,) tokens for the two token families.
 """
 
 from __future__ import annotations
@@ -15,7 +15,7 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from raft_stereo_tpu.config import RAFTStereoConfig, SDARMoEConfig, TrainConfig
+from raft_stereo_tpu.config import GraniteHybridConfig, RAFTStereoConfig, SDARMoEConfig, TrainConfig
 
 Batch = Dict[str, jax.Array]
 # (params, batch_stats, batch) -> (loss, metrics)
@@ -78,6 +78,26 @@ class SDARMoEFamily:
         return {"sample": [self.seq_len], "expert_parallel": config.model.expert_parallel}
 
 
+class GraniteHybridFamily:
+    """The Mamba-2 / attention hybrid on the plain causal loss: `tokens`
+    (B, L) int32 in, the mean next-token cross-entropy out
+    (models/granite_hybrid.py)."""
+
+    def __init__(self, sample_shape: Tuple[int, ...]):
+        (self.seq_len,) = tuple(sample_shape)
+
+    def init_variables(self, config: TrainConfig, rng: jax.Array):
+        from raft_stereo_tpu.models.granite_hybrid import init_granite_variables
+
+        return init_granite_variables(config.model, rng, self.seq_len)
+
+    def batch_shapes(self, batch_size: int) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+        return {"tokens": ((batch_size, self.seq_len), jnp.int32)}
+
+    def audit_meta(self, config: TrainConfig) -> Dict[str, Any]:
+        return {"sample": [self.seq_len], "layer_types": list(config.model.layer_types)}
+
+
 def _raft_stereo_loss(config: TrainConfig) -> LossFn:
     from raft_stereo_tpu.models import RAFTStereo
     from raft_stereo_tpu.train.loss import sequence_loss
@@ -111,6 +131,17 @@ def _sdar_moe_loss(config: TrainConfig) -> LossFn:
     return loss
 
 
+def _granite_hybrid_loss(config: TrainConfig) -> LossFn:
+    from raft_stereo_tpu.models.granite_hybrid import GraniteHybrid
+
+    model = GraniteHybrid(config.model)
+
+    def loss(params, batch_stats, batch):
+        return model.apply({"params": params}, batch["tokens"], method="loss")
+
+    return loss
+
+
 def make_loss(config: TrainConfig) -> LossFn:
     """The loss of a batch for the family of `config.model`; needs no sample
     shape."""
@@ -118,6 +149,8 @@ def make_loss(config: TrainConfig) -> LossFn:
         return _raft_stereo_loss(config)
     if isinstance(config.model, SDARMoEConfig):
         return _sdar_moe_loss(config)
+    if isinstance(config.model, GraniteHybridConfig):
+        return _granite_hybrid_loss(config)
     raise TypeError(f"no model family for a {type(config.model).__name__}")
 
 
@@ -126,4 +159,6 @@ def family_of(model_config, sample_shape: Tuple[int, ...]):
         return RaftStereoFamily(sample_shape)
     if isinstance(model_config, SDARMoEConfig):
         return SDARMoEFamily(sample_shape, model_config.block_length)
+    if isinstance(model_config, GraniteHybridConfig):
+        return GraniteHybridFamily(sample_shape)
     raise TypeError(f"no model family for a {type(model_config).__name__}")

@@ -16,6 +16,10 @@ Programs, at the sizes `chip_smoke.py` drives:
   train-tokens-dp   the same step on a (4, 1) data mesh, global batch 8 (the
             kernels shard_mapped over the data axis); not in the default set
 
+  train-lm[-L]      the hybrid family's step as the benchmark's cell runs it
+            (benchmark/configs/granite-4.0-h-micro-pp4-stage.json, batch 1 x
+            8192 tokens, or L tokens); not in the default set
+
   JAX_PLATFORMS=cpu python scripts/rehearse_chip_compile.py [names...]
 
 The kernel-level compiles (about two seconds each) are tests:
@@ -121,6 +125,17 @@ def _token_model(layers=None):
     return dataclasses.replace(model, num_hidden_layers=layers) if layers else model
 
 
+def _hybrid_model():
+    import json
+
+    from raft_stereo_tpu.config import GraniteHybridConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "granite-4.0-h-micro-pp4-stage.json")) as f:
+        published = json.load(f)
+    return GraniteHybridConfig.from_hf_config(published, **published["program"])
+
+
 def _train(name, devices, mesh_shape, batch, model=MODEL, sample=(320, 720, 3)):
     """The Trainer's own step, shardings and trace scope (train/trainer.py
     __init__), on a mesh of described devices instead of jax.devices()."""
@@ -187,13 +202,18 @@ def main(names):
             _train("train-dp (4,1) b8 320x720x22", topo.devices, (4, 1), 8)
         elif name == "train-tokens-dp":
             _train("train-tokens-dp (4,1) b8 x 4096", topo.devices, (4, 1), 8, _token_model(), (4096,))
+        elif name.startswith("train-lm"):
+            seq_len = int(name.split("-")[2]) if name.count("-") == 2 else 8192
+            model = _hybrid_model()
+            _train(f"train-lm b1 x {seq_len}, {model.num_hidden_layers} layers", topo.devices[:1], (1, 1), 1,
+                   model, (seq_len,))
         elif name.startswith("train-tokens"):
             layers = int(name.split("-")[2]) if name.count("-") == 2 else None
             model = _token_model(layers)
             _train(f"train-tokens b4 x 4096, {model.num_hidden_layers} layers", topo.devices[:1], (1, 1), 4,
                    model, (4096,))
         else:
-            raise SystemExit(f"unknown program {name!r}; choose from {PROGRAMS} or train-tokens[-N]")
+            raise SystemExit(f"unknown program {name!r}; choose from {PROGRAMS}, train-tokens[-N] or train-lm[-L]")
 
 
 if __name__ == "__main__":

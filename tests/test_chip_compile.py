@@ -178,3 +178,38 @@ def test_expert_activation_compiles_at_the_token_cells_chunk(v5e, call):
     value = lambda g, n: jnp.sum(grouped_matmul.swiglu_rows(g, n, tile).astype(jnp.float32))
     text = _assert_kernel_compiled(jax.value_and_grad(value), gate_up, num_tiles)
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 2  # the activation, its backward
+
+
+@pytest.mark.parametrize("call", ["forward", "forward_and_backward"])
+def test_state_space_scan_compiles_at_the_hybrid_cells_row(v5e, call):
+    """`ssd_scan` at a row of `granite-h-micro-train-causal-8k`: 8192
+    positions in chunks of 256, 64 heads of 64 (4 a grid step: whole lane
+    tiles), a state of 128, bf16."""
+    from raft_stereo_tpu.ops import ssd_scan
+
+    shape = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    operands = (shape((1, 8192, 64, 64), jnp.bfloat16), shape((1, 8192, 64), jnp.float32), shape((64,), jnp.float32),
+                shape((1, 8192, 128), jnp.bfloat16), shape((1, 8192, 128), jnp.bfloat16), shape((64,), jnp.float32))
+
+    def value(*o):
+        y, final = ssd_scan.ssd_scan(*o, chunk=256, heads=4)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(final)
+
+    if call == "forward":
+        text = _assert_kernel_compiled(value, *operands)
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+        return
+    text = _assert_kernel_compiled(jax.value_and_grad(value, argnums=(0, 1, 2, 3, 4, 5)), *operands)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2  # ssd_chunk, ssd_chunk_bwd
+
+
+def test_causal_attention_compiles_at_the_hybrid_cells_row(v5e):
+    """The causal entry of the attention kernels at head 64: 32 query heads
+    on 8 key-value heads, 8192 positions in tiles of 512."""
+    from raft_stereo_tpu.ops import block_attention
+
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 64), jnp.bfloat16, sharding=v5e)
+    kv = jax.ShapeDtypeStruct((1, 8, 8192, 64), jnp.bfloat16, sharding=v5e)
+    value = lambda q, k, v: jnp.sum(block_attention.causal_attention(q, k, v, 0.015625, 512).astype(jnp.float32))
+    text = _assert_kernel_compiled(jax.value_and_grad(value, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 3  # forward, dq, dk/dv

@@ -276,6 +276,7 @@ def _kernel_calls():
     return {
         **attention,
         **grouped,
+        **_scan_kernel_calls(),
         "corr_lookup": lambda: corr_pallas.pallas_corr_lookup_padded(state, coords, 2),
         "corr_scatter": lambda: jax.grad(
             lambda s: corr_pallas.pallas_corr_lookup_padded(s, coords, 2).sum())(state),
@@ -287,6 +288,16 @@ def _kernel_calls():
         "gru_tail": lambda: gru_tail_pallas.fused_gru_tail(g, g, g, g, g),
         "motion_tail": lambda: gru_tail_pallas.fused_motion_tail(jnp.ones((1, 8, 16, 126)), jnp.ones((1, 8, 16, 1))),
     }
+
+
+def _scan_kernel_calls():
+    """The `granite-hybrid` family's scan kernels at a tiny size: 16
+    positions in chunks of 8, 2 heads of 8, a state of 8."""
+    from raft_stereo_tpu.ops import ssd_scan as ss
+
+    x, dt, bc = jnp.ones((1, 16, 2, 8)), jnp.ones((1, 16, 2)), jnp.ones((1, 16, 8))
+    scan = lambda x: ss.ssd_scan(x, dt, -jnp.ones((2,)), bc, bc, jnp.ones((2,)), chunk=8)[0].sum()
+    return {"ssd_chunk": lambda: scan(x), "ssd_chunk_bwd": lambda: jax.grad(scan)(x)}
 
 
 def _token_kernel_calls():
@@ -320,7 +331,7 @@ def _token_kernel_calls():
 
 KERNELS = [
     "block_attention", "block_attention_dq", "block_attention_dkv", "grouped_matmul", "grouped_matmul_drhs",
-    "gather_rows", "scatter_add_rows",
+    "gather_rows", "scatter_add_rows", "ssd_chunk", "ssd_chunk_bwd",
     "corr_lookup", "corr_scatter", "corr_lookup_prefetch", "corr_pyramid", "encoder_conv_s2d",
     "encoder_join", "gru_tail", "motion_tail",
 ]
