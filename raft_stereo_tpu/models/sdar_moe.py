@@ -4,9 +4,11 @@ Per layer, for the hidden state `h` (no bias anywhere):
 
 - `attention`: `a = RMSNorm(h)`; q (Hq heads), k, v (Hkv heads) of
   `head_dim`; RMSNorm over the head dimension of q and k (a learned weight
-  per head dimension); rotary embedding over the whole head dimension
-  (rotate-half) by position id; `ops.block_attention` under the
-  block-diffusion mask; `h1 = h + concat(heads) Wo`.
+  per head dimension), then the rotary embedding over the whole head
+  dimension (rotate-half) by position id, both in `ops.qk_norm_rope`'s one
+  pass from a projection's output to the heads-first operand;
+  `ops.block_attention` under the block-diffusion mask;
+  `h1 = h + concat(heads) Wo`.
 - `router`: `m = RMSNorm(h1)`; `p = softmax(m Wr)` in float32 over ALL
   `num_experts * expert_parallel` experts; the `num_experts_per_tok`
   largest, weights renormalised over them (`norm_topk_prob`).
@@ -38,6 +40,7 @@ from raft_stereo_tpu.config import SDARMoEConfig
 from raft_stereo_tpu.ops.block_attention import block_attention
 from raft_stereo_tpu.ops.data_axis import over_data_axis
 from raft_stereo_tpu.ops.grouped_matmul import group_layout, grouped_matmul, swiglu_rows
+from raft_stereo_tpu.ops.qk_norm_rope import qk_norm_rope
 from raft_stereo_tpu.ops.tile_rows import fits, gather_rows, scatter_add_rows
 
 Array = jax.Array
@@ -73,11 +76,13 @@ def rotary_tables(seq_len: int, head_dim: int, theta: float) -> Tuple[Array, Arr
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def _rotate(x: Array, cos: Array, sin: Array) -> Array:
-    """x: (B, S, H, d) float32; rotate-half convention."""
-    half = x.shape[-1] // 2
-    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return x * cos[None, :, None, :] + rotated * sin[None, :, None, :]
+class HeadNormWeight(nn.Module):
+    """The learned weight of a q or k norm, (head_dim,), where an `RMSNorm`
+    of that name keeps it; `ops.qk_norm_rope` applies it."""
+
+    @nn.compact
+    def __call__(self, head_dim: int) -> Array:
+        return self.param("weight", nn.initializers.ones, (head_dim,), jnp.float32)
 
 
 class Attention(nn.Module):
@@ -92,14 +97,11 @@ class Attention(nn.Module):
         w_k = self.param("w_k", _DENSE_INIT, (d, hkv * hd), jnp.float32)
         w_v = self.param("w_v", _DENSE_INIT, (d, hkv * hd), jnp.float32)
         w_o = self.param("w_o", _DENSE_INIT, (hq * hd, d), jnp.float32)
-        q = _matmul(a, w_q).reshape(b, s, hq, hd)
-        k = _matmul(a, w_k).reshape(b, s, hkv, hd)
-        v = _matmul(a, w_v).reshape(b, s, hkv, hd)
-        q = _rotate(RMSNorm(cfg.rms_norm_eps, name="q_norm")(q).astype(jnp.float32), cos, sin).astype(a.dtype)
-        k = _rotate(RMSNorm(cfg.rms_norm_eps, name="k_norm")(k).astype(jnp.float32), cos, sin).astype(a.dtype)
+        q = qk_norm_rope(_matmul(a, w_q), HeadNormWeight(name="q_norm")(hd), cos, sin, hq, cfg.rms_norm_eps)
+        k = qk_norm_rope(_matmul(a, w_k), HeadNormWeight(name="k_norm")(hd), cos, sin, hkv, cfg.rms_norm_eps)
         heads_first = lambda x: x.transpose(0, 2, 1, 3)
-        o = block_attention(
-            heads_first(q), heads_first(k), heads_first(v), s // 2, cfg.block_length, cfg.attention_tile)
+        v = heads_first(_matmul(a, w_v).reshape(b, s, hkv, hd))
+        o = block_attention(q, k, v, s // 2, cfg.block_length, cfg.attention_tile)
         return _matmul(heads_first(o).reshape(b, s, hq * hd), w_o)
 
 

@@ -24,8 +24,10 @@ Scopes the program writes (beside flax's module names `cnet`, `fnet`,
 `grad_clip` and `optimizer` (train/optimizer.py, train/trainer.py). The
 `sdar-moe` family (models/sdar_moe.py) writes flax's module names `embed`,
 `layers/{input_norm,attention,post_attention_norm,router,experts}`, `norm`,
-`lm_head`, and the scopes `block_attention` (ops/block_attention.py, under
-`attention`), `grouped_matmul` and `swiglu_rows` (ops/grouped_matmul.py; the
+`lm_head`, and the scopes `block_attention` (ops/block_attention.py) and
+`qk_norm_rope` (ops/qk_norm_rope.py; its backward is the Pallas call
+`qk_norm_rope_bwd` inside the scope; both under `attention`),
+`grouped_matmul` and `swiglu_rows` (ops/grouped_matmul.py; the
 activation's backward is the Pallas call `swiglu_rows_bwd` inside the scope
 `swiglu_rows`), `gather_rows` and `scatter_add_rows` (ops/tile_rows.py; all
 four under `experts`) and `block_diffusion_loss`; the two norms before a

@@ -213,3 +213,22 @@ def test_causal_attention_compiles_at_the_hybrid_cells_row(v5e):
     value = lambda q, k, v: jnp.sum(block_attention.causal_attention(q, k, v, 0.015625, 512).astype(jnp.float32))
     text = _assert_kernel_compiled(jax.value_and_grad(value, argnums=(0, 1, 2)), q, kv, kv)
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 3  # forward, dq, dk/dv
+
+
+@pytest.mark.parametrize("heads", [32, 4], ids=["q", "k"])
+def test_head_prologue_compiles_at_the_token_cells_batch(v5e, heads):
+    """`qk_norm_rope` and its VJP at `sdar-a3b-train-blockdiff-4k`'s batch: 4
+    rows of 8192 positions, 32 query or 4 key heads of 128 (one lane tile a
+    head), bf16 in and out, float32 tables; and no float32 array of the
+    operand's size between the two calls and the rest of the program."""
+    from raft_stereo_tpu.ops import qk_norm_rope
+
+    shape = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    x, weight, table = shape((4, 8192, heads * 128), jnp.bfloat16), shape((128,), jnp.float32), shape((8192, 128), jnp.float32)
+    value = lambda x, w, cos, sin: jnp.sum(qk_norm_rope.qk_norm_rope(x, w, cos, sin, heads, 1e-6).astype(jnp.float32))
+    text = _assert_kernel_compiled(jax.value_and_grad(value, argnums=(0, 1)), x, weight, table, table)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2  # the prologue, its backward
+    assert f"f32[4,8192,{heads * 128}]" not in text and f"f32[4,8192,{heads},128]" not in text
+    with pytest.raises(ValueError, match="qk_norm_rope: a head dimension of 64"):
+        qk_norm_rope.qk_norm_rope(shape((4, 8192, heads * 64), jnp.bfloat16), shape((64,), jnp.float32),
+                                  shape((8192, 64), jnp.float32), shape((8192, 64), jnp.float32), heads, 1e-6)

@@ -302,7 +302,7 @@ def _scan_kernel_calls():
 
 def _token_kernel_calls():
     """The `sdar-moe` family's kernels at a tiny size: 2 x 16 positions in
-    blocks of 4; 16 rows in tiles of 8 over 2 experts."""
+    blocks of 4, 2 heads of 8; 16 rows in tiles of 8 over 2 experts."""
     from raft_stereo_tpu.ops import block_attention as ba
     from raft_stereo_tpu.ops import grouped_matmul as gm
 
@@ -312,10 +312,15 @@ def _token_kernel_calls():
     groups = (layout["tile_expert"], layout["num_tiles"], 8)
     lhs, rhs = jnp.ones((gm.rows_bound(16, 2, 8), 8)), jnp.ones((2, 8, 16))
     product = lambda lhs, rhs: gm.grouped_matmul(lhs, rhs, *groups).sum()
+    from raft_stereo_tpu.ops import qk_norm_rope as qk
+
+    prologue = lambda x: qk.qk_norm_rope(x, jnp.ones((8,)), jnp.ones((32, 8)), jnp.zeros((32, 8)), 2, 1e-6).sum()
     attention = {
         "block_attention": lambda: attend(q, kv, kv),
         "block_attention_dq": lambda: jax.grad(attend, 0)(q, kv, kv),
         "block_attention_dkv": lambda: jax.grad(attend, 1)(q, kv, kv),
+        "qk_norm_rope": lambda: prologue(jnp.ones((1, 32, 16))),
+        "qk_norm_rope_bwd": lambda: jax.grad(prologue)(jnp.ones((1, 32, 16))),
     }
     from raft_stereo_tpu.ops import tile_rows as tr
 
@@ -330,7 +335,8 @@ def _token_kernel_calls():
 
 
 KERNELS = [
-    "block_attention", "block_attention_dq", "block_attention_dkv", "grouped_matmul", "grouped_matmul_drhs",
+    "block_attention", "block_attention_dq", "block_attention_dkv", "qk_norm_rope", "qk_norm_rope_bwd",
+    "grouped_matmul", "grouped_matmul_drhs",
     "gather_rows", "scatter_add_rows", "ssd_chunk", "ssd_chunk_bwd",
     "corr_lookup", "corr_scatter", "corr_lookup_prefetch", "corr_pyramid", "encoder_conv_s2d",
     "encoder_join", "gru_tail", "motion_tail",

@@ -20,6 +20,7 @@ from raft_stereo_tpu.config import RAFTStereoConfig, SDARMoEConfig, TrainConfig
 from raft_stereo_tpu.models import sdar_moe
 from raft_stereo_tpu.ops import block_attention as ba
 from raft_stereo_tpu.ops import grouped_matmul as gm
+from raft_stereo_tpu.ops import qk_norm_rope as qk
 from raft_stereo_tpu.ops import tile_rows as tr
 
 SEQ = 32
@@ -399,6 +400,65 @@ def test_live_row_share_counts_the_live_tiles_of_a_hand_made_routing():
     assert int(layout["num_tiles"][0]) * 8 / layout["row_live"].shape[0] == 11 / 20
 
 
+# -- the head prologue: q/k norm, rotary and the heads-first layout in one pass -----------
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("tile", [16, 64], ids=["four-tiles", "one-tile"])
+@pytest.mark.parametrize("heads", [32, 4, 1])
+def test_qk_norm_rope_and_its_gradients_match_the_jax_numpy_form(heads, tile, dtype):
+    """The forward, and the gradients by x and by the learned weight (the
+    tables carry none), against `qk_norm_rope_dense`: float32 to 1e-5 of the
+    largest value, bfloat16 to its step (2^-8 of the largest)."""
+    batch, positions, d = 2, 64, 128
+    dtype = jnp.dtype(dtype)
+    keys = jax.random.split(jax.random.PRNGKey(heads), 3)
+    x = (2.0 * jax.random.normal(keys[0], (batch, positions, heads * d))).astype(dtype)
+    weight = jax.random.uniform(keys[1], (d,), minval=0.5, maxval=6.0)  # learned: not all ones
+    d_out = jax.random.normal(keys[2], (batch, heads, positions, d)).astype(dtype)
+    cos, sin = sdar_moe.rotary_tables(positions // 2, d, 1e6)
+    kernel = lambda x, w, cos, sin: qk.qk_norm_rope(x, w, cos, sin, heads, 1e-6, tile)
+    dense = lambda x, w: qk.qk_norm_rope_dense(x, w, cos, sin, heads, 1e-6)
+    (got, pull), (want, pull_dense) = jax.vjp(kernel, x, weight, cos, sin), jax.vjp(dense, x, weight)
+    tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -8
+    assert got.shape == (batch, heads, positions, d) and got.dtype == dtype
+    assert _close(got.astype(jnp.float32), want.astype(jnp.float32), tol)
+    (d_x, d_weight, d_cos, d_sin), (want_x, want_weight) = pull(d_out), pull_dense(d_out)
+    assert d_x.dtype == dtype and d_weight.dtype == jnp.float32
+    assert _close(d_x.astype(jnp.float32), want_x.astype(jnp.float32), tol)
+    # float32 sums of one product's roundings on either side: no worse than a step of the input
+    assert _close(d_weight, want_weight, tol)
+    assert not d_cos.any() and not d_sin.any()  # position ids are data
+
+
+def test_qk_norm_rope_refuses_a_head_dimension_of_64_by_name(monkeypatch):
+    """Compiled for the chip, a head is a column block of whole lane tiles;
+    the refusal is a `ValueError` from shapes, before any kernel is built.
+    (The interpreter has no lane tiles: the tiny models' heads of 16 run.)"""
+    x, weight = jnp.ones((1, 16, 4 * 64)), jnp.ones((64,))
+    cos, sin = sdar_moe.rotary_tables(8, 64, 1e6)
+    assert qk.qk_norm_rope(x, weight, cos, sin, 4, 1e-6).shape == (1, 4, 16, 64)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # as the chip has it
+    with pytest.raises(ValueError, match="qk_norm_rope: a head dimension of 64 is not a multiple of 128"):
+        qk.qk_norm_rope(x, weight, cos, sin, 4, 1e-6)
+    with pytest.raises(ValueError, match="qk_norm_rope: x"):  # 3 heads of 64 are not x's 256 columns
+        qk.qk_norm_rope(x, weight, cos, sin, 3, 1e-6)
+
+
+@pytest.mark.parametrize("event,kernel", [
+    ("%qk_norm_rope.3 = bf16[4,32,8192,128]{3,2,1,0} custom-call(bf16[4,8192,4096]{2,1,0} %a, f32[1,128]{1,0} %w)", "qk_norm_rope"),
+    ("%qk_norm_rope_bwd.7 = (bf16[4,8192,512]{2,1,0}, f32[64,1,128]{2,1,0}) custom-call(bf16[4,4,8192,128]{3,2,1,0} %dz)", "qk_norm_rope_bwd"),
+    ("%block_attention.11 = (bf16[4,32,8192,128]{3,2,1,0}, f32[4,32,1,8192]{3,2,1,0}) custom-call(s32[8192,1]{1,0} %c)", "block_attention"),
+])
+def test_a_trace_event_of_a_prologue_call_is_read_as_that_kernels_alone(event, kernel):
+    """The roofline readers find a kernel's events by the exact name of its
+    Pallas call: the two new calls are no attention kernel's, nor each
+    other's."""
+    from benchmark.readers import trace_kernel
+
+    assert trace_kernel.kernel_of(event) == kernel
+
+
 # -- the trainer's rules for the family -----------------------------------------------
 
 
@@ -492,11 +552,44 @@ def test_the_token_step_holds_no_float32_array_of_a_chunks_row_buffer(tmp_path):
     assert f"tensor<{rows}x{2 * f}xf32>" not in text and f"tensor<{rows}x{f}xf32>" not in text
 
 
+def test_the_token_step_holds_no_float32_array_of_a_q_sized_shape(tmp_path):
+    """With bfloat16 compute, q stays bfloat16 from its projection to the
+    attention kernels: a float32 array of (B, 2L, Hq, d), of half heads
+    (B, 2L, Hq, d/2) or (B, Hq, 2L, d/2) in the step's lowered text, or an elementwise
+    pass over (B, 2L, Hq x d) in float32, is the norm's or the rotary's pass
+    through HBM come back (on the chip: 537 MB a pass, a half head padded to
+    a whole lane tile). The interpreted kernels' bodies are in this text too,
+    and widen one tile's (rows, d) block only."""
+    from raft_stereo_tpu.obs import scopes
+    from raft_stereo_tpu.train.trainer import Trainer
+
+    heads, d = 6, 16  # 6 x 16 = 96 columns, three layers: no other array of the step is (2, 2L, 96)
+    program = dict(expert_parallel=2, expert_shard=1, block_length=4, mask_token_id=95)
+    model = SDARMoEConfig.from_hf_config(
+        dict(PUBLISHED, num_experts=4, num_attention_heads=heads, num_hidden_layers=3), **program,
+        **dict(TILES, mixed_precision=True))
+    trainer = Trainer(_tiny_train_config(tmp_path, model), sample_shape=(SEQ,))
+    text = trainer.train_step.lower(scopes.abstract(trainer.state), trainer._abstract_batch()).as_text()
+    b, s = 2, 2 * SEQ
+    assert f"tensor<{b}x{s}x{heads * d}xbf16>" in text and f"tensor<{b}x{heads}x{s}x{d}xbf16>" in text  # q as projected, q as attended
+    # (the heads-first whole head, (B, Hq, 2L, d), is read in float32 by the attention backward's
+    # `delta`, one fused reduction of do * o: not this chain's)
+    for shape in ((b, s, heads, d), (b, s, heads, d // 2), (b, heads, s, d // 2)):
+        assert f"tensor<{'x'.join(map(str, shape))}xf32>" not in text, shape
+    # (B, 2L, Hq x d) in float32 is a projection's accumulator and nothing else: a product (forward or
+    # transposed) and the rounding beside it, which the chip's compiler fuses into the product
+    flat = [line for line in text.splitlines() if f"tensor<{b}x{s}x{heads * d}xf32>" in line]
+    assert flat and all(" = stablehlo.dot_general " in line or " = stablehlo.convert " in line for line in flat)
+
+
 @pytest.mark.parametrize("path,want", [
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/embed/take", ("embed", "forward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/input_norm/mul", ("attention", "forward")),
     ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/layers/attention/block_attention/pallas_call", ("attention", "backward")),
-    ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/rematted_computation/layers/attention/q_norm/mul", ("attention", "recompute")),
+    ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/attention/qk_norm_rope/qk_norm_rope/pallas_call", ("attention", "forward")),
+    ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/rematted_computation/layers/attention/qk_norm_rope/qk_norm_rope/pallas_call", ("attention", "recompute")),
+    ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/layers/attention/qk_norm_rope/qk_norm_rope_bwd/pallas_call", ("attention", "backward")),
+    ("jit(step_fn)/transpose(jvp(SDARDecoder.loss))/SDARDecoder.hidden/while/body/closed_call/checkpoint/layers/attention/qk_norm_rope/reduce_sum", ("attention", "backward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/post_attention_norm/rsqrt", ("router", "forward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/router/top_k", ("router", "forward")),
     ("jit(step_fn)/jvp(SDARDecoder.loss)/SDARDecoder.hidden/while/body/closed_call/layers/experts/while/body/closed_call/checkpoint/grouped_matmul/pallas_call", ("experts", "forward")),
