@@ -161,8 +161,8 @@ def _load_variables(restore_ckpt: Optional[str], config: RAFTStereoConfig):
 
 def _token_model_config(args):
     """A token family's model from a published `config.json`-shaped file,
-    the family picked by its `model_type` (`sdar_moe`, `granitemoehybrid`);
-    a `program` group in it (expert_parallel, expert_shard, block_length,
+    the family picked by its `model_type` (`sdar_moe`, `granitemoehybrid`,
+    `laguna`); a `program` group in it (expert_parallel, expert_shard, block_length,
     ...; benchmark/configs/ has them) sets the chip's share and the program's
     own keys."""
     import json
@@ -184,8 +184,10 @@ def _train_parser() -> argparse.ArgumentParser:
     p.add_argument("--token_config", default=None,
                    help="train a token family instead of RAFT-Stereo, picked "
                    "by the file's model_type: sdar_moe (a routed-expert "
-                   "decoder under block diffusion) or granitemoehybrid (a "
-                   "Mamba-2 / attention hybrid on the next-token loss): path "
+                   "decoder under block diffusion), granitemoehybrid (a "
+                   "Mamba-2 / attention hybrid on the next-token loss) or "
+                   "laguna (a routed-expert decoder of window and full "
+                   "attention layers on the next-token loss): path "
                    "to a config.json-shaped file, optionally with a `program` "
                    "group; batches come from a seeded Zipf source "
                    "(data/tokens.py)")

@@ -345,11 +345,131 @@ class GraniteHybridConfig:
         return cls(**merged)
 
 
+@dataclasses.dataclass(frozen=True)
+class LagunaConfig:
+    """The fourth model family, `laguna-moe` (`model_type: laguna`: poolside
+    Laguna): a routed-expert decoder on the plain next-token loss whose
+    layers differ in kind. `layer_types` gives each layer's attention
+    (`full_attention`: causal; `sliding_attention`: causal under a window of
+    `sliding_window` keys), `num_attention_heads_per_layer` its query heads,
+    `mlp_layer_types` its second half (`dense`: a gated MLP of
+    `intermediate_size`; `sparse`: routed experts beside one shared expert).
+    Every attention has a per-head sigmoid gate on its output (the published
+    `gating`; no other value is modelled, so it is no field), q
+    and k normed over the head, and the rotary embedding of its kind
+    (`rope_full` / `rope_sliding`, the published `rope_parameters`' groups as
+    sorted (key, value) pairs: YaRN or default, over `partial_rotary_factor`
+    of the head). The router scores by sigmoid, renormalises its
+    `num_experts_per_tok` choices and scales the routed sum by
+    `moe_routed_scaling_factor`. Key names are the published `config.json`'s;
+    `from_hf_config` reads such a file. Defaults are the XS.2 release.
+
+    The counts may be ONE CHIP'S SHARE, as `SDARMoEConfig`'s: `num_experts`
+    the experts HELD here of `num_experts * expert_parallel` (the router
+    scores them all), `vocab_size` the vocabulary rows held, and the three
+    per-layer lists the layers of one pipeline stage; the loss is then taken
+    at the stage's output. Attention and the shared expert are whole on
+    every chip."""
+
+    vocab_size: int = 100352
+    hidden_size: int = 2048
+    intermediate_size: int = 8192
+    layer_types: Tuple[str, ...] = tuple(
+        "full_attention" if i % 4 == 0 else "sliding_attention" for i in range(40))
+    mlp_layer_types: Tuple[str, ...] = ("dense",) + ("sparse",) * 39
+    num_attention_heads_per_layer: Tuple[int, ...] = tuple(48 if i % 4 == 0 else 64 for i in range(40))
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    sliding_window: int = 512
+    rope_full: Tuple[Tuple[str, Any], ...] = (
+        ("attention_factor", 1.4158883083359672), ("beta_fast", 64), ("beta_slow", 1), ("factor", 64),
+        ("original_max_position_embeddings", 4096), ("partial_rotary_factor", 0.5), ("rope_theta", 500000),
+        ("rope_type", "yarn"))
+    rope_sliding: Tuple[Tuple[str, Any], ...] = (
+        ("partial_rotary_factor", 1), ("rope_theta", 10000), ("rope_type", "default"))
+    num_experts: int = 256
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    moe_routed_scaling_factor: float = 2.5
+    rms_norm_eps: float = 1e-6
+    # -- the chip's share --
+    expert_parallel: int = 1
+    expert_shard: int = 0
+    # -- program (as `SDARMoEConfig`'s) --
+    mixed_precision: bool = True  # bf16 compute, float32 parameters
+    remat_layers: bool = True
+    moe_chunk: int = 4096
+    moe_tile_rows: int = 128
+    attention_tile: int = 512
+    loss_chunk: int = 4096
+
+    @property
+    def num_hidden_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def router_width(self) -> int:
+        return self.num_experts * self.expert_parallel
+
+    def rope(self, kind: str) -> Dict[str, Any]:
+        """The rotary parameters of a layer's kind."""
+        return dict(self.rope_full if kind == "full_attention" else self.rope_sliding)
+
+    def __post_init__(self):
+        for name in ("layer_types", "mlp_layer_types", "num_attention_heads_per_layer"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        for name in ("rope_full", "rope_sliding"):
+            object.__setattr__(self, name, tuple(sorted(dict(getattr(self, name)).items())))
+        layers = len(self.layer_types)
+        if not layers or len(self.mlp_layer_types) != layers or len(self.num_attention_heads_per_layer) != layers:
+            raise ValueError("layer_types, mlp_layer_types and num_attention_heads_per_layer name the same layers")
+        if set(self.layer_types) - {"full_attention", "sliding_attention"}:
+            raise ValueError(f"layer_types holds {sorted(set(self.layer_types))}")
+        if set(self.mlp_layer_types) - {"dense", "sparse"}:
+            raise ValueError(f"mlp_layer_types holds {sorted(set(self.mlp_layer_types))}")
+        if any(h % self.num_key_value_heads for h in self.num_attention_heads_per_layer):
+            raise ValueError("a layer's query heads must be a multiple of num_key_value_heads")
+        if not 0 <= self.expert_shard < self.expert_parallel:
+            raise ValueError(f"expert_shard {self.expert_shard} not in [0, {self.expert_parallel})")
+        if self.num_experts_per_tok > self.router_width:
+            raise ValueError("num_experts_per_tok exceeds the router's width")
+        for kind in ("full_attention", "sliding_attention"):
+            rope = self.rope(kind)
+            if rope.get("rope_type", "default") not in ("default", "yarn"):
+                raise NotImplementedError(f"laguna: rope_type {rope['rope_type']!r} is neither default nor yarn")
+            if int(self.head_dim * rope.get("partial_rotary_factor", 1)) % 2:
+                raise ValueError("the rotary dimension must be even")
+
+    @classmethod
+    def from_hf_config(cls, published: Dict[str, Any], **program) -> "LagunaConfig":
+        """From a `config.json`-shaped dict (keys this class does not model
+        are ignored; a gate other than per head, a softcapped router, router
+        weights on the input and an attention bias are refused) and the
+        program's own keys."""
+        if published.get("gating", True) not in (True, "per-head"):
+            raise NotImplementedError(f"laguna: gating {published['gating']!r} is not the per-head gate")
+        if published.get("moe_router_logit_softcapping", 0) or published.get("moe_apply_router_weight_on_input"):
+            raise NotImplementedError("laguna: a softcapped router / router weights on the input are not modelled")
+        if published.get("attention_bias") or published.get("tie_word_embeddings"):
+            raise NotImplementedError("laguna: no bias in attention, and an untied head")
+        names = {f.name for f in dataclasses.fields(cls)}
+        merged = {k: v for k, v in {**published, **program}.items() if k in names}
+        groups = published.get("rope_parameters", {})
+        for field, kind in (("rope_full", "full_attention"), ("rope_sliding", "sliding_attention")):
+            if kind in groups:
+                merged[field] = tuple(sorted(groups[kind].items()))
+        if "layer_types" in merged and "num_hidden_layers" in published:
+            if len(merged["layer_types"]) != published["num_hidden_layers"]:
+                raise ValueError("layer_types does not name num_hidden_layers layers")
+        return cls(**merged)
+
+
 # `model_type` of a published config.json -> the family's config class
 # (`cli --token_config` picks the family by it).
-TOKEN_FAMILIES = {"sdar_moe": SDARMoEConfig, "granitemoehybrid": GraniteHybridConfig}
+TOKEN_FAMILIES = {"sdar_moe": SDARMoEConfig, "granitemoehybrid": GraniteHybridConfig, "laguna": LagunaConfig}
 
-ModelConfig = Union[RAFTStereoConfig, SDARMoEConfig, GraniteHybridConfig]
+ModelConfig = Union[RAFTStereoConfig, SDARMoEConfig, GraniteHybridConfig, LagunaConfig]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -384,7 +504,7 @@ class AugmentConfig:
 class TrainConfig:
     """Training-loop config (reference train_stereo.py:234-272)."""
 
-    # One of the two model families: the trainer takes how to initialise,
+    # One of the model families: the trainer takes how to initialise,
     # what a batch holds and the loss from it (train/families.py).
     model: ModelConfig = dataclasses.field(default_factory=RAFTStereoConfig)
     augment: AugmentConfig = dataclasses.field(default_factory=AugmentConfig)

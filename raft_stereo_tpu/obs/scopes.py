@@ -39,7 +39,12 @@ residual count with it), `ssm_conv` (the causal convolution, `silu`, the
 split and `dt`'s softplus), `ssm_scan` (ops/ssd_scan.py: the kernels
 `ssd_chunk` / `ssd_chunk_bwd`, the running sums, the recurrence over the
 chunks) and `ssm_gate_norm`; `block_attention` under `attention`; `lm_head`
-and `next_token_loss`.
+and `next_token_loss`. The `laguna-moe` family (models/laguna.py) writes
+flax's module names `embed`, `layers_<i>/{attention_full,attention_window}`
+(a layer's attention by its kind, its norm, gate and residual inside; the
+scopes `qk_norm_rope` and `block_attention` or `window_attention` under
+them), `layers_<i>/{mlp_norm,mlp}` or `layers_<i>/{post_attention_norm,
+router,experts,shared_expert}`, `norm`, `lm_head` and `next_token_loss`.
 
 Pure: jax is touched only by `scoped` (at trace time) and `abstract`; nothing
 is lowered or parsed until `registered()` is called.
@@ -64,6 +69,8 @@ COMPONENTS = (
     "embed", "attention", "router", "experts", "lm_head",
     # the `granite-hybrid` family's, beside `embed`, `attention`, `lm_head`
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm", "mlp",
+    # the `laguna-moe` family's, beside `embed`, `router`, `experts`, `mlp`, `lm_head`
+    "attention_full", "attention_window", "shared_expert",
     "loss", "optimizer", "collective", "other", "unscoped",
 )
 PHASES = ("forward", "backward", "recompute")
@@ -86,6 +93,9 @@ _ROWS = tuple(
         (r"upsample", "upsample"),
         (r"embed", "embed"),
         (r"attention|input_norm", "attention"),
+        (r"attention_full", "attention_full"),
+        (r"attention_window", "attention_window"),
+        (r"shared_expert", "shared_expert"),
         (r"router|post_attention_norm", "router"),
         (r"experts", "experts"),
         (r"ssm_proj|ssm_norm", "ssm_proj"),

@@ -30,8 +30,19 @@ runs the kernels on its own rows of the batch (ops/data_axis.py).
 of a row of L positions: the codes are `q_lim = pos`, `q_eq = -1`,
 `k_code = pos`, a query tile walks the key tiles up to its own and a key tile
 the query tiles from its own on, and the caller gives the scale of the scores
-(a model may fix a multiplier other than 1/sqrt(d)). Which tiles a tile sees
-is the one thing the two entries do not share: `_BlockWalk` / `_CausalWalk`.
+(a model may fix a multiplier other than 1/sqrt(d)).
+
+`window_attention` is the causal row under a sliding window: query i sees
+the `window` keys `i - window + 1 .. i`. The codes' middle vector carries the
+lower limit in place of the code a query equals (`q_low = pos - window + 1`;
+a query sees a key iff `q_low <= code <= q_lim`: `_in_window`), a query tile
+walks only the key tiles its window reaches, `ceil((window - 1) / tile)`
+before its own, and a key tile the matching query tiles after it. Its Pallas
+calls are named `window_attention*`, so that a trace tells them from the
+full mask's `block_attention*` calls of the same program.
+
+Which tiles a tile sees, and a tile's mask test, are all the entries do not
+share: `_BlockWalk` / `_CausalWalk` / `_WindowWalk`.
 """
 
 from __future__ import annotations
@@ -51,6 +62,11 @@ from raft_stereo_tpu.ops.pallas_mode import pallas_interpret
 
 Array = jax.Array
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
+# The Pallas calls' names (forward, dq, dk/dv): the full masks', the window's.
+KERNEL_NAMES = {
+    False: ("block_attention", "block_attention_dq", "block_attention_dkv"),
+    True: ("window_attention", "window_attention_dq", "window_attention_dkv"),
+}
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 
 
@@ -67,6 +83,10 @@ def mask_codes(seq_len: int, block_length: int) -> Tuple[Array, Array, Array]:
 
 def _visible(q_lim, q_eq, k_code):
     return ((k_code >= 0) & (k_code <= q_lim)) | (k_code == q_eq)
+
+
+def _in_window(q_lim, q_low, k_code):
+    return (k_code <= q_lim) & (k_code >= q_low)
 
 
 def block_mask(seq_len: int, block_length: int) -> Array:
@@ -93,6 +113,13 @@ def causal_attention_dense(q: Array, k: Array, v: Array, scale: float) -> Array:
     """`causal_attention` with a materialised mask: what the causal entry is
     tested against."""
     return _dense(q, k, v, jnp.tril(jnp.ones((q.shape[2], q.shape[2]), bool)), scale)
+
+
+def window_attention_dense(q: Array, k: Array, v: Array, window: int, scale: float) -> Array:
+    """`window_attention` with a materialised mask: what the window entry is
+    tested against."""
+    pos = jnp.arange(q.shape[2])
+    return _dense(q, k, v, _in_window(pos[:, None], pos[:, None] - window + 1, pos[None, :]), scale)
 
 
 # -- which tiles a tile sees ----------------------------------------------------
@@ -133,6 +160,7 @@ class _BlockWalk(NamedTuple):
     walk of a query tile / of a key tile (the grid's last axis)."""
 
     nh: int
+    visible = staticmethod(_visible)
 
     @property
     def tiles(self):
@@ -164,6 +192,7 @@ class _CausalWalk(NamedTuple):
     tile kt is seen by query tiles kt..nt-1."""
 
     nt: int
+    visible = staticmethod(_visible)
 
     @property
     def tiles(self):
@@ -184,33 +213,75 @@ class _CausalWalk(NamedTuple):
         return kt + jnp.minimum(u, self.nt - kt - 1)
 
 
+class _WindowWalk(NamedTuple):
+    """A causal row of `nt` tiles under a window that reaches `reach` tiles
+    back: query tile qt sees key tiles max(0, qt - reach)..qt, key tile kt is
+    seen by query tiles kt..min(nt - 1, kt + reach)."""
+
+    nt: int
+    reach: int
+    visible = staticmethod(_in_window)
+
+    @property
+    def tiles(self):
+        return self.nt
+
+    @property
+    def fwd_max(self):
+        return min(self.reach + 1, self.nt)
+
+    bwd_max = fwd_max
+
+    def fwd_steps(self, qt):
+        return jnp.minimum(qt, self.reach) + 1
+
+    def fwd_key_tile(self, qt, s):
+        return jnp.maximum(qt - self.reach, 0) + jnp.minimum(s, jnp.minimum(qt, self.reach))
+
+    def bwd_steps(self, kt):
+        return jnp.minimum(self.reach, self.nt - 1 - kt) + 1
+
+    def bwd_query_tile(self, kt, u):
+        return kt + jnp.minimum(u, jnp.minimum(self.reach, self.nt - 1 - kt))
+
+
 class _Mask(NamedTuple):
     """What the calls are traced for: `block_length` 0 is the causal mask of
-    `seq_len` positions, otherwise the block-diffusion mask of 2 x `seq_len`."""
+    `seq_len` positions (under a sliding window of `window` keys, where that
+    is not 0), otherwise the block-diffusion mask of 2 x `seq_len`."""
 
     seq_len: int
     block_length: int
+    window: int = 0
 
     @property
     def positions(self):
         return 2 * self.seq_len if self.block_length else self.seq_len
 
+    @property
+    def kernels(self):
+        """The Pallas calls' names: forward, dq, dk/dv."""
+        return KERNEL_NAMES[bool(self.window)]
+
     def codes(self):
         if self.block_length:
             return mask_codes(self.seq_len, self.block_length)
         pos = jnp.arange(self.seq_len, dtype=jnp.int32)
-        return pos, jnp.full_like(pos, -1), pos
+        return pos, (pos - self.window + 1 if self.window else jnp.full_like(pos, -1)), pos
 
     def walk(self, tile: int):
         t = _tile(self.seq_len, self.block_length or 1, tile)
+        if self.window:
+            return t, _WindowWalk(self.seq_len // t, -(-(self.window - 1) // t))
         return t, (_BlockWalk if self.block_length else _CausalWalk)(self.seq_len // t)
 
 
-def _scores(a, b, q_lim, q_eq, k_code, scale):
+def _scores(a, b, q_lim, q_eq, k_code, scale, visible):
     """Masked scores a @ b.T (queries down and keys across, or the other way
-    round: the codes broadcast to whichever it is) and the mask."""
+    round: the codes broadcast to whichever it is) and the mask, by the
+    walk's test."""
     s = jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32) * scale
-    mask = _visible(q_lim, q_eq, k_code)
+    mask = visible(q_lim, q_eq, k_code)
     return jnp.where(mask, s, _NEG), mask
 
 
@@ -239,7 +310,7 @@ def _fwd_kernel(ql_ref, qe_ref, kc_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s
     @pl.when(s < walk.fwd_steps(qt))
     def _():
         v = v_ref[0, 0]
-        sc, mask = _scores(q_ref[0, 0], k_ref[0, 0], ql_ref[...], qe_ref[...], kc_ref[...], scale)
+        sc, mask = _scores(q_ref[0, 0], k_ref[0, 0], ql_ref[...], qe_ref[...], kc_ref[...], scale, walk.visible)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -270,7 +341,7 @@ def _dq_kernel(
     @pl.when(s < walk.fwd_steps(qt))
     def _():
         k, v, do = k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
-        sc, mask = _scores(q_ref[0, 0], k, ql_ref[...], qe_ref[...], kc_ref[...], scale)
+        sc, mask = _scores(q_ref[0, 0], k, ql_ref[...], qe_ref[...], kc_ref[...], scale, walk.visible)
         p = jnp.where(mask, jnp.exp(sc - lse_scr[...]), 0.0)
         dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
         ds = p * (dp - delta_scr[...]) * scale
@@ -297,7 +368,7 @@ def _dkv_kernel(
         # Keys down, queries across: a query's statistics are rows, and both
         # accumulations are plain products.
         q, v, do = q_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
-        sc, mask = _scores(k_ref[0, 0], q, ql_ref[...], qe_ref[...], kc_ref[...], scale)
+        sc, mask = _scores(k_ref[0, 0], q, ql_ref[...], qe_ref[...], kc_ref[...], scale, walk.visible)
         p = jnp.where(mask, jnp.exp(sc - lse_ref[0, 0]), 0.0)
         dv_scr[...] += jnp.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
@@ -362,6 +433,7 @@ def _forward(q, k, v, mask, scale, tile):
     b, hq, positions, hd = q.shape
     t, walk = mask.walk(tile)
     codes, by_q, by_k, stat = _query_major_specs(t, hd, walk, hq // k.shape[1])
+    name = mask.kernels[0]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, walk=walk, scale=scale),
         grid=(b, hq, walk.tiles, walk.fwd_max),
@@ -371,7 +443,7 @@ def _forward(q, k, v, mask, scale, tile):
         scratch_shapes=[pltpu.VMEM((t, 1), jnp.float32), pltpu.VMEM((t, 1), jnp.float32), pltpu.VMEM((t, hd), jnp.float32)],
         compiler_params=_PARAMS,
         interpret=pallas_interpret(),
-        name="block_attention",
+        name=name,
     )(*_codes(mask), q, k, v)
 
 
@@ -383,6 +455,7 @@ def _backward(q, k, v, o, lse, do, mask, scale, tile):
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[:, :, None, :]
 
     codes, by_q, by_k, stat = _query_major_specs(t, hd, walk, group)
+    name = mask.kernels[1]
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, walk=walk, scale=scale),
         grid=(b, hq, walk.tiles, walk.fwd_max),
@@ -392,7 +465,7 @@ def _backward(q, k, v, o, lse, do, mask, scale, tile):
         scratch_shapes=[pltpu.VMEM((t, hd), jnp.float32), pltpu.VMEM((t, 1), jnp.float32), pltpu.VMEM((t, 1), jnp.float32)],
         compiler_params=_PARAMS,
         interpret=pallas_interpret(),
-        name="block_attention_dq",
+        name=name,
     )(*_codes(mask), q, k, v, do, lse, delta)
 
     # dk/dv: one key tile of one key-value head at a time; the last axis
@@ -403,6 +476,7 @@ def _backward(q, k, v, o, lse, do, mask, scale, tile):
     by_q = _vmem((1, 1, t, hd), lambda b_, hk, kt, step: (b_, q_head(b_, hk, kt, step), q_of(b_, hk, kt, step), 0))
     q_stat = _vmem((1, 1, 1, t), lambda b_, hk, kt, step: (b_, q_head(b_, hk, kt, step), 0, q_of(b_, hk, kt, step)))
     by_k = _vmem((1, 1, t, hd), lambda b_, hk, kt, step: (b_, hk, kt, 0))
+    name = mask.kernels[2]
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, walk=walk, scale=scale),
         grid=(b, hkv, walk.tiles, group * walk.bwd_max),
@@ -412,7 +486,7 @@ def _backward(q, k, v, o, lse, do, mask, scale, tile):
         scratch_shapes=[pltpu.VMEM((t, hd), jnp.float32), pltpu.VMEM((t, hd), jnp.float32)],
         compiler_params=_PARAMS,
         interpret=pallas_interpret(),
-        name="block_attention_dkv",
+        name=name,
     )(*_codes(mask, keys_down=True), q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -453,3 +527,13 @@ def causal_attention(q: Array, k: Array, v: Array, scale: float, tile: int = 512
     are scaled by `scale`; position i sees positions 0..i. Returns
     (B, Hq, L, d) in q's dtype."""
     return _over_rows(q, k, v, _Mask(q.shape[2], 0), float(scale), tile)
+
+
+@scoped("window_attention")
+def window_attention(q: Array, k: Array, v: Array, window: int, scale: float, tile: int = 512) -> Array:
+    """q: (B, Hq, L, d); k, v: (B, Hkv, L, d), Hq a multiple of Hkv; scores
+    are scaled by `scale`; position i sees the `window` positions
+    i - window + 1 .. i. Returns (B, Hq, L, d) in q's dtype."""
+    if window < 1:
+        raise ValueError(f"window_attention: a window of {window} keys")
+    return _over_rows(q, k, v, _Mask(q.shape[2], 0, int(window)), float(scale), tile)

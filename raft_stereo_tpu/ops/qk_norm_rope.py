@@ -1,5 +1,5 @@
-"""The head prologue of the `sdar-moe` family's attention as one Pallas pass
-over a projection's output, and one pass back.
+"""The head prologue of the routed-expert families' attention as one Pallas
+pass over a projection's output, and one pass back.
 
 For x = a projection's output, (B, S, H x d) in the compute dtype, per
 position and head
@@ -7,13 +7,21 @@ position and head
     y = x * rsqrt(mean(x^2) + eps) * w          (RMSNorm over the head, w: (d,))
     z = y * cos + rotate_half(y) * sin          (rotary, by position id)
 
+over the first r dimensions of the head, r the tables' width, and `z = y`
+over the other d - r (a partial rotary: `rotate_half` turns the first r
+dimensions among themselves). The tables are whatever the caller built: a
+scaled rotary (YaRN's attention factor) rides in cos and sin.
+
 written as (B, H, S, d) in x's dtype: the operand `ops.block_attention`
 takes. Everything between the load and the store is float32 in VMEM, so the
 only roundings are the input's and the output's, and no float32 array and no
 half-head array goes to HBM. `rotate_half(y) = concat(-y[d/2:], y[:d/2])` is a
 lane rotation by d/2 (`pltpu.roll`) against the sine table with the sign
 folded in; a rotation by half the lanes is its own inverse, which is all the
-backward pass needs of it.
+backward pass needs of it. A partial rotary is the same pass over tables
+padded to the head (cos 1, sin 0 beyond r) with the two halves of the first r
+lanes swapped by two rotations and a select, zero beyond r, so that the swap
+stays its own inverse.
 
 A grid step takes `tile` positions of up to `_HEADS` heads: lane-aligned column
 blocks of x (d = 128 is one lane tile) in, whole (tile, d) planes of z out, so
@@ -42,49 +50,67 @@ _LANES = 128
 _HEADS = 8  # heads a grid step, where the head count allows
 
 
-def _rotate_half(x: Array) -> Array:
-    half = x.shape[-1] // 2
-    return jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+def _rotate_half(x: Array, rotary: int) -> Array:
+    """rotate-half within the first `rotary` dimensions, zero beyond."""
+    half = rotary // 2
+    return jnp.concatenate([-x[..., half:rotary], x[..., :half], jnp.zeros_like(x[..., rotary:])], axis=-1)
 
 
 def qk_norm_rope_dense(x: Array, weight: Array, cos: Array, sin: Array, heads: int, eps: float) -> Array:
     """The same prologue in `jax.numpy`, float32 between the input and the
     output: what the kernels are tested against."""
+    rotary = cos.shape[1]
     b, s, _ = x.shape
     x32 = x.astype(jnp.float32).reshape(b, s, heads, -1)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps) * weight
-    z = y * cos[None, :, None, :] + _rotate_half(y) * sin[None, :, None, :]
+    cos, sin = _padded(cos, sin, y.shape[-1])
+    z = y * cos[None, :, None, :] + _rotate_half(y, rotary) * sin[None, :, None, :]
     return z.transpose(0, 2, 1, 3).astype(x.dtype)
 
 
-def _signed(sin):
+def _padded(cos, sin, d):
+    """Tables of the first r dimensions as tables of the head: beyond r,
+    cos 1 and sin 0 pass a dimension through."""
+    if cos.shape[1] == d:  # graftlint: disable=GL002  (two static sizes: a whole-head rotary lowers as it did)
+        return cos, sin
+    beyond = [(0, 0), (0, d - cos.shape[1])]
+    return jnp.pad(cos, beyond, constant_values=1.0), jnp.pad(sin, beyond)
+
+
+def _signed(sin, rotary):
     """sin with rotate-half's sign folded in: -sin over the first half of
-    the lanes."""
+    the `rotary` lanes."""
     lane = jax.lax.broadcasted_iota(jnp.int32, sin.shape, 1)
-    return jnp.where(lane < sin.shape[1] // 2, -sin, sin)
+    return jnp.where(lane < rotary // 2, -sin, sin)
 
 
-def _swap_halves(x):
-    return pltpu.roll(x, x.shape[1] // 2, 1)
+def _swap_halves(x, rotary):
+    """The two halves of the first `rotary` lanes swapped; zero beyond."""
+    d = x.shape[1]
+    if rotary == d:  # graftlint: disable=GL002  (two static sizes: the tables' width and the head's)
+        return pltpu.roll(x, d // 2, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    swapped = jnp.where(lane < rotary // 2, pltpu.roll(x, d - rotary // 2, 1), pltpu.roll(x, rotary // 2, 1))
+    return jnp.where(lane < rotary, swapped, 0.0)
 
 
-def _fwd_kernel(x_ref, w_ref, cos_ref, sin_ref, z_ref, *, eps):
+def _fwd_kernel(x_ref, w_ref, cos_ref, sin_ref, z_ref, *, eps, rotary):
     d = w_ref.shape[1]
-    w, cos, sin = w_ref[...], cos_ref[...], _signed(sin_ref[...])
+    w, cos, sin = w_ref[...], cos_ref[...], _signed(sin_ref[...], rotary)
     for j in range(z_ref.shape[1]):
         x = x_ref[0, :, j * d:(j + 1) * d].astype(jnp.float32)
         y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
-        z_ref[0, j] = (y * cos + _swap_halves(y) * sin).astype(z_ref.dtype)
+        z_ref[0, j] = (y * cos + _swap_halves(y, rotary) * sin).astype(z_ref.dtype)
 
 
-def _bwd_kernel(dz_ref, x_ref, w_ref, cos_ref, sin_ref, dx_ref, dw_ref, *, eps):
+def _bwd_kernel(dz_ref, x_ref, w_ref, cos_ref, sin_ref, dx_ref, dw_ref, *, eps, rotary):
     d = w_ref.shape[1]
-    w, cos, sin = w_ref[...], cos_ref[...], _signed(sin_ref[...])
+    w, cos, sin = w_ref[...], cos_ref[...], _signed(sin_ref[...], rotary)
     dw = jnp.zeros((1, d), jnp.float32)
     for j in range(dz_ref.shape[1]):
         dz = dz_ref[0, j].astype(jnp.float32)
         x = x_ref[0, :, j * d:(j + 1) * d].astype(jnp.float32)
-        dy = dz * cos + _swap_halves(dz * sin)
+        dy = dz * cos + _swap_halves(dz * sin, rotary)
         inv_rms = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
         normed = x * inv_rms
         dw = dw + jnp.sum(dy * normed, axis=0, keepdims=True)
@@ -119,8 +145,10 @@ _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "par
 def _forward(x, weight, cos, sin, heads, eps, tile):
     grid, by_position, by_head, whole, table, _ = _plan(x, heads, tile)
     b, s, width = x.shape
+    rotary = cos.shape[1]
+    cos, sin = _padded(cos, sin, width // heads)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, eps=eps),
+        functools.partial(_fwd_kernel, eps=eps, rotary=rotary),
         grid=grid,
         in_specs=[by_position, whole, table, table],
         out_specs=by_head,
@@ -133,8 +161,10 @@ def _forward(x, weight, cos, sin, heads, eps, tile):
 
 def _backward(dz, x, weight, cos, sin, heads, eps, tile):
     grid, by_position, by_head, whole, table, partial_sum = _plan(x, heads, tile)
+    rotary = cos.shape[1]
+    cos, sin = _padded(cos, sin, weight.shape[0])
     dx, dw = pl.pallas_call(
-        functools.partial(_bwd_kernel, eps=eps),
+        functools.partial(_bwd_kernel, eps=eps, rotary=rotary),
         grid=grid,
         in_specs=[by_head, by_position, whole, table, table],
         out_specs=[by_position, partial_sum],
@@ -167,14 +197,16 @@ _prologue.defvjp(_prologue_fwd, _prologue_bwd)
 
 @scoped("qk_norm_rope")
 def qk_norm_rope(x: Array, weight: Array, cos: Array, sin: Array, heads: int, eps: float, tile: int = 512) -> Array:
-    """x: (B, S, heads x d); weight: (d,) float32; cos, sin: (S, d) float32
-    (`rotary_tables`). -> (B, heads, S, d) in x's dtype. Compiled for the
+    """x: (B, S, heads x d); weight: (d,) float32; cos, sin: (S, r) float32,
+    r <= d the rotary dimension (`rotary_tables` gives r = d).
+    -> (B, heads, S, d) in x's dtype. Compiled for the
     chip, d must be whole lane tiles (a multiple of 128): a head is a column
     block of x and a rotation of whole vector registers; the interpreter,
     which has no lane tiles, takes the CPU tests' narrow heads as well."""
     _, s, width = x.shape
-    d = weight.shape[0]
-    if width != heads * d or cos.shape != (s, d) or sin.shape != (s, d) or d % 2 or s % min(tile, s):
+    d, rotary = weight.shape[0], cos.shape[-1]
+    if (width != heads * d or cos.shape != (s, rotary) or sin.shape != (s, rotary) or rotary % 2
+            or not 0 < rotary <= d or s % min(tile, s)):
         raise ValueError(
             f"qk_norm_rope: x {x.shape}, weight {weight.shape}, tables {cos.shape} / {sin.shape}, {heads} heads, "
             f"tiles of {tile}")

@@ -5,7 +5,7 @@ run report) is shared as it is.
 
 `family_of(model_config, sample_shape)` picks the family by the type of
 `TrainConfig.model`; `sample_shape` is the shape of ONE sample: (H, W, C) of
-an image for the stereo family, (L,) tokens for the two token families.
+an image for the stereo family, (L,) tokens for the three token families.
 """
 
 from __future__ import annotations
@@ -15,7 +15,7 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from raft_stereo_tpu.config import GraniteHybridConfig, RAFTStereoConfig, SDARMoEConfig, TrainConfig
+from raft_stereo_tpu.config import GraniteHybridConfig, LagunaConfig, RAFTStereoConfig, SDARMoEConfig, TrainConfig
 
 Batch = Dict[str, jax.Array]
 # (params, batch_stats, batch) -> (loss, metrics)
@@ -98,6 +98,22 @@ class GraniteHybridFamily:
         return {"sample": [self.seq_len], "layer_types": list(config.model.layer_types)}
 
 
+class LagunaFamily(GraniteHybridFamily):
+    """The routed-expert decoder of mixed attention kinds on the plain causal
+    loss: the hybrid's batch, `tokens` (B, L) int32 alone
+    (models/laguna.py)."""
+
+    def init_variables(self, config: TrainConfig, rng: jax.Array):
+        from raft_stereo_tpu.models.laguna import init_laguna_variables
+
+        return init_laguna_variables(config.model, rng, self.seq_len)
+
+    def audit_meta(self, config: TrainConfig) -> Dict[str, Any]:
+        model = config.model
+        return {"sample": [self.seq_len], "layer_types": list(model.layer_types),
+                "mlp_layer_types": list(model.mlp_layer_types), "expert_parallel": model.expert_parallel}
+
+
 def _raft_stereo_loss(config: TrainConfig) -> LossFn:
     from raft_stereo_tpu.models import RAFTStereo
     from raft_stereo_tpu.train.loss import sequence_loss
@@ -142,6 +158,17 @@ def _granite_hybrid_loss(config: TrainConfig) -> LossFn:
     return loss
 
 
+def _laguna_loss(config: TrainConfig) -> LossFn:
+    from raft_stereo_tpu.models.laguna import Laguna
+
+    model = Laguna(config.model)
+
+    def loss(params, batch_stats, batch):
+        return model.apply({"params": params}, batch["tokens"], method="loss")
+
+    return loss
+
+
 def make_loss(config: TrainConfig) -> LossFn:
     """The loss of a batch for the family of `config.model`; needs no sample
     shape."""
@@ -151,6 +178,8 @@ def make_loss(config: TrainConfig) -> LossFn:
         return _sdar_moe_loss(config)
     if isinstance(config.model, GraniteHybridConfig):
         return _granite_hybrid_loss(config)
+    if isinstance(config.model, LagunaConfig):
+        return _laguna_loss(config)
     raise TypeError(f"no model family for a {type(config.model).__name__}")
 
 
@@ -161,4 +190,6 @@ def family_of(model_config, sample_shape: Tuple[int, ...]):
         return SDARMoEFamily(sample_shape, model_config.block_length)
     if isinstance(model_config, GraniteHybridConfig):
         return GraniteHybridFamily(sample_shape)
+    if isinstance(model_config, LagunaConfig):
+        return LagunaFamily(sample_shape)
     raise TypeError(f"no model family for a {type(model_config).__name__}")

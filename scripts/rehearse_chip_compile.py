@@ -19,6 +19,9 @@ Programs, at the sizes `chip_smoke.py` drives:
   train-lm[-L]      the hybrid family's step as the benchmark's cell runs it
             (benchmark/configs/granite-4.0-h-micro-pp4-stage.json, batch 1 x
             8192 tokens, or L tokens); not in the default set
+  train-laguna[-L[-B]]  the `laguna-moe` family's step as the benchmark's cell
+            runs it (benchmark/configs/laguna-xs.2-ep8-shard.json, batch 1 x
+            16384 tokens, or B x L); not in the default set
 
   JAX_PLATFORMS=cpu python scripts/rehearse_chip_compile.py [names...]
 
@@ -112,28 +115,24 @@ def serve(chip):
         _report(f"serve finalize 384x1248 b{batch}", finalize.lower(variables, state))
 
 
+def _published_model(file_name):
+    """A token family's model from its configuration's file, the family by
+    the file's `model_type`."""
+    import json
+
+    from raft_stereo_tpu.config import TOKEN_FAMILIES
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", file_name)) as f:
+        published = json.load(f)
+    return TOKEN_FAMILIES[published["model_type"]].from_hf_config(published, **published["program"])
+
+
 def _token_model(layers=None):
     import dataclasses
-    import json
 
-    from raft_stereo_tpu.config import SDARMoEConfig
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs", "sdar-30b-a3b-ep8-shard.json")) as f:
-        published = json.load(f)
-    model = SDARMoEConfig.from_hf_config(published, **published["program"])
+    model = _published_model("sdar-30b-a3b-ep8-shard.json")
     return dataclasses.replace(model, num_hidden_layers=layers) if layers else model
-
-
-def _hybrid_model():
-    import json
-
-    from raft_stereo_tpu.config import GraniteHybridConfig
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs", "granite-4.0-h-micro-pp4-stage.json")) as f:
-        published = json.load(f)
-    return GraniteHybridConfig.from_hf_config(published, **published["program"])
 
 
 def _train(name, devices, mesh_shape, batch, model=MODEL, sample=(320, 720, 3)):
@@ -204,16 +203,21 @@ def main(names):
             _train("train-tokens-dp (4,1) b8 x 4096", topo.devices, (4, 1), 8, _token_model(), (4096,))
         elif name.startswith("train-lm"):
             seq_len = int(name.split("-")[2]) if name.count("-") == 2 else 8192
-            model = _hybrid_model()
+            model = _published_model("granite-4.0-h-micro-pp4-stage.json")
             _train(f"train-lm b1 x {seq_len}, {model.num_hidden_layers} layers", topo.devices[:1], (1, 1), 1,
                    model, (seq_len,))
+        elif name.startswith("train-laguna"):
+            seq_len, batch = ([int(x) for x in name.split("-")[2:]] + [16384, 1])[:2] if name.count("-") > 1 else (16384, 1)
+            model = _published_model("laguna-xs.2-ep8-shard.json")
+            _train(f"train-laguna b{batch} x {seq_len}, {model.num_hidden_layers} layers", topo.devices[:1], (1, 1),
+                   batch, model, (seq_len,))
         elif name.startswith("train-tokens"):
             layers = int(name.split("-")[2]) if name.count("-") == 2 else None
             model = _token_model(layers)
             _train(f"train-tokens b4 x 4096, {model.num_hidden_layers} layers", topo.devices[:1], (1, 1), 4,
                    model, (4096,))
         else:
-            raise SystemExit(f"unknown program {name!r}; choose from {PROGRAMS}, train-tokens[-N] or train-lm[-L]")
+            raise SystemExit(f"unknown program {name!r}; choose from {PROGRAMS}, train-tokens[-N], train-lm[-L] or train-laguna[-L[-B]]")
 
 
 if __name__ == "__main__":

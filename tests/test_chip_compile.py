@@ -232,3 +232,41 @@ def test_head_prologue_compiles_at_the_token_cells_batch(v5e, heads):
     with pytest.raises(ValueError, match="qk_norm_rope: a head dimension of 64"):
         qk_norm_rope.qk_norm_rope(shape((4, 8192, heads * 64), jnp.bfloat16), shape((64,), jnp.float32),
                                   shape((8192, 64), jnp.float32), shape((8192, 64), jnp.float32), heads, 1e-6)
+
+
+@pytest.mark.parametrize("kind,heads", [("window", 64), ("full", 48)], ids=["window-group8", "full-group6"])
+def test_attention_compiles_at_the_laguna_cells_row(v5e, kind, heads):
+    """Both attention kinds of `laguna-xs2-train-causal-16k` at head 128 on 8
+    key-value heads, 16,384 positions in tiles of 512: the window entry (64
+    query heads, a window of 512: two key tiles a query tile) under its own
+    kernel names, the causal entry at a group of 6 (48 query heads)."""
+    from raft_stereo_tpu.ops import block_attention
+
+    q = jax.ShapeDtypeStruct((1, heads, 16384, 128), jnp.bfloat16, sharding=v5e)
+    kv = jax.ShapeDtypeStruct((1, 8, 16384, 128), jnp.bfloat16, sharding=v5e)
+    scale = 128 ** -0.5
+    if kind == "window":
+        attend = lambda q, k, v: block_attention.window_attention(q, k, v, 512, scale, 512)
+    else:
+        attend = lambda q, k, v: block_attention.causal_attention(q, k, v, scale, 512)
+    value = lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32))
+    text = _assert_kernel_compiled(jax.value_and_grad(value, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 3  # forward, dq, dk/dv
+    name = "window_attention" if kind == "window" else "block_attention"
+    other = "block_attention" if kind == "window" else "window_attention"
+    assert all(f"%{name}{suffix}" in text for suffix in (".", "_dq.", "_dkv.")) and f"%{other}" not in text
+
+
+def test_partial_rotary_prologue_compiles_at_the_laguna_cells_full_layer(v5e):
+    """`qk_norm_rope` and its VJP with a rotary over HALF the head (tables of
+    64 for heads of 128, YaRN's), 48 query heads, one row of 16,384
+    positions: the two rotations and the select lower, and no float32 array
+    of the operand's size appears beside the two calls."""
+    from raft_stereo_tpu.ops import qk_norm_rope
+
+    shape = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    x, weight, table = shape((1, 16384, 48 * 128), jnp.bfloat16), shape((128,), jnp.float32), shape((16384, 64), jnp.float32)
+    value = lambda x, w, cos, sin: jnp.sum(qk_norm_rope.qk_norm_rope(x, w, cos, sin, 48, 1e-6).astype(jnp.float32))
+    text = _assert_kernel_compiled(jax.value_and_grad(value, argnums=(0, 1)), x, weight, table, table)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2  # the prologue, its backward
+    assert "f32[1,16384,6144]" not in text and "f32[1,16384,48,128]" not in text

@@ -137,7 +137,19 @@ def seeded():
 def reference_gradient(seeded):
     """((loss, final-state rms), gradient) of the sound reference."""
     _, params, tokens = seeded
-    return jax.value_and_grad(lambda p: granite_reference.loss(PUBLISHED, p, {"tokens": tokens}), has_aux=True)(params)
+    return jax.jit(jax.value_and_grad(
+        lambda p: granite_reference.loss(PUBLISHED, p, {"tokens": tokens}), has_aux=True))(params)
+
+
+@pytest.fixture(scope="module")
+def program(seeded):
+    """The float32 model's two entries, each compiled once a module: logits
+    of (params, tokens), and the loss with its metrics and gradient."""
+    config, _, _ = seeded
+    model = granite_hybrid.GraniteHybrid(config)
+    logits = jax.jit(lambda p, tokens: model.apply({"params": p}, tokens))
+    graded = jax.jit(jax.value_and_grad(lambda p, tokens: model.apply({"params": p}, tokens, method="loss"), has_aux=True))
+    return logits, graded
 
 
 def test_program_and_reference_lay_the_weights_out_alike(seeded):
@@ -145,14 +157,12 @@ def test_program_and_reference_lay_the_weights_out_alike(seeded):
     assert {k: v.shape for k, v in flatten(params)} == dict(flatten(granite_reference.param_shapes(PUBLISHED)))
 
 
-def test_logits_loss_and_every_leafs_gradient_match_the_reference(seeded, reference_gradient):
-    config, params, tokens = seeded
-    model = granite_hybrid.GraniteHybrid(config)
-    logits = model.apply({"params": params}, tokens)
-    want_logits, want_rms = granite_reference.forward(PUBLISHED, params, tokens)
+def test_logits_loss_and_every_leafs_gradient_match_the_reference(seeded, reference_gradient, program):
+    _, params, tokens = seeded
+    logits = program[0](params, tokens)
+    want_logits, want_rms = jax.jit(lambda p: granite_reference.forward(PUBLISHED, p, tokens))(params)
     assert logits.shape == (2, SEQ, 96) and _close(logits, want_logits)
-    (loss, metrics), grads = jax.value_and_grad(
-        lambda p: model.apply({"params": p}, tokens, method="loss"), has_aux=True)(params)
+    (loss, metrics), grads = program[1](params, tokens)
     (want, rms), want_grads = reference_gradient
     assert abs(float(loss) - float(want)) < 1e-5 * float(want)
     assert abs(float(metrics["ssm_final_state_rms"]) - float(rms)) < 1e-5 * float(rms) and float(rms) > 0
@@ -161,24 +171,23 @@ def test_logits_loss_and_every_leafs_gradient_match_the_reference(seeded, refere
     assert sorted(got) == sorted(wanted) and all(_close(got[k], wanted[k], 5e-5) for k in wanted)
 
 
-def test_the_loss_reads_position_t_against_id_t_plus_1(seeded):
-    config, params, tokens = seeded
-    model = granite_hybrid.GraniteHybrid(config)
-    logits = model.apply({"params": params}, tokens)
+def test_the_loss_reads_position_t_against_id_t_plus_1(seeded, program):
+    _, params, tokens = seeded
+    logits = program[0](params, tokens)
     nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(logits, jnp.roll(tokens, -1, axis=1)[..., None], axis=-1)[..., 0]
-    loss, _ = model.apply({"params": params}, tokens, method="loss")
+    (loss, _), _ = program[1](params, tokens)
     assert abs(float(loss) - float(jnp.mean(nll[:, :-1]))) < 1e-5
     # causal: a later token changes no earlier position's logits
     later = tokens.at[:, -1].set((tokens[:, -1] + 1) % 96)
-    assert _close(model.apply({"params": params}, later)[:, :-1], logits[:, :-1], 1e-6)
+    assert _close(program[0](params, later)[:, :-1], logits[:, :-1], 1e-6)
 
 
 @pytest.mark.parametrize("fault,moves", [("chunk_reset", "state"), ("bidirectional_attention", "gradient")])
 def test_each_fault_of_the_reference_moves_its_number(seeded, reference_gradient, fault, moves):
     _, params, tokens = seeded
     (_, sound_rms), sound = reference_gradient
-    (_, wrong_rms), wrong = jax.value_and_grad(
-        lambda p: granite_reference.loss(PUBLISHED, p, {"tokens": tokens}, fault=fault), has_aux=True)(params)
+    (_, wrong_rms), wrong = jax.jit(jax.value_and_grad(
+        lambda p: granite_reference.loss(PUBLISHED, p, {"tokens": tokens}, fault=fault), has_aux=True))(params)
     if moves == "state":
         assert abs(float(wrong_rms) - float(sound_rms)) > 1e-2 * float(sound_rms)
     else:  # the sound program's gradient stands 5e-5 from the reference's, leaf by leaf
@@ -186,16 +195,17 @@ def test_each_fault_of_the_reference_moves_its_number(seeded, reference_gradient
         assert float(wrong_rms) != float(sound_rms)  # the layer after attention reads another stream
 
 
-def test_mixed_precision_keeps_the_decays_and_the_state_float32(seeded):
+def test_mixed_precision_keeps_the_decays_and_the_state_float32(seeded, reference_gradient):
     _, params, tokens = seeded
     model = granite_hybrid.GraniteHybrid(_config(mixed_precision=True))
-    text = jax.jit(lambda p: model.apply({"params": p}, tokens, method="loss")).lower(params).as_text()
+    mixed = jax.jit(lambda p: model.apply({"params": p}, tokens, method="loss"))
+    text = mixed.lower(params).as_text()
     assert "exponential" in text and "bf16" in text
     for line in text.splitlines():
         if "stablehlo.exponential" in line or "stablehlo.cumsum" in line or "stablehlo.log_plus_one" in line:
             assert "bf16" not in line, line  # softplus, running sums and every decay are float32
-    loss, metrics = model.apply({"params": params}, tokens, method="loss")
-    want, _ = granite_reference.loss(PUBLISHED, params, {"tokens": tokens})
+    loss, metrics = mixed(params)
+    want = reference_gradient[0][0]
     assert abs(float(loss) - float(want)) < 2e-2 * float(want) and metrics["ssm_final_state_rms"].dtype == jnp.float32
 
 
@@ -225,14 +235,33 @@ def _tiny_train_config(tmp_path, **kwargs):
                        checkpoint_dir=str(tmp_path / "ckpt"), log_dir=str(tmp_path / "logs"), **kwargs)
 
 
-def test_a_hybrid_steps_instructions_are_placed(tmp_path):
-    """A step's lowered instructions land in the family's rows of the ONE
-    table, in every phase the step has, and no other family's row takes one."""
+_STEP_METRICS = ("live_loss", "grad_norm", "ssm_final_state_rms")
+
+
+def _step_batch():
+    return {"tokens": np.asarray(jax.random.randint(jax.random.PRNGKey(2), (2, SEQ), 0, 96), np.int32)}
+
+
+@pytest.fixture(scope="module")
+def one_device_step(tmp_path_factory):
+    """The tiny family's one-device step, built and compiled once a module
+    (ahead of time, so the text read is the program that ran): (its
+    optimized text, its metrics on `_step_batch()`)."""
     from raft_stereo_tpu.obs import scopes
     from raft_stereo_tpu.train.trainer import Trainer
 
-    trainer = Trainer(_tiny_train_config(tmp_path), sample_shape=(SEQ,))
-    text = trainer.train_step.lower(scopes.abstract(trainer.state), trainer._abstract_batch()).compile().as_text()
+    trainer = Trainer(_tiny_train_config(tmp_path_factory.mktemp("one_device"), seed=3), sample_shape=(SEQ,))
+    step = trainer.train_step.lower(scopes.abstract(trainer.state), trainer._abstract_batch()).compile()
+    _, metrics = step(trainer.state, trainer.sharding.place_batch(_step_batch()))
+    return step.as_text(), tuple(float(metrics[k]) for k in _STEP_METRICS)
+
+
+def test_a_hybrid_steps_instructions_are_placed(one_device_step):
+    """A step's lowered instructions land in the family's rows of the ONE
+    table, in every phase the step has, and no other family's row takes one."""
+    from raft_stereo_tpu.obs import scopes
+
+    text = one_device_step[0]
     seen = {}
     for op_name, opcode in scopes.instruction_scopes(text).values():
         component, phase = scopes.component(op_name, opcode)
@@ -287,17 +316,26 @@ def test_the_new_rows_take_no_path_of_the_stereo_or_the_sdar_tables():
 
 
 def test_cmd_train_picks_the_family_by_model_type(tmp_path, monkeypatch):
+    """The dispatch, apart from the fit (`test_sdar_moe.py` drives one fit
+    through `cmd_train`; this family's goes through `cli.run_training` in
+    tests/benchmark/test_bench_granite.py): the file's `model_type` picks the
+    config class, `_token_trainer` hands the trainer that model, one sample's
+    shape and a loader of ids alone, and an unknown type ends `cmd_train`."""
     from raft_stereo_tpu import cli
+    from raft_stereo_tpu.train import trainer as trainer_module
     from raft_stereo_tpu.utils import run_report as rr
 
     path = tmp_path / "config.json"
     path.write_text(json.dumps(dict(PUBLISHED, program=dict(TILES, mixed_precision=True))))
+    args = cli._train_parser().parse_args(["--token_config", str(path), "--seq_len", str(SEQ), "--batch_size", "2"])
+    model = cli._token_model_config(args)
+    assert model == _config(mixed_precision=True)
+    monkeypatch.setattr(trainer_module, "Trainer", lambda config, sample_shape: (config.model, sample_shape))
+    (built_for, sample_shape), loader = cli._token_trainer(args, TrainConfig(model=model, batch_size=2))
+    assert built_for is model and sample_shape == (SEQ,)
+    first = next(iter(loader))
+    assert set(first) == {"tokens"} and first["tokens"].shape == (2, SEQ)
     monkeypatch.chdir(tmp_path)
-    code = cli.cmd_train(["--token_config", str(path), "--seq_len", str(SEQ), "--batch_size", "2", "--num_steps", "2",
-                          "--name", "hybrid", "--mesh_shape", "1", "1"])
-    assert code == rr.EXIT_OK
-    report = json.loads((tmp_path / "runs" / "run_report.json").read_text())
-    assert report["final_step"] == 2 and report["stop_cause"] == "completed"
     unknown = tmp_path / "unknown.json"
     unknown.write_text(json.dumps(dict(PUBLISHED, model_type="no_such_family")))
     assert cli.cmd_train(["--token_config", str(unknown), "--num_steps", "1"]) == rr.EXIT_ERROR
@@ -314,13 +352,10 @@ def test_token_batches_without_noise_and_the_familys_batch():
     assert {k: (v[0], np.dtype(v[1]).name) for k, v in shapes.items()} == {"tokens": ((2, SEQ), "int32")}
 
 
-def test_two_device_step_gives_the_one_device_steps_loss(tmp_path):
+def test_two_device_step_gives_the_one_device_steps_loss(tmp_path, one_device_step):
     from raft_stereo_tpu.train.trainer import Trainer
 
-    batch = {"tokens": np.asarray(jax.random.randint(jax.random.PRNGKey(2), (4, SEQ), 0, 96), np.int32)}
-    seen = []
-    for devices in (1, 2):
-        trainer = Trainer(_tiny_train_config(tmp_path / str(devices), mesh_shape=(devices, 1), seed=3), sample_shape=(SEQ,))
-        _, metrics = trainer.train_step(trainer.state, trainer.sharding.place_batch(batch))
-        seen.append((float(metrics["live_loss"]), float(metrics["grad_norm"]), float(metrics["ssm_final_state_rms"])))
+    trainer = Trainer(_tiny_train_config(tmp_path, mesh_shape=(2, 1), seed=3), sample_shape=(SEQ,))
+    _, metrics = trainer.train_step(trainer.state, trainer.sharding.place_batch(_step_batch()))
+    seen = [one_device_step[1], tuple(float(metrics[k]) for k in _STEP_METRICS)]
     assert all(abs(a - b) < 1e-4 * abs(a) for a, b in zip(*seen)), seen

@@ -315,7 +315,11 @@ def _token_kernel_calls():
     from raft_stereo_tpu.ops import qk_norm_rope as qk
 
     prologue = lambda x: qk.qk_norm_rope(x, jnp.ones((8,)), jnp.ones((32, 8)), jnp.zeros((32, 8)), 2, 1e-6).sum()
+    slide = lambda q, k, v: ba.window_attention(q, k, v, 4, 1.0, 8).sum()
     attention = {
+        "window_attention": lambda: slide(q, kv, kv),
+        "window_attention_dq": lambda: jax.grad(slide, 0)(q, kv, kv),
+        "window_attention_dkv": lambda: jax.grad(slide, 1)(q, kv, kv),
         "block_attention": lambda: attend(q, kv, kv),
         "block_attention_dq": lambda: jax.grad(attend, 0)(q, kv, kv),
         "block_attention_dkv": lambda: jax.grad(attend, 1)(q, kv, kv),
@@ -336,6 +340,7 @@ def _token_kernel_calls():
 
 KERNELS = [
     "block_attention", "block_attention_dq", "block_attention_dkv", "qk_norm_rope", "qk_norm_rope_bwd",
+    "window_attention", "window_attention_dq", "window_attention_dkv",
     "grouped_matmul", "grouped_matmul_drhs",
     "gather_rows", "scatter_add_rows", "ssd_chunk", "ssd_chunk_bwd",
     "corr_lookup", "corr_scatter", "corr_lookup_prefetch", "corr_pyramid", "encoder_conv_s2d",
@@ -371,6 +376,10 @@ def test_every_pallas_call_in_ops_is_named():
             assert found, f"a pl.pallas_call in {path} has no name="
             if found.group(2):
                 named.add(found.group(2))
+    # the attention kernels take theirs from the entry's mask: the module's table of them
+    from raft_stereo_tpu.ops import block_attention
+
+    named.update(name for names in block_attention.KERNEL_NAMES.values() for name in names)
     assert named == set(KERNELS)
 
 

@@ -66,14 +66,22 @@ def test_decoder_loss_gradients_and_logits_match_the_reference(block_length, sha
     batch = _batch(block_length)
     model = sdar_moe.SDARDecoder(config)
     loss = lambda p: model.apply({"params": p}, batch["tokens"], batch["masked"], batch["noise_t"], method="loss")
-    (got, aux), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
-    (want, held), want_grads = jax.value_and_grad(
-        lambda p: sdar_reference.loss(file_config, p, batch), has_aux=True)(params)
+
+    # one program a side: the loss with its gradient, and the logits
+    @jax.jit
+    def program(p):
+        return jax.value_and_grad(loss, has_aux=True)(p), model.apply({"params": p}, batch["tokens"], batch["masked"])
+
+    @jax.jit
+    def reference(p):
+        graded = jax.value_and_grad(lambda p: sdar_reference.loss(file_config, p, batch), has_aux=True)(p)
+        return graded, sdar_reference.forward(file_config, p, batch["tokens"], batch["masked"])
+
+    ((got, aux), grads), (logits, _) = program(params)
+    ((want, held), want_grads), (want_logits, _) = reference(params)
     assert abs(float(got) - float(want)) < 1e-5 * abs(float(want))
     assert float(aux["moe_held_rows"]) == float(held)  # no row dropped, none invented
     assert all(jax.tree.leaves(jax.tree.map(_close, grads, want_grads)))
-    logits, _ = jax.jit(lambda p: model.apply({"params": p}, batch["tokens"], batch["masked"]))(params)
-    want_logits, _ = sdar_reference.forward(file_config, params, batch["tokens"], batch["masked"])
     assert _close(logits, want_logits)
 
 
@@ -104,19 +112,20 @@ def test_the_shards_partial_results_add_up_to_the_uncut_layers(expert_parallel):
     m = jax.random.normal(jax.random.PRNGKey(4), (2 * 2 * SEQ, 64))
     want, want_rows = sdar_reference._experts(
         lambda x: x, whole_file, sdar_reference._dims(whole_file), first["experts"], first["router"], m, None)
-    total, rows = 0.0, 0
     held = 8 // expert_parallel
-    for shard in range(expert_parallel):
-        config, _ = _configs(4, expert_parallel, shard)
-        mine = jax.tree.map(lambda x: x[shard * held:(shard + 1) * held], first["experts"])
 
-        @jax.jit
-        def part(m):
+    @jax.jit
+    def parts(m):
+        out = []
+        for shard in range(expert_parallel):
+            config, _ = _configs(4, expert_parallel, shard)
+            mine = jax.tree.map(lambda x: x[shard * held:(shard + 1) * held], first["experts"])
             chosen, weights = sdar_moe.Router(config).apply({"params": first["router"]}, m)
-            return sdar_moe.Experts(config).apply({"params": mine}, m, chosen, weights)
+            out.append(sdar_moe.Experts(config).apply({"params": mine}, m, chosen, weights)[:2])
+        return out
 
-        y, counts, _ = part(m)
-        total, rows = total + y, rows + int(counts.sum())
+    found = parts(m)
+    total, rows = sum(y for y, _ in found), sum(int(counts.sum()) for _, counts in found)
     assert rows == int(want_rows) == m.shape[0] * 2
     assert _close(total, want)
 
@@ -138,7 +147,7 @@ def test_the_vocabulary_slices_logits_concatenate_to_the_uncut_heads(shards):
 
 
 @pytest.mark.parametrize("seq,block,tile,heads", [(32, 4, 16, (4, 2)), (32, 16, 16, (4, 2)), (32, 4, 32, (2, 2)),
-                                                  (64, 8, 16, (8, 1))])
+                                                  (32, 4, 8, (8, 1))])
 def test_block_attention_forward_and_backward_match_the_dense_form(seq, block, tile, heads):
     hq, hkv = heads
     keys = jax.random.split(jax.random.PRNGKey(7), 4)
@@ -148,8 +157,9 @@ def test_block_attention_forward_and_backward_match_the_dense_form(seq, block, t
     w = jax.random.normal(keys[3], q.shape)
     kernel = lambda q, k, v: (ba.block_attention(q, k, v, seq, block, tile) * w).sum()
     dense = lambda q, k, v: (ba.block_attention_dense(q, k, v, seq, block) * w).sum()
-    assert _close(ba.block_attention(q, k, v, seq, block, tile), ba.block_attention_dense(q, k, v, seq, block))
-    got, want = jax.grad(kernel, (0, 1, 2))(q, k, v), jax.grad(dense, (0, 1, 2))(q, k, v)
+    forward = jax.jit(lambda q, k, v: (ba.block_attention(q, k, v, seq, block, tile), ba.block_attention_dense(q, k, v, seq, block)))
+    assert _close(*forward(q, k, v))
+    got, want = jax.jit(jax.grad(kernel, (0, 1, 2)))(q, k, v), jax.jit(jax.grad(dense, (0, 1, 2)))(q, k, v)
     assert all(_close(a, b) for a, b in zip(got, want))
 
 
@@ -357,7 +367,7 @@ def _expert_layer_values(config, devices):
     probs, chosen = jax.lax.top_k(jax.nn.softmax(jax.random.normal(keys[1], (4 * SEQ, 8))), 2)
     g = jax.random.normal(keys[2], m.shape)
     experts = sdar_moe.Experts(config)
-    params = experts.init(keys[3], m, chosen, probs)["params"]
+    params = jax.jit(experts.init)(keys[3], m, chosen, probs)["params"]
 
     def value(params, m, weights):
         y, counts, live = experts.apply({"params": params}, m, chosen.astype(jnp.int32), weights)
@@ -393,8 +403,8 @@ def test_live_row_share_counts_the_live_tiles_of_a_hand_made_routing():
     for moe_chunk, want in ((4096, 11 / 20), (32, 7 / 12)):
         config, _ = _configs(4, 2, 1, moe_chunk=moe_chunk)
         experts = sdar_moe.Experts(config)
-        params = experts.init(jax.random.PRNGKey(17), m, chosen, weights)["params"]
-        _, counts, live = experts.apply({"params": params}, m, chosen, weights)
+        params = jax.jit(experts.init)(jax.random.PRNGKey(17), m, chosen, weights)["params"]
+        _, counts, live = jax.jit(experts.apply)({"params": params}, m, chosen, weights)
         assert list(np.asarray(counts)) == [2 * SEQ, 0, 0, 0] and abs(float(live) - want) < 1e-6
     layout = gm.group_layout(jnp.asarray([0, 4] * (2 * SEQ), jnp.int32), 4, 8)
     assert int(layout["num_tiles"][0]) * 8 / layout["row_live"].shape[0] == 11 / 20
@@ -493,33 +503,43 @@ def test_token_batch_and_state_rules():
     assert "tokens" in dp.explain(batch_template=template)
 
 
-@pytest.mark.parametrize("preset", ["dp", "fsdp"])
-def test_two_device_step_gives_the_one_device_steps_loss(tmp_path, preset):
+_STEP_METRICS = ("live_loss", "grad_norm", "moe_held_rows", "moe_live_row_share")
+
+
+@pytest.fixture(scope="module")
+def one_device_step(tmp_path_factory):
+    """The tiny family's one-device step, built and compiled once a module
+    (ahead of time, so the text read is the program that ran): (its
+    optimized text, its metrics on `_batch(4)`)."""
+    from raft_stereo_tpu.obs import scopes
     from raft_stereo_tpu.train.trainer import Trainer
 
-    batch = jax.tree.map(np.asarray, _batch(4))
-    losses = []
-    for mesh_shape, rules in (((1, 1), "dp"), ((2, 1), preset)):
-        trainer = Trainer(_tiny_train_config(tmp_path / rules / str(mesh_shape[0]), mesh_shape=mesh_shape,
-                                             sharding_rules=rules, seed=3), sample_shape=(SEQ,))
-        state, metrics = trainer.train_step(trainer.state, trainer.sharding.place_batch(batch))
-        losses.append((float(metrics["live_loss"]), float(metrics["grad_norm"]), float(metrics["moe_held_rows"]),
-                       float(metrics["moe_live_row_share"])))
+    trainer = Trainer(_tiny_train_config(tmp_path_factory.mktemp("one_device"), seed=3), sample_shape=(SEQ,))
+    step = trainer.train_step.lower(scopes.abstract(trainer.state), trainer._abstract_batch()).compile()
+    _, metrics = step(trainer.state, trainer.sharding.place_batch(jax.tree.map(np.asarray, _batch(4))))
+    return step.as_text(), tuple(float(metrics[k]) for k in _STEP_METRICS)
+
+
+@pytest.mark.parametrize("preset", ["dp", "fsdp"])
+def test_two_device_step_gives_the_one_device_steps_loss(tmp_path, one_device_step, preset):
+    from raft_stereo_tpu.train.trainer import Trainer
+
+    trainer = Trainer(_tiny_train_config(tmp_path, mesh_shape=(2, 1), sharding_rules=preset, seed=3), sample_shape=(SEQ,))
+    _, metrics = trainer.train_step(trainer.state, trainer.sharding.place_batch(jax.tree.map(np.asarray, _batch(4))))
+    losses = [one_device_step[1], tuple(float(metrics[k]) for k in _STEP_METRICS)]
     assert losses[0][2] == losses[1][2] and 0.0 < losses[0][3] == losses[1][3] <= 1.0
     assert abs(losses[0][0] - losses[1][0]) < 1e-5 and abs(losses[0][1] - losses[1][1]) < 1e-4
 
 
-def test_token_steps_instructions_are_placed(tmp_path):
+def test_token_steps_instructions_are_placed(one_device_step):
     """A token step's lowered instructions land in the family's rows of the
     ONE table, in every phase the step has; what the model wrote and no row
     takes is the layer scan's own plumbing. (Interpreted kernels print their
     bodies as nameless calls here; on the chip a kernel is one named custom
     call: PERF.md section 5 has the chip's shares.)"""
     from raft_stereo_tpu.obs import scopes
-    from raft_stereo_tpu.train.trainer import Trainer
 
-    trainer = Trainer(_tiny_train_config(tmp_path), sample_shape=(SEQ,))
-    text = trainer.train_step.lower(scopes.abstract(trainer.state), trainer._abstract_batch()).compile().as_text()
+    text = one_device_step[0]
     seen = {}
     for op_name, opcode in scopes.instruction_scopes(text).values():
         component, phase = scopes.component(op_name, opcode)
