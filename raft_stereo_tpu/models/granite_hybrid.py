@@ -40,7 +40,7 @@ import jax.numpy as jnp
 
 from raft_stereo_tpu.config import GraniteHybridConfig
 from raft_stereo_tpu.models.sdar_moe import _DENSE_INIT, RMSNorm, _matmul, chunked_loss_sum
-from raft_stereo_tpu.ops.block_attention import causal_attention
+from raft_stereo_tpu.ops.block_attention import causal_attention, interior_pair_share
 from raft_stereo_tpu.ops.ssd_scan import ssd_scan
 
 Array = jax.Array
@@ -211,7 +211,11 @@ class GraniteHybrid(nn.Module):
             total = chunked_loss_sum(
                 h.reshape(b * seq_len, -1), self.embed.embedding.T, targets.reshape(-1), weights.reshape(-1),
                 cfg.loss_chunk, "next_token_loss", 1.0 / cfg.logits_scaling)
-        return total, {"ssm_final_state_rms": state_rms}
+        return total, {
+            "ssm_final_state_rms": state_rms,
+            # tile pairs the attention backward kernels run without their mask test, of those they visit
+            "attn_interior_pair_share": jnp.float32(interior_pair_share(seq_len, tile=cfg.attention_tile)),
+        }
 
 
 @functools.lru_cache(maxsize=8)

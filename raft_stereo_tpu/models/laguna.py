@@ -51,7 +51,7 @@ import numpy as np
 from raft_stereo_tpu.config import LagunaConfig
 from raft_stereo_tpu.models.sdar_moe import (
     _DENSE_INIT, Experts, HeadNormWeight, LMHead, RMSNorm, _matmul, chunked_loss_sum)
-from raft_stereo_tpu.ops.block_attention import causal_attention, window_attention
+from raft_stereo_tpu.ops.block_attention import causal_attention, interior_pair_share, window_attention
 from raft_stereo_tpu.ops.qk_norm_rope import qk_norm_rope
 
 Array = jax.Array
@@ -236,6 +236,10 @@ class Laguna(nn.Module):
             "moe_held_rows": jnp.sum(counts),
             "moe_max_over_mean_load": jnp.mean(load),
             "moe_live_row_share": jnp.mean(live),
+            # tile pairs the attention backward kernels run without their mask test, of those they visit, by layer kind
+            "attn_interior_pair_share": jnp.float32(interior_pair_share(seq_len, tile=cfg.attention_tile)),
+            "attn_window_interior_pair_share": jnp.float32(
+                interior_pair_share(seq_len, window=cfg.sliding_window, tile=cfg.attention_tile)),
             # a gate that saturates at 0 or 1 is the first thing to look for
             "attn_gate_mean": gate_mean,
         }

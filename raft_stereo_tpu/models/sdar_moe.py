@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from raft_stereo_tpu.config import SDARMoEConfig
-from raft_stereo_tpu.ops.block_attention import block_attention
+from raft_stereo_tpu.ops.block_attention import block_attention, interior_pair_share
 from raft_stereo_tpu.ops.data_axis import over_data_axis
 from raft_stereo_tpu.ops.grouped_matmul import group_layout, grouped_matmul, swiglu_rows
 from raft_stereo_tpu.ops.qk_norm_rope import qk_norm_rope
@@ -401,6 +401,8 @@ class SDARDecoder(nn.Module):
             # rows of the live tiles over the rows of the buffers they lie in:
             # what the copies of `ops/tile_rows.py` touch of the worst case
             "moe_live_row_share": jnp.mean(live),
+            # tile pairs the attention backward kernels run without their mask test, of those they visit
+            "attn_interior_pair_share": jnp.float32(interior_pair_share(seq_len, cfg.block_length, tile=cfg.attention_tile)),
             "masked_tokens": jnp.sum(masked.astype(jnp.float32)),
         }
 

@@ -18,7 +18,16 @@ visited: for a query tile the grid's last axis walks only the clean key tiles
 up to its own and, for a noised tile, its own noised tile (`_fwd_key_tile`);
 for a key tile only the query tiles at or after it (`_bwd_query_tile`). A
 step past a tile's last visible partner keeps the previous block index, so
-nothing is copied for it, and computes nothing.
+nothing is copied for it, and computes nothing. Of the visited pairs, those
+the walk knows from the two tile indices to be wholly visible (`interior`;
+tiles hold whole blocks) run a body with no mask arithmetic in the dq and
+dk/dv kernels: a clean key tile before the query tile's own half-index under
+the block-diffusion mask, a key tile before the query tile under the causal
+one, and under a window an earlier key tile the query tile's last query
+still reaches (none where the window is under two tiles: those kernels hold
+the masked body only). The rest, the diagonal and the window's edge, test
+their codes, and so does the forward on every pair: its body is slower
+without the test than with it (PERF.md section 6, PR 37).
 
 Grouped-query: `q` has G = Hq / Hkv heads a key-value head; the forward and
 dq index k/v by `h // G`, dk/dv sum their group inside the kernel. Scores and
@@ -186,6 +195,12 @@ class _BlockWalk(NamedTuple):
     def bwd_query_tile(self, kt, u):
         return _bwd_query_tile(kt, u, self.nh)
 
+    def interior(self, qt, kt):
+        """Every query of tile `qt` sees every key of tile `kt`: a clean key
+        tile before the query tile's own half-index (`q_lim` is the block or
+        the block before, and an earlier tile's blocks lie under both)."""
+        return (kt >= self.nh) & (kt - self.nh < jnp.where(qt < self.nh, qt, qt - self.nh))
+
 
 class _CausalWalk(NamedTuple):
     """A causal row of `nt` tiles: query tile qt sees key tiles 0..qt, key
@@ -212,19 +227,28 @@ class _CausalWalk(NamedTuple):
     def bwd_query_tile(self, kt, u):
         return kt + jnp.minimum(u, self.nt - kt - 1)
 
+    def interior(self, qt, kt):
+        return kt < qt
+
 
 class _WindowWalk(NamedTuple):
-    """A causal row of `nt` tiles under a window that reaches `reach` tiles
-    back: query tile qt sees key tiles max(0, qt - reach)..qt, key tile kt is
-    seen by query tiles kt..min(nt - 1, kt + reach)."""
+    """A causal row of `nt` tiles of `tile` positions under a window of
+    `window` keys, which reaches `reach` tiles back: query tile qt sees key
+    tiles max(0, qt - reach)..qt, key tile kt is seen by query tiles
+    kt..min(nt - 1, kt + reach)."""
 
     nt: int
-    reach: int
+    tile: int
+    window: int
     visible = staticmethod(_in_window)
 
     @property
     def tiles(self):
         return self.nt
+
+    @property
+    def reach(self):
+        return -(-(self.window - 1) // self.tile)
 
     @property
     def fwd_max(self):
@@ -243,6 +267,14 @@ class _WindowWalk(NamedTuple):
 
     def bwd_query_tile(self, kt, u):
         return kt + jnp.minimum(u, jnp.minimum(self.reach, self.nt - 1 - kt))
+
+    def interior(self, qt, kt):
+        """An earlier tile whose first key the query tile's last query still
+        sees. A window under two tiles holds none: the Python `False` tells
+        the kernels so while they are traced."""
+        if self.window < 2 * self.tile:
+            return False
+        return (kt < qt) & ((qt - kt + 1) * self.tile <= self.window)
 
 
 class _Mask(NamedTuple):
@@ -272,17 +304,48 @@ class _Mask(NamedTuple):
     def walk(self, tile: int):
         t = _tile(self.seq_len, self.block_length or 1, tile)
         if self.window:
-            return t, _WindowWalk(self.seq_len // t, -(-(self.window - 1) // t))
+            return t, _WindowWalk(self.seq_len // t, t, self.window)
         return t, (_BlockWalk if self.block_length else _CausalWalk)(self.seq_len // t)
 
 
-def _scores(a, b, q_lim, q_eq, k_code, scale, visible):
-    """Masked scores a @ b.T (queries down and keys across, or the other way
-    round: the codes broadcast to whichever it is) and the mask, by the
-    walk's test."""
-    s = jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32) * scale
-    mask = visible(q_lim, q_eq, k_code)
-    return jnp.where(mask, s, _NEG), mask
+@functools.lru_cache(maxsize=None)
+def interior_pair_share(seq_len: int, block_length: int = 0, window: int = 0, tile: int = 512) -> float:
+    """Wholly visible tile pairs over visited tile pairs of one head's walk
+    (the three kernels visit the same pairs): how often dq and dk/dv run the
+    body without the mask. The arguments are `_Mask`'s and the entries'
+    `tile`; a constant from shapes, also where it is called under a trace."""
+    _, walk = _Mask(seq_len, block_length, window).walk(tile)
+    with jax.ensure_compile_time_eval():
+        qt, s = jnp.arange(walk.tiles)[:, None], jnp.arange(walk.fwd_max)[None, :]
+        live = s < walk.fwd_steps(qt)
+        interior = live & walk.interior(qt, walk.fwd_key_tile(qt, s))
+        return int(interior.sum()) / int(live.sum())
+
+
+def _scores(a, b, scale):
+    """Scores a @ b.T: queries down and keys across, or the other way round."""
+    return jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32) * scale
+
+
+def _by_pair(walk, live, qt, kt, code_refs, body):
+    """Run `body(mask)` on a live step: with the walk's test of the tiles'
+    codes (they broadcast to whichever way round the scores are), or with
+    None where the walk knows from the two tile indices alone that every
+    entry passes it. A dead step runs neither."""
+    masked = lambda: body(walk.visible(*(ref[...] for ref in code_refs)))
+    interior = walk.interior(qt, kt)
+    if interior is False:
+        pl.when(live)(masked)
+        return
+    pl.when(live & interior)(lambda: body(None))
+    pl.when(live & jnp.logical_not(interior))(masked)
+
+
+def _probs(sc, lse, mask):
+    """The backward kernels' rebuilt softmax of the scores `sc`."""
+    if mask is None:
+        return jnp.exp(sc - lse)
+    return jnp.where(mask, jnp.exp(jnp.where(mask, sc, _NEG) - lse), 0.0)
 
 
 def _row(column):
@@ -309,12 +372,20 @@ def _fwd_kernel(ql_ref, qe_ref, kc_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_s
 
     @pl.when(s < walk.fwd_steps(qt))
     def _():
+        # One select, on every pair: a hidden score is _NEG, and exp(_NEG - m)
+        # is 0 under any real maximum m. A row that has seen nothing yet has
+        # m = _NEG and gathers exp(0) = 1 for its hidden keys, finite sums
+        # that alpha = exp(_NEG - m) = 0 wipes at the row's first real score
+        # (every query sees itself). Zeroing p by a second select costs an
+        # eighth of the call and a body without the mask more still
+        # (PERF.md section 6, PR 37), so the forward takes no `_by_pair`.
         v = v_ref[0, 0]
-        sc, mask = _scores(q_ref[0, 0], k_ref[0, 0], ql_ref[...], qe_ref[...], kc_ref[...], scale, walk.visible)
+        sc = _scores(q_ref[0, 0], k_ref[0, 0], scale)
+        sc = jnp.where(walk.visible(ql_ref[...], qe_ref[...], kc_ref[...]), sc, _NEG)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
+        p = jnp.exp(sc - m_new)
         l_scr[...] = alpha * l_scr[...] + p.sum(axis=1, keepdims=True)
         acc_scr[...] = alpha * acc_scr[...] + jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -338,14 +409,14 @@ def _dq_kernel(
         lse_scr[...] = _column(lse_ref[0, 0])
         delta_scr[...] = _column(delta_ref[0, 0])
 
-    @pl.when(s < walk.fwd_steps(qt))
-    def _():
+    def pair(mask):
         k, v, do = k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
-        sc, mask = _scores(q_ref[0, 0], k, ql_ref[...], qe_ref[...], kc_ref[...], scale, walk.visible)
-        p = jnp.where(mask, jnp.exp(sc - lse_scr[...]), 0.0)
+        p = _probs(_scores(q_ref[0, 0], k, scale), lse_scr[...], mask)
         dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
         ds = p * (dp - delta_scr[...]) * scale
         acc_scr[...] += jnp.dot(ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
+
+    _by_pair(walk, s < walk.fwd_steps(qt), qt, walk.fwd_key_tile(qt, s), (ql_ref, qe_ref, kc_ref), pair)
 
     @pl.when(s == pl.num_programs(3) - 1)
     def _():
@@ -363,17 +434,18 @@ def _dkv_kernel(
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(step % walk.bwd_max < walk.bwd_steps(kt))
-    def _():
+    def pair(mask):
         # Keys down, queries across: a query's statistics are rows, and both
         # accumulations are plain products.
         q, v, do = q_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
-        sc, mask = _scores(k_ref[0, 0], q, ql_ref[...], qe_ref[...], kc_ref[...], scale, walk.visible)
-        p = jnp.where(mask, jnp.exp(sc - lse_ref[0, 0]), 0.0)
+        p = _probs(_scores(k_ref[0, 0], q, scale), lse_ref[0, 0], mask)
         dv_scr[...] += jnp.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
         ds = p * (dp - delta_ref[0, 0]) * scale
         dk_scr[...] += jnp.dot(ds.astype(q.dtype), q, preferred_element_type=jnp.float32)
+
+    u = step % walk.bwd_max
+    _by_pair(walk, u < walk.bwd_steps(kt), walk.bwd_query_tile(kt, u), kt, (ql_ref, qe_ref, kc_ref), pair)
 
     @pl.when(step == pl.num_programs(3) - 1)
     def _():

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import walk_checks
 from benchmark import granite_reference
 from benchmark.weights import flatten
 from raft_stereo_tpu.config import GraniteHybridConfig, TrainConfig
@@ -122,6 +123,31 @@ def test_the_causal_walk_visits_the_visible_tiles_and_no_other():
         ba.causal_attention(jnp.ones((1, 2, 24, 8)), jnp.ones((1, 1, 24, 8)), jnp.ones((1, 1, 24, 8)), 1.0, 16)
 
 
+@pytest.mark.parametrize("seq,tile", [(64, 16), (64, 8), (32, 32), (8192, 512)],
+                         ids=["four-tiles", "eight-tiles", "one-tile", "the-cell"])
+def test_the_causal_walk_calls_interior_the_tiles_under_the_diagonal_and_no_other(seq, tile):
+    t, walk = ba._Mask(seq, 0).walk(tile)
+    nt = seq // t
+    interior, visited = walk_checks.interior_pairs(np.tril(np.ones((seq, seq), bool)), walk, t, every=True)
+    assert (interior, visited) == (nt * (nt - 1) // 2, nt * (nt + 1) // 2)
+    assert ba.interior_pair_share(seq, tile=tile) == interior / visited
+
+
+def test_the_cells_interior_pair_share_is_120_of_136():
+    assert ba.interior_pair_share(8192) == 120 / 136 == jax.jit(lambda: ba.interior_pair_share(8192, 0, 0, 512))()
+
+
+@pytest.mark.parametrize("tile,heads", [(16, (4, 2)), (8, (6, 1))], ids=["four-tiles-group2", "eight-tiles-group6"])
+def test_causal_attention_without_the_unmasked_body_keeps_every_bit(monkeypatch, tile, heads):
+    """The scale is a power of two, as 1 / sqrt(d) is in the block and window
+    cases: XLA's CPU code contracts `s * scale - m` into one fused
+    multiply-add where no `where` stands between the two, which rounds once;
+    an exact product rounds alike both ways. The interpreter's backend, not
+    the kernels' arithmetic: the chip is held to any scale (PERF.md, PR 37)."""
+    walk_checks.never_interior_keeps_the_bits(
+        monkeypatch, ba._CausalWalk, lambda q, k, v: ba.causal_attention(q, k, v, 0.25, tile), heads, 64, 64)
+
+
 # -- the model against the reference -----------------------------------------------------------
 
 
@@ -167,6 +193,7 @@ def test_logits_loss_and_every_leafs_gradient_match_the_reference(seeded, refere
     assert abs(float(loss) - float(want)) < 1e-5 * float(want)
     assert abs(float(metrics["ssm_final_state_rms"]) - float(rms)) < 1e-5 * float(rms) and float(rms) > 0
     assert abs(float(rms) - float(want_rms)) < 1e-6 * float(rms)
+    assert float(metrics["attn_interior_pair_share"]) == np.float32(10 / 15)  # five tiles: the pairs under the diagonal
     got, wanted = dict(flatten(grads)), dict(flatten(want_grads))
     assert sorted(got) == sorted(wanted) and all(_close(got[k], wanted[k], 5e-5) for k in wanted)
 

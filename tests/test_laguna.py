@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import walk_checks
 from benchmark import laguna_reference
 from benchmark.reference import rounder
 from benchmark.weights import flatten
@@ -106,6 +107,36 @@ def test_the_window_walk_visits_the_tiles_the_window_reaches_and_no_other():
     assert ba._Mask(SEQ, 0, 8).kernels[0] == "window_attention" and ba._Mask(SEQ, 0).kernels[0] == "block_attention"
     with pytest.raises(ValueError):
         ba.window_attention(jnp.zeros((1, 2, SEQ, 16)), jnp.zeros((1, 2, SEQ, 16)), jnp.zeros((1, 2, SEQ, 16)), 0, 1.0, 8)
+
+
+@pytest.mark.parametrize("seq,window,tile", [(80, 40, 16), (64, 16, 16), (64, 32, 16), (64, 33, 16), (40, 20, 8),
+                                             (40, 100, 8), (40, 3, 8), (2048, 512, 512)],
+                         ids=["over-two-tiles", "a-tile", "two-tiles", "two-tiles-and-one", "two-and-a-half", "over-the-row",
+                              "under-a-tile", "the-cells-window"])
+def test_the_window_walk_calls_interior_the_tiles_wholly_inside_the_window_and_no_other(seq, window, tile):
+    """Against the dense mask, from the query side and from the key side. A
+    window under two tiles holds no whole tile but a query's own, which the
+    causal edge cuts: the walk says so before any index is known."""
+    t, walk = ba._Mask(seq, 0, window).walk(tile)
+    pos = np.arange(seq)
+    mask = ba._in_window(pos[:, None], pos[:, None] - window + 1, pos[None, :])
+    interior, visited = walk_checks.interior_pairs(mask, walk, t, every=True)
+    nt, whole = seq // t, max(window // t - 1, 0)  # whole tiles before a query tile's own that its last query still reaches
+    assert visited == sum(min(qt, walk.reach) + 1 for qt in range(nt))
+    assert interior == sum(min(qt, whole) for qt in range(nt)) and (whole > 0 or walk.interior(1, 0) is False)
+    assert ba.interior_pair_share(seq, 0, window, tile) == interior / visited
+
+
+def test_the_cells_interior_pair_shares_are_496_of_528_and_none():
+    assert ba.interior_pair_share(16384) == 496 / 528 and ba.interior_pair_share(16384, window=512) == 0.0
+    assert jax.jit(lambda: ba.interior_pair_share(16384, 0, 0, 512))() == np.float32(496 / 528)
+
+
+@pytest.mark.parametrize("window,heads", [(20, (12, 2)), (16, (6, 1)), (100, (16, 2))],
+                         ids=["two-and-a-half-group6", "two-tiles-group6", "over-the-row-group8"])
+def test_window_attention_without_the_unmasked_body_keeps_every_bit(monkeypatch, window, heads):
+    walk_checks.never_interior_keeps_the_bits(
+        monkeypatch, ba._WindowWalk, lambda q, k, v: ba.window_attention(q, k, v, window, 0.25, 8), heads, SEQ, 16)
 
 
 # -- the partial and scaled rotary ----------------------------------------------------------
@@ -208,6 +239,9 @@ def test_logits_loss_and_every_leafs_gradient_match_the_reference(seeded, refere
     assert float(metrics["moe_held_rows"]) == float(held) == float(want_held)
     assert abs(float(metrics["attn_gate_mean"]) - float(gate_mean)) < 1e-5 and 0.3 < float(gate_mean) < 0.7
     assert 0 < float(metrics["moe_live_row_share"]) <= 1 and float(metrics["moe_max_over_mean_load"]) >= 1
+    # five tiles: a full layer's pairs under the diagonal; a window of one tile holds no whole tile
+    assert float(metrics["attn_interior_pair_share"]) == np.float32(10 / 15)
+    assert float(metrics["attn_window_interior_pair_share"]) == 0.0
     got, wanted = dict(flatten(grads)), dict(flatten(want_grads))
     assert sorted(got) == sorted(wanted) and all(_close(got[k], wanted[k], 5e-5) for k in wanted)
 

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import walk_checks
 from benchmark import sdar_reference
 from raft_stereo_tpu.config import RAFTStereoConfig, SDARMoEConfig, TrainConfig
 from raft_stereo_tpu.models import sdar_moe
@@ -81,6 +82,7 @@ def test_decoder_loss_gradients_and_logits_match_the_reference(block_length, sha
     ((want, held), want_grads), (want_logits, _) = reference(params)
     assert abs(float(got) - float(want)) < 1e-5 * abs(float(want))
     assert float(aux["moe_held_rows"]) == float(held)  # no row dropped, none invented
+    assert float(aux["attn_interior_pair_share"]) == 2 / 8  # two tiles a half: the two clean pasts of eight pairs
     assert all(jax.tree.leaves(jax.tree.map(_close, grads, want_grads)))
     assert _close(logits, want_logits)
 
@@ -190,6 +192,29 @@ def test_tiles_no_query_can_see_are_never_visited():
         steps = int(ba._bwd_steps(jnp.int32(kt), nh)[1])
         visited = {int(ba._bwd_query_tile(jnp.int32(kt), jnp.int32(u), nh)) for u in range(steps)}
         assert visited == set(np.flatnonzero(mask[:, kt])), kt
+
+
+@pytest.mark.parametrize("seq,block,tile", [(64, 4, 16), (64, 8, 32), (32, 4, 8), (32, 16, 16), (4096, 4, 512)],
+                         ids=["four-tiles", "two-tiles", "eight-tiles", "a-block-a-tile", "the-cell"])
+def test_the_block_walk_calls_interior_the_wholly_visible_pairs_and_no_other(seq, block, tile):
+    """Against the dense mask, from the query side and from the key side;
+    where a tile is one block its diagonal pairs are wholly visible too and
+    stay with the masked body."""
+    t, walk = ba._Mask(seq, block).walk(tile)
+    nh = seq // t
+    interior, visited = walk_checks.interior_pairs(np.asarray(ba.block_mask(seq, block)), walk, t, every=t > block)
+    assert (interior, visited) == (nh * (nh - 1), nh * (nh + 2))
+    assert ba.interior_pair_share(seq, block, tile=tile) == interior / visited
+
+
+def test_the_cells_interior_pair_share_is_56_of_80():
+    assert ba.interior_pair_share(4096, 4) == 56 / 80 == jax.jit(lambda: ba.interior_pair_share(4096, 4, 0, 512))()
+
+
+@pytest.mark.parametrize("seq,block,tile,heads", [(32, 4, 16, (4, 2)), (32, 4, 8, (8, 1)), (64, 16, 16, (2, 2))])
+def test_block_attention_without_the_unmasked_body_keeps_every_bit(monkeypatch, seq, block, tile, heads):
+    walk_checks.never_interior_keeps_the_bits(
+        monkeypatch, ba._BlockWalk, lambda q, k, v: ba.block_attention(q, k, v, seq, block, tile), heads, 2 * seq, 16)
 
 
 # -- grouped_matmul --------------------------------------------------------------------
