@@ -36,9 +36,9 @@ sublayer count with it. The `granite-hybrid` family
 `layers_<i>/{ssm_norm,mixer,input_norm,attention,mlp_norm,mlp}`, `norm`, and
 inside `mixer` the scopes `ssm_proj` (both projections; the layer's norm and
 residual count with it), `ssm_conv` (the causal convolution, `silu`, the
-split and `dt`'s softplus), `ssm_scan` (ops/ssd_scan.py: the kernels
-`ssd_chunk` / `ssd_chunk_bwd`, the running sums, the recurrence over the
-chunks) and `ssm_gate_norm`; `block_attention` under `attention`; `lm_head`
+split and `dt`'s softplus), `ssm_scan` (ops/ssd_scan.py: the running sums,
+`C B^T`, and the kernels `ssd_chunk` / `ssd_chunk_bwd`, which carry the
+state over the chunks) and `ssm_gate_norm`; `block_attention` under `attention`; `lm_head`
 and `next_token_loss`. The `laguna-moe` family (models/laguna.py) writes
 flax's module names `embed`, `layers_<i>/{attention_full,attention_window}`
 (a layer's attention by its kind, its norm, gate and residual inside; the

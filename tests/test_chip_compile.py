@@ -12,7 +12,9 @@ One file and one process on purpose: describing the topology takes
 `/tmp/libtpu_lockfile`, and two processes doing it at once abort.
 """
 
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 
@@ -183,8 +185,8 @@ def test_expert_activation_compiles_at_the_token_cells_chunk(v5e, call):
 @pytest.mark.parametrize("call", ["forward", "forward_and_backward"])
 def test_state_space_scan_compiles_at_the_hybrid_cells_row(v5e, call):
     """`ssd_scan` at a row of `granite-h-micro-train-causal-8k`: 8192
-    positions in chunks of 256, 64 heads of 64 (4 a grid step: whole lane
-    tiles), a state of 128, bf16."""
+    positions in chunks of 256, 64 heads of 64 (8 a grid step, as the cell
+    runs them), a state of 128, bf16."""
     from raft_stereo_tpu.ops import ssd_scan
 
     shape = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
@@ -192,15 +194,31 @@ def test_state_space_scan_compiles_at_the_hybrid_cells_row(v5e, call):
                 shape((1, 8192, 128), jnp.bfloat16), shape((1, 8192, 128), jnp.bfloat16), shape((64,), jnp.float32))
 
     def value(*o):
-        y, final = ssd_scan.ssd_scan(*o, chunk=256, heads=4)
+        y, final = ssd_scan.ssd_scan(*o, chunk=256)
         return jnp.sum(y.astype(jnp.float32)) + jnp.sum(final)
 
     if call == "forward":
         text = _assert_kernel_compiled(value, *operands)
         assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
-        return
-    text = _assert_kernel_compiled(jax.value_and_grad(value, argnums=(0, 1, 2, 3, 4, 5)), *operands)
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2  # ssd_chunk, ssd_chunk_bwd
+    else:
+        text = _assert_kernel_compiled(jax.value_and_grad(value, argnums=(0, 1, 2, 3, 4, 5)), *operands)
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 2  # ssd_chunk, ssd_chunk_bwd
+    # The recurrence, the past's term, D x and the output's cast live in the
+    # kernels: no float32 array of a row's 8192 x 4096 goes through HBM.
+    assert _unfused_results(text, "f32", 8192 * 4096) == []
+
+
+def _unfused_results(text, dtype, elements):
+    """Instructions outside fusions whose result is `dtype` with `elements`
+    numbers, whatever its shape."""
+    found, fused = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            fused = "fused" in line.split(" ")[0] or "fusion" in line.split(" ")[0]
+        match = re.match(rf"\s+(?:ROOT )?%?([\w.\-]+) = {dtype}\[([\d,]*)\]", line)
+        if match and not fused and math.prod(int(v) for v in match.group(2).split(",") if v) == elements:
+            found.append(match.group(1))
+    return found
 
 
 def test_causal_attention_compiles_at_the_hybrid_cells_row(v5e):

@@ -58,21 +58,41 @@ def _recurrence(x, dt, a, b, c, d):
     return jnp.swapaxes(y, 0, 1), final
 
 
-def _scan_operands(seq, batch=2, heads=4, p=16, n=16):
-    k = jax.random.split(jax.random.PRNGKey(seq), 7)
+def _scan_operands(seq, batch=2, heads=4, p=16, n=16, on_y=True):
+    k = jax.random.split(jax.random.PRNGKey(seq), 8)
     x = jax.random.normal(k[0], (batch, seq, heads, p))
     dt = jax.nn.softplus(jax.random.normal(k[1], (batch, seq, heads)))
     a = -jnp.exp(jax.random.uniform(k[2], (heads,), minval=0.0, maxval=2.0))
     b, c = jax.random.normal(k[3], (batch, seq, n)), jax.random.normal(k[4], (batch, seq, n))
-    weights = jax.random.normal(k[5], x.shape), jax.random.normal(k[6], (batch, heads, n, p))
-    return (x, dt, a, b, c, jnp.ones((heads,))), weights
+    d = jax.random.uniform(k[7], (heads,), minval=0.5, maxval=2.0)
+    weights = jax.random.normal(k[5], x.shape) * on_y, jax.random.normal(k[6], (batch, heads, n, p))
+    return (x, dt, a, b, c, d), weights
 
 
-@pytest.mark.parametrize("seq", [SEQ, 37, 5], ids=["whole_chunks", "ragged_last_chunk", "shorter_than_a_chunk"])
+# Chunks of 8 positions. `heads`: heads a grid step asks for; heads of 16
+# lanes never fill a lane tile, so every head goes in one step, and 8 heads
+# of 32 take two steps of 4.
+SCAN_CASES = {
+    "whole_chunks": dict(seq=SEQ),
+    "ragged_last_chunk": dict(seq=37),
+    "shorter_than_a_chunk": dict(seq=5),
+    "many_chunks": dict(seq=56, batch=1),  # the state crosses six chunk boundaries, each way
+    "two_head_groups": dict(seq=SEQ, heads=8, p=32, per_step=4),
+    "two_rows_apart": dict(seq=48, batch=2),  # a state left over from row 0 would show in row 1
+    "final_state_only": dict(seq=SEQ, on_y=False),  # the gradient enters through the last state alone
+}
+
+
+@pytest.mark.parametrize("case", list(SCAN_CASES))
 @pytest.mark.parametrize("form", ["kernels", "dense"])
-def test_ssd_scan_matches_the_recurrence_forward_and_backward(seq, form):
-    operands, (w_y, w_s) = _scan_operands(seq)
-    scan = {"kernels": lambda *o: ss.ssd_scan(*o, chunk=8, heads=2), "dense": lambda *o: ss.ssd_scan_dense(*o, chunk=8)}[form]
+def test_ssd_scan_matches_the_recurrence_forward_and_backward(case, form):
+    sizes = dict(SCAN_CASES[case])
+    per_step = sizes.pop("per_step", 2)
+    operands, (w_y, w_s) = _scan_operands(**sizes)
+    if case == "two_head_groups":
+        assert ss._heads_a_step(8, 32, per_step) == 4
+    scan = {"kernels": lambda *o: ss.ssd_scan(*o, chunk=8, heads=per_step),
+            "dense": lambda *o: ss.ssd_scan_dense(*o, chunk=8)}[form]
 
     def value(fn):
         def weighed(*o):
